@@ -51,10 +51,6 @@ def main(argv=None):
     p.add_argument("--num-examples", type=int, default=512)
     args = p.parse_args(argv)
 
-    from mxnet_tpu.base import probe_backend_or_fallback
-
-    probe_backend_or_fallback()
-
     import mxnet_tpu as mx
     from mxnet_tpu import autograd, gluon
     from mxnet_tpu.gluon import nn

@@ -69,10 +69,6 @@ def main(argv=None):
                    help="backbone checkpoint path (default: tmp)")
     args = p.parse_args(argv)
 
-    from mxnet_tpu.base import probe_backend_or_fallback
-
-    probe_backend_or_fallback()
-
     import jax
 
     import mxnet_tpu as mx

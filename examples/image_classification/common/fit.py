@@ -119,6 +119,7 @@ def fit(args, network, data_loader, **kwargs):
         network = sym
 
     devs = mx.tpu() if args.ctx == "tpu" and mx.num_tpus() > 0 else mx.cpu()
+    logging.info("--ctx %s runs on %s", args.ctx, devs.jax_device())
     lr, lr_scheduler = _get_lr_scheduler(args, kv)
 
     model = mx.mod.Module(context=devs, symbol=network)

@@ -17,18 +17,14 @@ from __future__ import annotations
 __version__ = "0.1.0"
 
 from .base import (MXNetError, apply_platform_env as _ape,
-                   maybe_enable_latency_hiding as _lhs,
                    maybe_init_distributed as _midi)
 
-# all three must run BEFORE anything touches the XLA backend (the only
-# moment they work): MXTPU_PLATFORM platform pinning, the XLA
-# latency-hiding-scheduler flags for non-CPU backends (collectives
-# overlap compute — docs/PERFORMANCE.md), then the tools/launch.py
+# both must run BEFORE anything touches the XLA backend (the only moment
+# they work): MXTPU_PLATFORM platform pinning, then the tools/launch.py
 # jax.distributed rendezvous
 _ape()
-_lhs()
 _midi()
-del _ape, _lhs, _midi
+del _ape, _midi
 
 import os as _os
 

@@ -1160,8 +1160,6 @@ class _ServeRole(_Role):
         env = self._base_env(slot, generation)
         env.pop("MXTPU_COORDINATOR", None)
         env.setdefault("MXNET_TPU_GANG_BEAT", "0.5")
-        env.setdefault("MXNET_TPU_CACHE_DIR",
-                       os.path.join(self.sup.run_dir, "cache"))
         env.setdefault("MXTPU_FLEET_DIR", self.dir)
         bus = self.cfg.get("subscribe_to")
         if bus:

@@ -18,13 +18,14 @@ call sites), which buys one seam for:
   or a topology change invalidates instead of mis-hitting.
 * **A two-level cache** — the in-memory executable map (per wrapped
   function, keyed on the call signature) over a **persistent on-disk
-  cache** of serialized compiled executables under ``MXNET_TPU_CACHE_DIR``
+  cache** of serialized compiled executables under the cache root
   (CRC-manifested per entry like ``checkpoint.py``, written tmp+rename so
   concurrent writers are safe, corrupt entries fall back to recompile).
-  jax's own compilation cache is additionally pointed at
-  ``<cache_dir>/xla`` when available, so even signatures this layer cannot
-  serialize (e.g. executables returning vjp closures) skip XLA
-  backend-compile across runs.
+  On an accelerator backend jax's own compilation cache backs what this
+  layer does not serialize (donating executables: the trainer step and
+  the serving ladder) — in ``JAX_COMPILATION_CACHE_DIR`` where that is
+  set (jax reads it itself; nothing here overrides it), else in
+  ``<root>/xla``.
 * **AOT warmup** — every compile records its signature into an in-memory
   (and, with a cache dir, on-disk) *warmup manifest*; :func:`warmup`
   replays a manifest so serving/training pods compile before first
@@ -40,7 +41,10 @@ call sites), which buys one seam for:
 
 Knobs
 -----
-``MXNET_TPU_CACHE_DIR``          on-disk cache root (unset = memory only)
+``MXNET_TPU_CACHE_DIR``          on-disk cache root; unset, the root is
+                                 ``JAX_COMPILATION_CACHE_DIR``, else on a
+                                 TPU backend the fixed in-checkout
+                                 ``.mxtpu_cache``, else (CPU) memory only
 ``MXNET_TPU_COMPILE_SERVICE=0``  bypass the service (raw ``jax.jit``)
 ``MXNET_TPU_CACHE_SALT``         extra fingerprint salt (tests use it to
                                  simulate a jax-version/backend change)
@@ -78,6 +82,14 @@ __all__ = ["jit", "stats", "totals", "reset_stats", "set_enabled",
            "disk_report", "gc_cache", "clear_memory", "registered"]
 
 ENV_DIR = "MXNET_TPU_CACHE_DIR"
+ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: the cache root on an accelerator backend when neither variable names
+#: one: a fixed path inside the checkout, resolved from this file's own
+#: location (the path is part of jax's cache key — a directory that
+#: moves never hits)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".mxtpu_cache")
 ENV_ENABLE = "MXNET_TPU_COMPILE_SERVICE"
 ENV_SALT = "MXNET_TPU_CACHE_SALT"
 
@@ -140,23 +152,33 @@ def set_enabled(on) -> bool:
     return prev
 
 
+def _resolve_dir():
+    """The cache root when nothing was passed to :func:`configure`:
+    ``MXNET_TPU_CACHE_DIR``, else ``JAX_COMPILATION_CACHE_DIR`` (this
+    layer's ``exec/``, ``kernels/`` and manifest files then sit next to
+    jax's own entries), else on an accelerator backend the one fixed
+    :data:`DEFAULT_DIR` inside the checkout, else (CPU) None."""
+    explicit = os.environ.get(ENV_DIR) or os.environ.get(ENV_JAX_DIR)
+    if explicit:
+        return explicit
+    return DEFAULT_DIR if _default_device().platform != "cpu" else None
+
+
 def configure(cache_dir="__env__"):
-    """(Re)configure the disk layer. Default: read ``MXNET_TPU_CACHE_DIR``.
+    """(Re)configure the disk layer. Default: :func:`_resolve_dir`.
     Explicit ``cache_dir=None`` forces memory-only mode. Re-running after
     an env change is supported (tests); in-memory executables persist —
     call :func:`clear_memory` to force the disk path."""
     global _DIR, _FP, _CONFIGURED
     with _lock:
         if cache_dir == "__env__":
-            cache_dir = os.environ.get(ENV_DIR) or None
+            cache_dir = _resolve_dir()
         _DIR = os.path.abspath(cache_dir) if cache_dir else None
         _FP = None  # salt / backend may have changed
         _CONFIGURED = True
         if _DIR:
             os.makedirs(os.path.join(_DIR, "exec"), exist_ok=True)
             _enable_native_cache(_DIR)
-        else:
-            _disable_native_cache()
 
 
 def _ensure_configured():
@@ -165,60 +187,25 @@ def _ensure_configured():
 
 
 def _enable_native_cache(root):
-    """Point jax's own compilation cache at ``<root>/xla`` (best effort —
-    flag names moved across versions; missing flags are skipped). This
-    layer catches what executable serialization cannot: the XLA
-    backend-compile of re-traced programs still skips work across runs."""
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(root, "xla"))
-    except Exception:
+    """Point jax's own compilation cache at ``<root>/xla`` on accelerator
+    backends, where donating executables (the trainer step, the serving
+    ladder) are never serialized by this layer and warm-start through
+    jax's cache instead. Where ``JAX_COMPILATION_CACHE_DIR`` is set jax
+    reads it itself and this function does nothing. On the CPU backend
+    jax's cache stays off: executables loaded from it corrupt the heap
+    when they donate (see the platform policy in :func:`jit`), and this
+    layer's ``exec/`` entries cover the CPU warm start."""
+    if os.environ.get(ENV_JAX_DIR) or _default_device().platform == "cpu":
         return
-    for flag, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, val)
-        except Exception:
-            pass
-    try:
-        # jax latches cache availability at the first compile; compiles
-        # very likely already happened (device_put on import paths), so
-        # un-latch to make the new dir take effect
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:
-        pass
-    global _NATIVE_ENABLED
-    _NATIVE_ENABLED = True
-
-
-_NATIVE_ENABLED = False
-
-
-def _disable_native_cache():
-    """Turn jax's compilation cache back off when the service goes
-    memory-only (tests flip cache dirs; a stale pointer at a deleted dir
-    must not keep serving — on CPU jaxlib, executables loaded from the
-    cache corrupt the heap when they donate, see the platform policy in
-    :func:`jit`)."""
-    global _NATIVE_ENABLED
-    if not _NATIVE_ENABLED:
-        return
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", None)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-
-        _cc.reset_cache()
-        _NATIVE_ENABLED = False
-    except Exception:
-        pass
+    _cc.set_cache_dir(os.path.join(root, "xla"))
+    jax = _ensure_jax()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches cache availability at the first compile, which has
+    # very likely already happened (device_put on import paths)
+    _cc.reset_cache()
 
 
 def cache_dir():
@@ -241,13 +228,8 @@ def fingerprint() -> str:
             jl = getattr(jaxlib, "__version__", "?")
         except ImportError:
             jl = "?"
-        try:
-            devs = jax.devices()
-            backend = (devs[0].platform,
-                       getattr(devs[0], "device_kind", devs[0].platform),
-                       str(len(devs)))
-        except Exception as e:  # backend probe failure: still usable
-            backend = ("unknown", type(e).__name__, "0")
+        devs = jax.devices()
+        backend = (devs[0].platform, devs[0].device_kind, str(len(devs)))
         parts = (jax.__version__, jl) + backend + (
             os.environ.get(ENV_SALT, ""),)
         _FP = hashlib.sha1("|".join(parts).encode()).hexdigest()[:12]
@@ -509,7 +491,12 @@ def _disk_store(key, compiled, site, canon, spec_args):
     meta = {"crc32": zlib.crc32(payload) & 0xFFFFFFFF,
             "size": len(payload), "site": site, "canon": canon,
             "fingerprint": fingerprint(), "created": time.time(),
-            "args": spec_args}
+            "args": spec_args,
+            # the device assignment the executable was compiled for, in
+            # order: deserialize_and_load spreads an executable over
+            # EVERY device of the backend unless told which ones
+            "devices": [int(d.id) for d in
+                        compiled.runtime_executable().local_devices()]}
     meta_bytes = json.dumps(meta, sort_keys=True).encode()
     try:
         _atomic_write_bytes(os.path.join(d, key + ".bin"),
@@ -550,10 +537,16 @@ def _disk_load(key, st):
             (zlib.crc32(payload) & 0xFFFFFFFF) != meta.get("crc32"):
         st[6] += 1
         return None
+    ids = meta.get("devices")
+    if not ids:  # entry predates the recorded device assignment
+        return None
     try:
         from jax.experimental import serialize_executable as se
 
-        return se.deserialize_and_load(*pickle.loads(payload))
+        by_id = {d.id: d for d in _ensure_jax().devices()}
+        return se.deserialize_and_load(
+            *pickle.loads(payload),
+            execution_devices=[by_id[i] for i in ids])
     except Exception:
         st[6] += 1
         return None
@@ -742,12 +735,15 @@ def _spec_args(node):
     if t == "dict":
         return {k: _spec_args(v) for k, v in node["items"].items()}
     if t == "arr":
-        sh = _shard_from_json(node.get("sharding"))
-        kw = {}
-        if sh is not None:
-            kw["sharding"] = sh
+        # "on the default device" replays as COMMITTED there — what an
+        # NDArray or a staged serving batch presents. jit lowers committed
+        # and uncommitted arguments separately, so replaying uncommitted
+        # would compile (and seed jax's cache with) a program that
+        # traffic never asks for.
+        sh = _shard_from_json(node.get("sharding")) \
+            or jax.sharding.SingleDeviceSharding(_default_device())
         return jax.ShapeDtypeStruct(tuple(node["shape"]), node["dtype"],
-                                    **kw)
+                                    sharding=sh)
     # scalar leaf: replay with the recorded sample value
     return node.get("value")
 
@@ -1045,29 +1041,19 @@ class ServiceFunction:
             # compile AOT so the executable can be serialized for the
             # next process
             t0 = time.perf_counter()
-            try:
-                compiled = self._jit.lower(*args).compile()
-            except Exception:
-                compiled = None  # odd arg mix: raw jit still handles it
-            if compiled is not None:
-                ms = (time.perf_counter() - t0) * 1e3
-                st[3] += 1
-                st[4] += ms
-                self._seen[sig] = compiled
-                _record_manifest(self._token_key, self._site, args)
-                _disk_store(key, compiled, self._site, canon,
-                            _spec_tree(args))
-                if _xcost_wanted(self._site):
-                    _capture_analysis(self._site, self._token_key,
-                                      compiled=compiled, source="compile")
-                _profiler_compile(self._site, ms, "compile", st)
-                try:
-                    return compiled(*args)
-                except Exception:
-                    # placement/layout stricter than jit: permanent
-                    # fallback for this signature
-                    self._seen[sig] = self._jit
-                    return self._jit(*args)
+            compiled = self._jit.lower(*args).compile()
+            ms = (time.perf_counter() - t0) * 1e3
+            st[3] += 1
+            st[4] += ms
+            self._seen[sig] = compiled
+            _record_manifest(self._token_key, self._site, args)
+            _disk_store(key, compiled, self._site, canon,
+                        _spec_tree(args))
+            if _xcost_wanted(self._site):
+                _capture_analysis(self._site, self._token_key,
+                                  compiled=compiled, source="compile")
+            _profiler_compile(self._site, ms, "compile", st)
+            return compiled(*args)
         # memory mode (or non-persistable signature): the jit call itself
         # traces + compiles; its own cache serves subsequent hits
         t0 = time.perf_counter()
@@ -1169,20 +1155,16 @@ def jit(fn, *, site, token, **jit_kwargs):
             or not _ENABLED:
         return _ensure_jax().jit(fn, **jit_kwargs)
     _ensure_configured()
-    if jit_kwargs.get("donate_argnums") and _DIR is not None:
-        try:
-            platform = _default_device().platform
-        except Exception:
-            platform = "unknown"
-        if platform == "cpu":
-            # CPU jaxlib corrupts the heap when a DESERIALIZED executable
-            # (ours or jax's native compilation cache — both active under
-            # a cache dir) donates its input buffers (malloc_consolidate
-            # aborts under the trainer step). Donation is purely a memory
-            # optimisation, so on the CPU backend the persistent cache
-            # wins: strip donation, keep the executable serializable.
-            # TPU/GPU runtimes handle donation through the cache normally
-            # and keep it (only OUR executable serialization is skipped
-            # for donating fns there — see ServiceFunction.__init__).
-            jit_kwargs = dict(jit_kwargs, donate_argnums=())
+    if jit_kwargs.get("donate_argnums") and _DIR is not None \
+            and _default_device().platform == "cpu":
+        # CPU jaxlib corrupts the heap when a DESERIALIZED executable
+        # (ours, or one from jax's own compilation cache) donates its
+        # input buffers (malloc_consolidate aborts under the trainer
+        # step). Donation is purely a memory optimisation, so on the CPU
+        # backend the persistent cache wins: strip donation, keep the
+        # executable serializable. TPU runtimes handle donation through
+        # jax's cache normally and keep it (only OUR executable
+        # serialization is skipped for donating fns there — see
+        # ServiceFunction.__init__).
+        jit_kwargs = dict(jit_kwargs, donate_argnums=())
     return ServiceFunction(fn, site, _token_key(site, token), jit_kwargs)

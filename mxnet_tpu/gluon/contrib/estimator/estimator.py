@@ -32,6 +32,8 @@ class Estimator:
             context = tpu() if num_tpus() > 0 else cpu()
         self.context = context if isinstance(context, (list, tuple)) \
             else [context]
+        self.logger.info("Estimator context %s runs on %s", self.context,
+                         [c.jax_device() for c in self.context])
         self.train_metrics = [metric_mod.create(m)
                               for m in (train_metrics or ["accuracy"])]
         self.val_metrics = [metric_mod.create(m)

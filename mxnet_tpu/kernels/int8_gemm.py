@@ -48,8 +48,12 @@ def _gemm_body(x_ref, w_ref, sc_ref, b_ref, o_ref, acc_ref, *, n_kb,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # precision pinned: an integer contraction has none to choose, and
+    # Mosaic rejects int8 operands under an ambient
+    # jax.default_matmul_precision("highest")
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32)
 
     @pl.when(ki == n_kb - 1)

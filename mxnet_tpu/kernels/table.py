@@ -5,7 +5,7 @@ kernel against its XLA baseline per (backend, family, shape bucket) and
 records the winner here; :func:`mxnet_tpu.kernels.dispatch` consults the
 table at trace time. Persistence follows the compile-cache discipline
 exactly (``mxnet_tpu/compile.py`` disk layer): entries live under
-``MXNET_TPU_CACHE_DIR/kernels/dispatch_<fingerprint>.json`` where the
+``<cache root>/kernels/dispatch_<fingerprint>.json`` where the
 fingerprint folds in jax/jaxlib versions, backend platform, device kind
 and count — a backend change makes old measurements invisible instead of
 silently mis-routing. Writes are tmp + fsync + rename (concurrent-writer
@@ -65,16 +65,12 @@ def table_path():
 
 
 def _fresh():
+    import jax
+
     from .. import compile as _compile
 
-    try:
-        import jax
-
-        backend = jax.devices()[0].platform
-    except Exception:
-        backend = "unknown"
     return {"version": 1, "fingerprint": _compile.fingerprint(),
-            "backend": backend, "created": time.time(), "opperf": None,
+            "backend": jax.devices()[0].platform, "created": time.time(), "opperf": None,
             "entries": {}}
 
 

@@ -51,11 +51,14 @@ def _from_tiles(t, shape, size):
 
 def _compress_body(g_ref, r_ref, codes_ref, res_ref, *, thr):
     g = g_ref[...] + r_ref[...]
-    one = jnp.int8(1)
-    codes = jnp.where(g >= thr, one,
-                      jnp.where(g <= -thr, -one, jnp.int8(0)))
-    codes_ref[...] = codes
-    res_ref[...] = g - codes.astype(g.dtype) * thr
+    # select in the gradient's own dtype and narrow once at the store:
+    # Mosaic cannot relayout the f32 compare's (8, 128) mask onto int8's
+    # (32, 128) tiles, so an int8 select driven by it does not compile.
+    # {-1, 0, +1} are exact in both types — the bits match kvstore's.
+    codes = jnp.where(g >= thr, 1.0,
+                      jnp.where(g <= -thr, -1.0, 0.0)).astype(g.dtype)
+    codes_ref[...] = codes.astype(jnp.int8)
+    res_ref[...] = g - codes * thr
 
 
 def _decompress_body(c_ref, o_ref, *, thr):

@@ -2,9 +2,10 @@
 
 The reference implements its data pipeline (RecordIO reader, image
 normalization) in C++ (`src/io/`); this package provides the TPU
-framework's native equivalents. The shared library builds on demand with
-the system toolchain (g++ -O3) and is cached alongside the source; every
-entry point has a pure-Python fallback so the framework works without a
+framework's native equivalents. The shared library is never committed:
+it builds on first use from ``mxtpu_io.cc`` with the system toolchain
+(g++ -O3) and is cached, untracked, alongside the source; every entry
+point has a pure-Python fallback so the framework works without a
 compiler.
 
 API:
@@ -35,17 +36,26 @@ _tried = False
 _error = None  # why the probe failed (cached; surfaced ONCE, see _load)
 
 
-def _build():
+def _compile_to(out):
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-fopenmp",
-           _SRC, "-o", _LIB_PATH, "-ljpeg"]
+           _SRC, "-o", out, "-ljpeg"]
     try:
         subprocess.run(cmd, check=True, capture_output=True)
     except subprocess.CalledProcessError:
         # hosts without libjpeg/OpenMP: build without the decode path
         # (decode_jpeg_batch falls back to Python; the rest still works)
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-               "-DMXTPU_NO_JPEG", _SRC, "-o", _LIB_PATH]
+               "-DMXTPU_NO_JPEG", _SRC, "-o", out]
         subprocess.run(cmd, check=True, capture_output=True)
+
+
+def _build():
+    # tmp + rename: several processes (fleet workers, test children) may
+    # reach first use at once, and none may load a library another is
+    # still writing
+    from ..checkpoint import atomic_write
+
+    atomic_write(_LIB_PATH, _compile_to)
 
 
 def _record_failure(exc):
@@ -88,14 +98,7 @@ def _load():
         if not os.path.exists(_LIB_PATH) or \
                 os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC):
             _build()
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            # a stale checked-in .so linked against libs this host lacks
-            # (e.g. libjpeg): rebuild for THIS host — _build() falls back
-            # to the no-jpeg variant, preserving every other native path
-            _build()
-            lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(_LIB_PATH)
         lib.mxtpu_recordio_scan.restype = ctypes.c_longlong
         lib.mxtpu_recordio_scan.argtypes = [
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64),

@@ -44,10 +44,6 @@ def moe_apply(expert_fn, mesh, axis="ep"):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .._jax_compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     jmesh = mesh.jax_mesh
     num_experts = mesh.size(axis)
 
@@ -69,7 +65,7 @@ def moe_apply(expert_fn, mesh, axis="ep"):
         aux = num_experts * jax.lax.psum(frac_e * mean_p_e, axis)
         return y, aux
 
-    sharded = shard_map(local, mesh=jmesh,
+    sharded = jax.shard_map(local, mesh=jmesh,
                         in_specs=(P(axis), P(), P()),
                         out_specs=(P(), P()))
 

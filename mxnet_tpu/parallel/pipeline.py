@@ -72,10 +72,6 @@ def pipeline_apply(stage_fn, mesh, num_microbatches, axis="pp"):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .._jax_compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     jmesh = mesh.jax_mesh
     num_stages = mesh.size(axis)
     m = num_microbatches
@@ -87,8 +83,7 @@ def pipeline_apply(stage_fn, mesh, num_microbatches, axis="pp"):
         perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
         # mark the carries as device-varying over pp (shard_map's vma check
         # rejects a scan whose carry changes variance mid-loop)
-        from .._jax_compat import pcast
-
+        pcast = jax.lax.pcast
         state = pcast(jnp.zeros_like(xs[0]), axis, to="varying")
         out_buf = pcast(jnp.zeros_like(xs), axis, to="varying")
 
@@ -116,7 +111,7 @@ def pipeline_apply(stage_fn, mesh, num_microbatches, axis="pp"):
                             jnp.zeros_like(out_buf))
         return jax.lax.psum(out_buf, axis)
 
-    sharded = shard_map(local, mesh=jmesh,
+    sharded = jax.shard_map(local, mesh=jmesh,
                         in_specs=(P(axis), P()), out_specs=P())
 
     @jax.jit

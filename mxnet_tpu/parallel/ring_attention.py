@@ -78,8 +78,7 @@ def _ring_attention_local(q, k, v, axis_name, causal, scale):
         return o_new, m_new, l_new, k_next, v_next
 
     # initial carries must carry the sp-varying type (shard_map type system)
-    from .._jax_compat import pcast
-
+    pcast = jax.lax.pcast
     o = pcast(jnp.zeros(q.shape, jnp.float32), axis_name, to="varying")
     m = pcast(jnp.full(q.shape[:-1], -jnp.inf, jnp.float32),
               axis_name, to="varying")
@@ -100,15 +99,11 @@ def ring_attention_sharded(mesh, axis="sp", causal=False, scale=None):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from .._jax_compat import get_shard_map
-
-    shard_map = get_shard_map()
-
     jmesh = mesh.jax_mesh
     spec = P(None, None, axis, None)
     local = functools.partial(_ring_attention_local, axis_name=axis,
                               causal=causal, scale=scale)
-    fn = shard_map(lambda q, k, v: local(q, k, v), mesh=jmesh,
+    fn = jax.shard_map(lambda q, k, v: local(q, k, v), mesh=jmesh,
                    in_specs=(spec, spec, spec), out_specs=spec)
     return jax.jit(fn)
 

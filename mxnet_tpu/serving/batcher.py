@@ -199,14 +199,11 @@ class BucketBatcher:
         do_stage = cfg["stage"] if stage is None else bool(stage)
         self._stager = None
         if do_stage:
-            try:
-                import jax
+            import jax
 
-                from ..io.io import DeviceStager
+            from ..io.io import DeviceStager
 
-                self._stager = DeviceStager(device=jax.devices()[0])
-            except Exception:
-                self._stager = None
+            self._stager = DeviceStager(device=jax.devices()[0])
 
     # ----------------------------------------------------------- control --
     def start(self):
@@ -453,11 +450,17 @@ class BucketBatcher:
             x = self._pad(reqs, rows, bucket)
             t_pad = time.monotonic()
             if self._stager is not None:
-                # h2d on this thread overlaps the runner's compiled call
+                # h2d on this thread overlaps the runner's compiled call;
+                # a transfer the device refuses fails its batch (and only
+                # it) instead of passing the host array on in silence
                 try:
                     x = self._stager.put(x)
-                except Exception:
-                    pass  # staging is an optimisation; jit transfers too
+                except Exception as e:
+                    self._fail_batch(reqs, RequestError(
+                        f"model {self.model.name!r}: staging a batch of "
+                        f"{rows} rows failed: {type(e).__name__}: {e}",
+                        cause=e))
+                    continue
             t_staged = time.monotonic()
             for r in reqs:   # batch_collect = pad; h2d = the staged put
                 if r.fut._trace is not None:

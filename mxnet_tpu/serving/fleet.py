@@ -1299,10 +1299,11 @@ class ServingFleet:
         worker_env["PYTHONPATH"] = pkg_root + os.pathsep + \
             os.environ.get("PYTHONPATH", "")
         # a shared persistent compile cache is what makes rollout cheap:
-        # generation N+1 LOADS the ladder the first generation compiled
-        worker_env.setdefault("MXNET_TPU_CACHE_DIR",
-                              os.environ.get("MXNET_TPU_CACHE_DIR")
-                              or os.path.join(self.run_dir, "cache"))
+        # generation N+1 LOADS the ladder the first generation compiled.
+        # Workers inherit MXNET_TPU_CACHE_DIR / JAX_COMPILATION_CACHE_DIR
+        # from this process's environment (or take compile.py's fixed
+        # on-TPU default); no cache path is derived from the run dir,
+        # which may be a fresh temporary name that would never hit
         # diagnose run next to the fleet finds the run dir through this
         worker_env.setdefault("MXTPU_FLEET_DIR", self.run_dir)
         # live weight streaming: every worker of every generation

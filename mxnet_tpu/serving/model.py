@@ -89,8 +89,19 @@ class ServedModel:
         if buckets is None:
             buckets = _config.effective()["buckets"]
         self.buckets = _config._coerce("buckets", buckets)
-        self._praws = tuple(param_raws)
-        self._araws = tuple(aux_raws)
+        import jax
+
+        # parameters, aux state and every batch are COMMITTED to the one
+        # serving device. jit keys its executables on commitment as well
+        # as on shape, so a warm-up over host arrays followed by staged
+        # (committed) traffic, or by a model-bus swap, would recompile
+        # every bucket on its first real batch — behind the back of
+        # compile.stats(), which canonicalises both to one signature
+        self._device = jax.devices()[0]
+        self._praws = tuple(jax.device_put(r, self._device)
+                            for r in param_raws)
+        self._araws = tuple(jax.device_put(r, self._device)
+                            for r in aux_raws)
         # the model-bus census surface: param names (when the loader
         # knows them) + the version/pinned-tuple pair behind live weight
         # swaps. _pinned is rebound as ONE tuple — a batch reads it once,
@@ -105,14 +116,7 @@ class ServedModel:
         # memory win on accelerators; CPU jaxlib only warns about it, so
         # gate on platform (the compile service additionally strips
         # donation on cpu under a cache dir — see its platform policy)
-        donate = ()
-        try:
-            import jax
-
-            if jax.devices()[0].platform != "cpu":
-                donate = (2,)
-        except Exception:
-            pass
+        donate = (2,) if self._device.platform != "cpu" else ()
         self._fn = _compile.jit(forward, site="serving",
                                 token=self._token(forward),
                                 donate_argnums=donate)
@@ -242,7 +246,7 @@ class ServedModel:
         import jax
 
         praws, araws, version = self._pinned
-        out = self._fn(praws, araws, x)
+        out = self._fn(praws, araws, jax.device_put(x, self._device))
         outs = out if isinstance(out, tuple) else (out,)
         host = jax.device_get(outs)
         n = x.shape[0] if rows is None else rows
@@ -283,6 +287,7 @@ class ServedModel:
         Parameters are snapshotted at build time (later training does not
         leak into serving)."""
         from .. import autograd
+        from ..cached_op import TraceScope
         from ..ndarray import NDArray
 
         params = block.collect_params()
@@ -298,11 +303,17 @@ class ServedModel:
         def fwd(praws, araws, x):
             # the ShardedTrainer.predict idiom: rebind the live handles to
             # the traced values for the duration of the trace
+            import jax
+
             saved = [(h, h._data) for h in handles]
             try:
                 for h, r in zip(handles, praws):
                     h._data = r
-                with autograd.pause(train_mode=False):
+                # the scope inlines hybridized children into THIS trace
+                # (their CachedOps would otherwise draw from the global
+                # RNG mid-trace); fixed key: inference is deterministic
+                with TraceScope(jax.random.PRNGKey(0)), \
+                        autograd.pause(train_mode=False):
                     out = block.forward(NDArray(x))
                 outs = out if isinstance(out, (tuple, list)) else (out,)
                 return tuple(o._data for o in outs)

@@ -101,8 +101,15 @@ def end_step(flops=None, devices=1, device_kind=None):
     rec["phases"]["other"] = round(max(0.0, dur_ms - accounted), 3)
     if flops:
         rec["flops"] = flops
-        mfu = _costs.mfu_xla(flops, 1e3 / dur_ms if dur_ms > 0 else 0.0,
-                             devices=devices, device_kind=device_kind)
+        try:
+            mfu = _costs.mfu_xla(
+                flops, 1e3 / dur_ms if dur_ms > 0 else 0.0,
+                devices=devices, device_kind=device_kind)
+        except LookupError:
+            # no published peak for this device kind (the CPU backend):
+            # the utilization is not measured, and the record says so by
+            # leaving it out
+            mfu = None
         if mfu is not None:
             rec["mfu_xla"] = round(mfu, 5)
     _HIST.append(rec)

@@ -171,9 +171,9 @@ def check_numeric_gradient(op_name, input_arrays, kwargs=None, rtol=1e-2,
 
     Runs under locally-scoped x64 so the finite differences are computed in
     real float64 without changing suite-wide dtype semantics."""
-    from ._jax_compat import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64():
         _check_numeric_gradient_x64(op_name, input_arrays, kwargs, rtol, atol, eps)
 
 

@@ -10,9 +10,8 @@ the SAME trajectory:
     EL_TOTAL      total global steps (default 12)
     EL_EPOCH      steps per epoch (default 4)
     EL_DEVICES    simulated device count — applied BEFORE the jax backend
-                  initialises (jax_num_cpu_devices, or the XLA_FLAGS
-                  --xla_force_host_platform_device_count fallback for
-                  jax<0.5, exactly like tests/conftest.py)
+                  initialises (jax_num_cpu_devices, exactly like
+                  tests/conftest.py)
     EL_RESUME     "1" -> resume from the manager's latest good checkpoint
     EL_RESHARD    "0" -> forbid cross-topology resume (reshard=False)
     EL_OUT        where to np.savez the final params + per-step losses
@@ -33,12 +32,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 if _n:
-    try:
-        jax.config.update("jax_num_cpu_devices", _n)
-    except AttributeError:  # jax < 0.5 spells this flag via XLA_FLAGS
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={_n}")
+    jax.config.update("jax_num_cpu_devices", _n)
 
 import numpy as np  # noqa: E402
 

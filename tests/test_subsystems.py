@@ -383,56 +383,6 @@ def test_model_zoo_get_model_names():
         and len(names) >= 25
 
 
-def test_ensure_live_backend_respects_pin(monkeypatch):
-    """An explicit MXTPU_PLATFORM pin short-circuits the backend probe
-    (base.py ensure_live_backend)."""
-    monkeypatch.setenv("MXTPU_PLATFORM", "cpu")
-    from mxnet_tpu.base import ensure_live_backend
-
-    assert ensure_live_backend() == "cpu"
-
-
-def test_ensure_live_backend_fallback_paths(monkeypatch):
-    """Timeout -> cpu-fallback (env pinned only after success); crash ->
-    RuntimeError after retry, env untouched (base.py ensure_live_backend)."""
-    import subprocess
-
-    import pytest
-
-    from mxnet_tpu import base
-
-    monkeypatch.delenv("MXTPU_PLATFORM", raising=False)
-    # an earlier in-process probe success latches MXTPU_PROBE_OK and would
-    # short-circuit the probe entirely (regression guard for the full-suite
-    # order dependency fixed alongside conftest's _probe_env_guard)
-    monkeypatch.delenv("MXTPU_PROBE_OK", raising=False)
-
-    def hang(*a, **kw):
-        raise subprocess.TimeoutExpired(cmd="probe", timeout=kw["timeout"])
-
-    monkeypatch.setattr(subprocess, "run", hang)
-    # conftest already pinned the cpu platform, so config.update succeeds
-    assert base.ensure_live_backend(timeout_s=0.1) == "cpu-fallback"
-    assert os.environ["MXTPU_PLATFORM"] == "cpu"
-
-    monkeypatch.delenv("MXTPU_PLATFORM", raising=False)
-    calls = []
-
-    class Boom:
-        returncode = 1
-        stderr = b"device busy"
-
-    def crash(*a, **kw):
-        calls.append(1)
-        return Boom()
-
-    monkeypatch.setattr(subprocess, "run", crash)
-    with pytest.raises(RuntimeError, match="crash, not a hang"):
-        base.ensure_live_backend(timeout_s=0.1, retries=1)
-    assert len(calls) == 2  # initial + one retry
-    assert "MXTPU_PLATFORM" not in os.environ
-
-
 # ----------------------------------------------------------- bulking -------
 
 def _mlp():

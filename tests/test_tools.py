@@ -173,7 +173,6 @@ def test_mxlint_catches_planted_violations(tmp_path):
         "import os\n"                                    # unused-import
         "import numpy as np\n"
         "import jax\n"
-        "from jax.experimental import enable_x64\n"      # raw-jax-compat
         "from mxnet_tpu.ops.registry import register\n"
         "def f(x, y=[]):\n"                              # mutable-default
         "    try:\n"
@@ -189,7 +188,7 @@ def test_mxlint_catches_planted_violations(tmp_path):
         "spec = P('dpp', None)\n")                       # partition-spec-literal
     findings = mxlint.run([str(bad)], root=str(tmp_path))
     rules = {f.rule for f in findings}
-    assert rules == {"unused-import", "raw-jax-compat", "raw-jit",
+    assert rules == {"unused-import", "raw-jit",
                      "mutable-default", "host-sync", "bare-except",
                      "unseeded-random", "no-schema-doc",
                      "partition-spec-literal"}
@@ -215,7 +214,7 @@ def test_mxlint_catches_planted_violations(tmp_path):
 @pytest.mark.lint
 def test_mxlint_raw_jit_rule_scoping(tmp_path):
     """raw-jit fires on direct jax.jit calls and 'from jax import jit',
-    but compile.py (the service home) and _jax_compat.py are exempt."""
+    but compile.py (the service home) is exempt."""
     import mxlint
 
     direct = tmp_path / "site.py"

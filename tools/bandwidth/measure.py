@@ -28,9 +28,7 @@ def measure(sizes_mib, iters=10, dtype="float32", warmup=2):
     mesh = Mesh(np.array(devices), ("x",))
     results = []
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # jax <= 0.4.x keeps it in experimental
-        from jax.experimental.shard_map import shard_map
+    shard_map = jax.shard_map
 
     @jax.jit
     def _psum(arr):

@@ -689,7 +689,10 @@ def fleet_drill(root=None):
     fl = fleet_mod.ServingFleet(
         v1, workers=2, run_dir=os.path.join(root, "run"),
         config={"min": 2, "max": 2, "beat": 0.2, "grace": 20},
-        name="chaos-fleet")
+        name="chaos-fleet",
+        # the rollout's zero-recompile claim needs a cache both
+        # generations share; the fleet derives none from its run dir
+        env={"MXNET_TPU_CACHE_DIR": os.path.join(root, "cache")})
     fl.start(timeout=90)
     stop.clear()
     del errors[:]

@@ -66,7 +66,7 @@ Examples::
         --deadline-ms 50 --hot-key-frac 0.3 --duration 10
 
 The last stdout line is one JSON report (bench.py --serve embeds it into
-the BENCH_r06+ metric series).
+its serving line).
 """
 from __future__ import annotations
 

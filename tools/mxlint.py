@@ -12,11 +12,8 @@ bare-except       ``except:`` swallows KeyboardInterrupt/SystemExit and
 host-sync         ``.asnumpy()`` / ``.asscalar()`` / ``.item()`` in library
                   code — each is a device round-trip and splits any live
                   bulk segment; hot paths must stay async.
-raw-jax-compat    ``shard_map`` / ``enable_x64`` / ``pcast`` taken from jax
-                  directly: their home moved across jax versions, so call
-                  sites must go through ``mxnet_tpu._jax_compat``.
-raw-jit           a direct ``jax.jit(`` call outside ``compile.py`` /
-                  ``_jax_compat.py`` — every compile must go through the
+raw-jit           a direct ``jax.jit(`` call outside ``compile.py`` —
+                  every compile must go through the
                   unified compile service (``mxnet_tpu.compile.jit``) so
                   it gets the canonical cache key, the persistent on-disk
                   cache, AOT warmup and the per-site hit/miss metrics;
@@ -105,7 +102,7 @@ from collections import Counter
 DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "mxlint_baseline.txt")
 
-RULES = ("bare-except", "host-sync", "raw-jax-compat", "raw-jit",
+RULES = ("bare-except", "host-sync", "raw-jit",
          "unseeded-random", "no-schema-doc", "unused-import",
          "mutable-default", "unbounded-sync", "partition-spec-literal",
          "serving-blocking-call", "print-call", "raw-pallas-call",
@@ -131,7 +128,6 @@ _SYNC_METHODS = {"asnumpy", "asscalar"}
 # canonical mesh-axis vocabulary — keep in sync with
 # mxnet_tpu/parallel/mesh.py AXIS_ORDER
 _MESH_AXES = {"dp", "pp", "tp", "sp", "ep"}
-_COMPAT_NAMES = {"shard_map", "enable_x64", "pcast"}
 _NP_RANDOM_FNS = {
     "rand", "randn", "randint", "random", "random_sample", "ranf", "sample",
     "uniform", "normal", "standard_normal", "choice", "shuffle",
@@ -176,11 +172,9 @@ class _Linter(ast.NodeVisitor):
         self.findings = []
         self.lines = source.splitlines()
         self.is_init = os.path.basename(path) == "__init__.py"
-        self.is_compat = os.path.basename(path) == "_jax_compat.py"
         self.is_watchdog = os.path.basename(path) == "watchdog.py"
         # compile.py IS the service — the one home of raw jax.jit
-        self.is_compile = os.path.basename(path) in ("compile.py",
-                                                     "_jax_compat.py")
+        self.is_compile = os.path.basename(path) == "compile.py"
         # parallel/ is the home of the sharding vocabulary itself
         self.is_parallel = "/parallel/" in rel.replace(os.sep, "/")
         # serving/ code must never wait unboundedly outside watchdog.sync
@@ -337,12 +331,6 @@ class _Linter(ast.NodeVisitor):
                      "default_rng threaded from a seed (host-side shuffles)")
 
     def visit_Attribute(self, node):
-        if not self.is_compat and node.attr in _COMPAT_NAMES:
-            chain = _dotted(node)
-            if chain is not None and chain.split(".")[0] == "jax":
-                self.add(node, "raw-jax-compat",
-                         f"{chain} moved across jax versions; route through "
-                         "mxnet_tpu._jax_compat")
         if not self.is_compile and node.attr == "jit":
             chain = _dotted(node)
             if chain is not None and chain.split(".")[0] == "jax":
@@ -379,13 +367,6 @@ class _Linter(ast.NodeVisitor):
             for a in node.names:
                 if a.name == "PartitionSpec":
                     self.pspec_aliases.add(a.asname or a.name)
-        if not self.is_compat and mod.split(".")[0] == "jax":
-            for a in node.names:
-                if a.name in _COMPAT_NAMES:
-                    self.add(node, "raw-jax-compat",
-                             f"'from {mod} import {a.name}' moved across "
-                             "jax versions; route through "
-                             "mxnet_tpu._jax_compat")
         if not self.is_compile and mod == "jax":
             for a in node.names:
                 if a.name == "jit":
