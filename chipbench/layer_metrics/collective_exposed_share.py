@@ -1,0 +1,19 @@
+"""The part of the collective time during which no compute instruction
+runs on that device, as a share of the traced window: what the step
+really waits for."""
+from chipbench.harness import trace_reduce
+
+LAYER = "collectives"
+MOVES = "train_samples_per_s"
+UNIT = "%"
+
+
+def applies(run):
+    return run["mode"] == "train" and run["chips"] > 1
+
+
+def compute(run):
+    if not run["trace"]["devices"]:
+        return None
+    split = trace_reduce.collective_split(run["trace"])
+    return 100.0 * split["exposed_s"] / split["window_s"]
