@@ -1,0 +1,18 @@
+"""Device idle time a step, mean over the chips, while the host was under
+``trainer.dispatch``: the compile service's signature over every argument
+leaf (``compile.signature``) and jit's C++ dispatch of the step with its
+donation holds (``compile.execute``). One of six that sum to the device's
+idle time a step (``harness/program_spans.py``), in ms."""
+from chipbench.harness import program_spans
+
+LAYER = "trainer"
+MOVES = "train_samples_per_s"
+UNIT = "ms"
+
+
+def applies(run):
+    return run["mode"] == "train"
+
+
+def compute(run):
+    return program_spans.idle_ms(run, "dispatch")

@@ -75,6 +75,7 @@ from .analysis import distcheck as _distcheck
 from .telemetry import _state as _tele_state
 from .telemetry import costs as _tele_costs
 from .telemetry import flight as _flight
+from .telemetry import trace as _trace
 
 __all__ = ["jit", "stats", "totals", "reset_stats", "set_enabled",
            "enabled", "configure", "cache_dir", "fingerprint", "warmup",
@@ -1130,6 +1131,31 @@ def _profiler_compile(site, ms, source, st):
 
 def _token_key(site, token):
     return site + "|" + hashlib.sha1(repr(token).encode()).hexdigest()[:20]
+
+
+def call_spanned(fn, *args):
+    """``fn(*args)`` for a caller that runs once a step, not once an op
+    (the trainer): ``ServiceFunction.__call__``'s two halves each in a
+    span of their own — ``compile.signature`` (:func:`_sig_of` over every
+    leaf of the arguments) and ``compile.execute`` (the dict probe and
+    the executable's call: jit's C++ dispatch with its donation holds,
+    or on a miss the compile). ``__call__`` itself, the per-op path,
+    stays as it is."""
+    if not (_ENABLED and isinstance(fn, ServiceFunction)):
+        with _trace.span("compile.execute"):
+            return fn(*args)
+    with _trace.span("compile.signature"):
+        sig = _sig_of(args)
+    with _trace.span("compile.execute"):
+        if sig is None:
+            return fn._jit(*args)
+        rec = fn._seen.get(sig)
+        if rec is None:
+            return fn._miss(sig, args)
+        fn._st[0] += 1
+        if _distcheck.CACHE_TRACK:
+            _distcheck.cache_event("service", fn._site, sig, True)
+        return rec(*args)
 
 
 def jit(fn, *, site, token, **jit_kwargs):

@@ -652,18 +652,24 @@ class ShardedTrainer:
         the exception."""
         from .. import preempt as _preempt
         from .. import watchdog as _watchdog
+        from ..telemetry import trace as _trace
 
         if _preempt.requested():
             raise _preempt.DrainRequested(_preempt.event())
-        return _watchdog.sync("trainer.step",
-                              lambda: self._step_impl(x, y),
-                              label=f"step {self._t + 1}")
+        # the step's span (telemetry/trace.py): every piece of host work
+        # below is a child of it, the watchdog's and telemetry's own
+        # bookkeeping is what is left of it outside them
+        with _trace.step(self._t + 1):
+            return _watchdog.sync("trainer.step",
+                                  lambda: self._step_impl(x, y),
+                                  label=f"step {self._t + 1}")
 
     def _step_impl(self, x, y):
         from ..telemetry import steps as _tsteps
+        from ..telemetry import trace as _trace
 
-        # per-step phase timeline (data-wait / h2d / compute / optimizer
-        # / sync — docs/OBSERVABILITY.md): the record opens here, phases
+        # per-step phase timeline (data-wait / h2d / host / compute /
+        # sync — docs/OBSERVABILITY.md): the record opens here, phases
         # accrue inside _step_exec, and a raising step (injected fault,
         # drain request, stall) abandons its partial record
         _tsteps.begin_step(self._t + 1)
@@ -672,8 +678,9 @@ class ShardedTrainer:
         except BaseException:
             _tsteps.abort()
             raise
-        _tsteps.end_step(flops=self._step_flops(),
-                         devices=self._mesh.num_devices)
+        with _trace.span("trainer.bookkeeping"):
+            _tsteps.end_step(flops=self._step_flops(),
+                             devices=self._mesh.num_devices)
         if self._bus is not None and self._t % self._bus_every == 0:
             self.publish_update()
         return out
@@ -697,13 +704,13 @@ class ShardedTrainer:
         return _tsteps.last()
 
     def _step_exec(self, x, y):
-        import time as _time
+        import jax.numpy as jnp
 
-        import jax
-
+        from .. import compile as _compile
         from .. import faults as _faults
         from .. import random as _rand
         from ..telemetry import steps as _tsteps
+        from ..telemetry.trace import span as _span
 
         x_raw = x._data if isinstance(x, NDArray) else x
         y_raw = y._data if isinstance(y, NDArray) else y
@@ -719,60 +726,80 @@ class ShardedTrainer:
             from ..analysis import distcheck as _distcheck
 
             _distcheck.check_trainer(self, x_raw, y_raw)
-        t0 = _time.perf_counter()
-        x_raw = self._put_batch(
-            x_raw, self._mesh.sharding(
-                *(("dp",) + (None,) * (len(x_raw.shape) - 1))))
-        y_raw = self._put_batch(y_raw, self._mesh.sharding("dp"))
-        _tsteps.phase("h2d", (_time.perf_counter() - t0) * 1e3)
+        # every piece of host work below runs in a span of its own, and
+        # the phase it feeds is that span's duration (one measurement)
+        with _span("trainer.put_batch") as sp:
+            x_raw = self._put_batch(
+                x_raw, self._mesh.sharding(
+                    *(("dp",) + (None,) * (len(x_raw.shape) - 1))))
+            y_raw = self._put_batch(y_raw, self._mesh.sharding("dp"))
+        _tsteps.phase("h2d", sp.dur_ms)
         if self._step_fn is None:
             self._step_fn = self._build(x_raw, y_raw)
         self._t += 1
-        import jax.numpy as jnp
-
         lr = self._lr if self._lr_scheduler is None \
             else float(self._lr_scheduler(self._t))
-        in_p = tuple(h._data for h in self._train_handles)
-        in_opt = self._opt_raws
-        in_aux = tuple(h._data for h in self._aux_handles)
-        t0 = _time.perf_counter()
-        new_p, new_opt, new_aux, loss, ok = self._step_fn(
-            in_p, in_opt, in_aux,
-            x_raw, y_raw, _rand.next_key(),
-            jnp.asarray(self._t, jnp.int32),
-            jnp.asarray(lr, jnp.float32))
+        # the key and the two scalars each launch small device programs
+        # of their own ahead of the step's
+        with _span("trainer.rng_key") as sp:
+            key = _rand.next_key()
+        _tsteps.phase("compute", sp.dur_ms)
+        with _span("trainer.scalars") as sp:
+            t = jnp.asarray(self._t, jnp.int32)
+            lr = jnp.asarray(lr, jnp.float32)
+        _tsteps.phase("compute", sp.dur_ms)
+        with _span("trainer.gather") as sp:
+            in_p = tuple(h._data for h in self._train_handles)
+            in_opt = self._opt_raws
+            in_aux = tuple(h._data for h in self._aux_handles)
+        _tsteps.phase("host", sp.dur_ms)
         # the fused executable runs fwd+bwd+optimizer as one program, so
         # the optimizer phase is folded into compute (async dispatch:
         # device time lands in the nan-guard sync read below, or in the
         # next step's phases when nan_guard=False)
-        _tsteps.phase("compute", (_time.perf_counter() - t0) * 1e3)
-        if self._donate and self._distcheck:
-            # donation-safety (distcheck pass 3): the step donated every
-            # param/opt/aux input buffer — poison them so a stale alias
-            # used later raises a param-named use-after-donate error
-            # instead of jax's anonymous "Array has been deleted"
-            from ..analysis import distcheck as _distcheck
+        with _span("trainer.dispatch") as sp:
+            new_p, new_opt, new_aux, loss, ok = _compile.call_spanned(
+                self._step_fn, in_p, in_opt, in_aux, x_raw, y_raw, key,
+                t, lr)
+        _tsteps.phase("compute", sp.dur_ms)
+        with _span("trainer.commit") as sp:
+            if self._donate and self._distcheck:
+                # donation-safety (distcheck pass 3): the step donated
+                # every param/opt/aux input buffer — poison them so a
+                # stale alias used later raises a param-named
+                # use-after-donate error instead of jax's anonymous
+                # "Array has been deleted"
+                from ..analysis import distcheck as _distcheck
 
-            origin = "ShardedTrainer.step (donate=True)"
-            for name, raw in zip(self._param_names, in_p):
-                _distcheck.mark_donated(raw, name, origin, self._t)
-            for name, per in zip(self._param_names, in_opt):
-                for j, raw in enumerate(per):
-                    _distcheck.mark_donated(
-                        raw, f"{name} (optimizer state {j})", origin,
-                        self._t)
-            for name, raw in zip(self._aux_names, in_aux):
-                _distcheck.mark_donated(raw, name, origin, self._t)
-        with autograd.pause():
-            for h, raw in zip(self._train_handles, new_p):
-                h._data = raw  # donated buffers: rebind directly
-            for h, raw in zip(self._aux_handles, new_aux):
-                h._data = raw
-        self._opt_raws = new_opt
+                origin = "ShardedTrainer.step (donate=True)"
+                for name, raw in zip(self._param_names, in_p):
+                    _distcheck.mark_donated(raw, name, origin, self._t)
+                for name, per in zip(self._param_names, in_opt):
+                    for j, raw in enumerate(per):
+                        _distcheck.mark_donated(
+                            raw, f"{name} (optimizer state {j})", origin,
+                            self._t)
+                for name, raw in zip(self._aux_names, in_aux):
+                    _distcheck.mark_donated(raw, name, origin, self._t)
+            with autograd.pause():
+                for h, raw in zip(self._train_handles, new_p):
+                    h._data = raw  # donated buffers: rebind directly
+                for h, raw in zip(self._aux_handles, new_aux):
+                    h._data = raw
+            self._opt_raws = new_opt
+        _tsteps.phase("host", sp.dur_ms)
         if self._nan_guard:
-            t0 = _time.perf_counter()
-            self._account_skip(bool(ok))  # blocks on step completion
-            _tsteps.phase("sync", (_time.perf_counter() - t0) * 1e3)
+            with _span("trainer.guard_sync") as sp:
+                ok = bool(ok)  # blocks on step completion
+            _tsteps.phase("sync", sp.dur_ms)
+        with _span("trainer.release") as sp:
+            # the donated inputs die here, under a name, and not unseen
+            # when this frame is torn down: every array's destructor and
+            # the expiry callback of its distcheck poison record
+            del in_p, in_opt, in_aux
+        _tsteps.phase("host", sp.dur_ms)
+        if self._nan_guard:
+            self._account_skip(ok)
         return NDArray(loss)
 
     def _account_skip(self, ok):

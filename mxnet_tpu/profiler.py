@@ -11,8 +11,10 @@ dispatch path (`ndarray._invoke`) and CachedOp executions; device-side
 traces come from XLA via ``jax.profiler`` when ``profile_device=True`` is
 passed to :func:`set_config` (written next to the chrome trace as
 ``<filename>.device/`` in TensorBoard format — the XLA analogue of the
-reference's per-stream GPU events). The chrome trace loads directly in
-``chrome://tracing`` / Perfetto.
+reference's per-stream GPU events; the session runs without the Python
+tracer, so it exports in seconds, and its host plane holds every
+``telemetry.trace.span`` of the program on the device events' clock).
+The chrome trace loads directly in ``chrome://tracing`` / Perfetto.
 """
 from __future__ import annotations
 
@@ -48,13 +50,12 @@ _config = {
 }
 _events = []  # chrome trace events
 _aggregate = {}  # name -> [count, total_us, min_us, max_us]
-_epoch = time.perf_counter()
-_epoch_mono = time.monotonic()  # same instant: the cross-clock anchor
+_epoch = time.monotonic()  # the clock of telemetry's spans and records
 _device_trace_active = False
 
 
 def _now_us():
-    return (time.perf_counter() - _epoch) * 1e6
+    return (time.monotonic() - _epoch) * 1e6
 
 
 def _refresh():
@@ -89,7 +90,14 @@ def set_state(state="stop", profile_process="worker"):
                 try:
                     import jax
 
-                    jax.profiler.start_trace(_config["filename"] + ".device")
+                    # no Python tracer: one event per Python call swamps
+                    # the trace (3 s of traffic took 6 minutes to export,
+                    # PERF.md) and slows the host threads it measures
+                    options = jax.profiler.ProfileOptions()
+                    options.python_tracer_level = 0
+                    jax.profiler.start_trace(
+                        _config["filename"] + ".device",
+                        profiler_options=options)
                     _device_trace_active = True
                 except Exception:
                     _device_trace_active = False
@@ -266,12 +274,12 @@ def record_counter(name, value):
 
 
 def trace_info():
-    """The recorded chrome events plus the monotonic instant matching
-    the profiler's perf_counter epoch — so ``telemetry.trace.dump()``
-    can re-base profiler events onto the span/flight timeline (both
-    clocks are CLOCK_MONOTONIC-backed on the platforms we run on)."""
+    """The recorded chrome events plus the ``time.monotonic`` instant
+    their timestamps count from — the clock of the span ring, the step
+    records and the flight recorder, so ``telemetry.trace.dump()`` can
+    re-base profiler events onto that timeline."""
     with _lock:
-        return {"epoch_mono": _epoch_mono, "events": list(_events)}
+        return {"epoch_mono": _epoch, "events": list(_events)}
 
 
 def dump(finished=True, profile_process="worker"):

@@ -1,24 +1,42 @@
-"""Per-step phase timeline: data-wait / h2d / compute / optimizer / sync.
+"""Per-step phase timeline: data-wait / h2d / host / compute / optimizer
+/ sync.
 
-One record per ``ShardedTrainer.step``, assembled from the existing
-instrumentation seams rather than new ones:
+One record per ``ShardedTrainer.step``. Every phase the trainer feeds is
+the duration of a :func:`~mxnet_tpu.telemetry.trace.span` around the
+work itself (one pair of clock reads, two views: the span in the ring
+and the profiler's trace, the phase here):
 
     ``data_wait``  time the consumer blocked on the input pipeline
                    (PrefetchingIter's staged-batch join — reported by
                    ``io/io.py`` into the *next* step's record);
     ``h2d``        host-to-device placement of the batch
-                   (``_put_batch``; ~0 when the prefetcher device-staged);
-    ``compute``    the compiled step call — dispatch plus, when the
-                   nan-guard's flag read synchronizes, device execution.
-                   The fused step runs fwd+bwd+optimizer as ONE
-                   executable, so the optimizer phase is folded in here;
+                   (``trainer.put_batch``; ~0 when the prefetcher
+                   device-staged);
+    ``host``       the trainer's own Python around the compiled call:
+                   gathering the handles' buffers into its arguments
+                   (``trainer.gather``), after it marking the donated
+                   buffers and rebinding the handles
+                   (``trainer.commit``), and at the end letting go of
+                   the donated inputs (``trainer.release``);
+    ``compute``    getting the step's programs launched: the PRNG key
+                   and the two scalar arguments (``trainer.rng_key``,
+                   ``trainer.scalars``: small device programs of their
+                   own) and the compiled step call
+                   (``trainer.dispatch``), which returns once the
+                   program is enqueued. The fused step runs
+                   fwd+bwd+optimizer as ONE executable, so the optimizer
+                   phase is folded in here;
     ``optimizer``  a separate optimizer executable's time (0 for the
                    fused ShardedTrainer step — present so the grammar is
                    stable across trainer styles);
     ``sync``       explicit post-step host reads (the nan-guard skip-flag
-                   read). With ``nan_guard=False`` dispatch is async and
-                   both compute and sync shrink toward dispatch cost —
-                   wall-clock then shows up in the NEXT step's phases.
+                   read, ``trainer.guard_sync``: it blocks until the
+                   device has finished the step). With ``nan_guard=False``
+                   dispatch is async and sync is 0 — wall-clock then
+                   shows up in the NEXT step's phases.
+
+``other`` is the record's duration less all of these: what has no span
+(the fault and distcheck hooks, building the step on first use).
 
 Each finished step publishes gauges (``mxtpu_step_time_ms``,
 ``mxtpu_step_phase_ms{phase}``), a duration histogram, a running step
@@ -40,7 +58,7 @@ from . import registry as _registry
 __all__ = ["PHASES", "begin_step", "phase", "end_step", "abort", "last",
            "history", "reset"]
 
-PHASES = ("data_wait", "h2d", "compute", "optimizer", "sync")
+PHASES = ("data_wait", "h2d", "host", "compute", "optimizer", "sync")
 
 _lock = threading.Lock()
 _HIST = deque(maxlen=256)
@@ -132,8 +150,9 @@ def end_step(flops=None, devices=1, device_kind=None):
             "per-device-kind peak)").set(rec["mfu_xla"])
         _registry.gauge("mxtpu_step_flops",
                         "XLA-analyzed flops per step").set(flops)
-    # the step's span twin, keyed (generation, rank, step) — the raw
-    # material of the fleet straggler verdict and the merged gang trace
+    # the phase split rides the step's span, keyed (generation, rank,
+    # step) — the raw material of the fleet straggler verdict and the
+    # merged gang trace
     from . import trace as _trace
 
     _trace.step_span(rec, cur["t0"])

@@ -23,19 +23,30 @@ died". Neither answers "where did THIS request spend its 5ms?" or
   ``request_id`` fields, ``X-Request-Id`` header echoed), and in
   ``tools/loadgen.py``'s per-phase percentile report.
 
-* **Trainer steps** reuse the :mod:`~mxnet_tpu.telemetry.steps` phase
-  timeline: every finished step commits one span keyed by
-  ``(generation, rank, step)`` with its phase children — the raw
-  material of the fleet-level straggler verdict
-  (:mod:`~mxnet_tpu.telemetry.fleet`).
+* **Trainer steps**: ``ShardedTrainer.step`` runs inside one
+  :func:`step` span keyed by ``(generation, rank, step)``; the pieces of
+  host work in it (``trainer.put_batch`` ... ``trainer.bookkeeping``)
+  are real :func:`span` children, measured where they happen, and the
+  :mod:`~mxnet_tpu.telemetry.steps` phase split rides the parent as its
+  ``phases`` attribute — the raw material of the fleet-level straggler
+  verdict (:mod:`~mxnet_tpu.telemetry.fleet`).
 
-* **Ad-hoc spans** (:func:`span`) nest through a per-thread stack and
-  inherit the thread's propagated trace context (:func:`context`).
+* :func:`span` is the one way the program times a piece of host work:
+  one pair of clock reads (``time.monotonic``, the clock of the flight
+  recorder, the step records and the fleet shards) feeds the ring, the
+  caller (``dur_ms``) and — through a ``jax.profiler.TraceAnnotation``
+  entered beside them — the host plane of whatever profiler session is
+  running, on the clock the device events are on. Spans nest through a
+  per-thread stack and inherit the thread's propagated trace context
+  (:func:`context`); :func:`carry`/:func:`adopt` hand both to a helper
+  thread. A span recorded after the fact (:func:`commit`: request and
+  bucket phases) has no live interval to annotate and stays in the ring
+  only.
 
 Committed spans live in a bounded ring (``MXNET_TPU_TRACE``, default
-2048 spans; 0 disables tracing entirely). Overhead contract: tracing
-off = one module-global check per hook (:func:`enabled`); on, the cost
-is per *request/step/batch*, never per op.
+2048 spans; 0 turns the ring off, spans still time and annotate).
+Overhead contract: telemetry off = one module-global check per hook; on,
+the cost is per *request/step/batch*, never per op.
 
 :func:`dump` folds spans, flight-recorder tails and (locally) the
 profiler's chrome events into a ``trace.json`` that loads directly in
@@ -48,6 +59,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -56,9 +68,9 @@ from . import _state
 
 __all__ = ["enabled", "configure", "size", "new_request_id", "coords",
            "context", "set_context", "get_context", "span", "commit",
-           "request_begin", "RequestTrace", "REQUEST_PHASES",
-           "step_span", "tail", "counts", "clear", "dump", "last_dump",
-           "merged_events", "describe"]
+           "carry", "adopt", "request_begin", "RequestTrace",
+           "REQUEST_PHASES", "step", "step_span", "tail", "counts",
+           "clear", "dump", "last_dump", "merged_events", "describe"]
 
 #: the serving request phase vocabulary, in pipeline order
 REQUEST_PHASES = ("queue_wait", "batch_collect", "h2d", "compute",
@@ -173,53 +185,122 @@ def commit(name, t0_mono, dur_ms, *, kind="span", trace_id=None,
            "lane": int(lane) if lane is not None
            else (threading.get_ident() % 100000),
            "attrs": attrs or None}
-    _ring.append(rec)
-    with _counts_lock:
-        _counts[kind] = _counts.get(kind, 0) + 1
+    _append(rec)
     return sid
 
 
-class span:
-    """Measure a nested span: ``with trace.span("io.h2d"): ...``.
-    Nesting is tracked per thread — an inner span's ``parent`` is the
-    enclosing span's id, and both inherit the thread's trace context."""
+def _append(rec):
+    _ring.append(rec)
+    with _counts_lock:
+        _counts[rec["kind"]] = _counts.get(rec["kind"], 0) + 1
 
-    def __init__(self, name, kind="span", **attrs):
+
+_TraceAnnotation = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is loaded (telemetry
+    never imports it: a process without jax has no profiler session to
+    annotate)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation
+
+
+class span:
+    """Time a piece of host work: ``with trace.span("io.h2d") as sp: ...``.
+
+    One pair of ``time.monotonic`` reads gives ``sp.dur_ms`` (what the
+    caller feeds its :mod:`~mxnet_tpu.telemetry.steps` phase from) and
+    the ring record; a ``jax.profiler.TraceAnnotation`` of the same name
+    is entered beside them, so that under anybody's profiler session the
+    span sits in the trace's host plane on the device events' clock (no
+    session: an atomic flag check). Nesting is tracked per thread — an
+    inner span's ``parent`` is the enclosing span's id, and both inherit
+    the thread's trace context, which ``trace_id`` rebinds for the
+    span's own extent. Telemetry off: nothing is read, ``dur_ms`` is 0.
+    ``attrs`` ride the ring record and the annotation."""
+
+    __slots__ = ("name", "kind", "attrs", "span_id", "dur_ms", "_t0",
+                 "_ann", "_trace_id", "_lane", "_prev")
+
+    def __init__(self, name, kind="span", trace_id=None, lane=None,
+                 **attrs):
         self.name = name
         self.kind = kind
         self.attrs = attrs
         self.span_id = None
+        self.dur_ms = 0.0
         self._t0 = None
+        self._ann = None
+        self._trace_id = trace_id
+        self._lane = lane
+        self._prev = None
 
     def __enter__(self):
-        if enabled():
-            self._t0 = time.monotonic()
-            stack = getattr(_tls, "stack", None)
-            if stack is None:
-                stack = _tls.stack = []
+        if not _state.enabled:
+            return self
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.name, **self.attrs)
+            self._ann.__enter__()
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if _N > 0:
             # claim the id up front so children can reference it
             self.span_id = next(_seq)
-            stack.append(self.span_id)
+        if self._lane is None and stack:
+            self._lane = stack[-1]._lane   # drawn under its parent
+        stack.append(self)
+        if self._trace_id is not None:
+            self._prev = set_context(self._trace_id)
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        if self._t0 is None:
+        t0 = self._t0
+        if t0 is None:
             return
-        stack = getattr(_tls, "stack", ())
-        if stack and stack[-1] == self.span_id:
+        self.dur_ms = (time.monotonic() - t0) * 1e3
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        trace_id = get_context()
+        if self._trace_id is not None:
+            _tls.trace = self._prev
+        stack = _tls.stack
+        if stack and stack[-1] is self:
             stack.pop()
-        parent = stack[-1] if stack else None
-        if not enabled():
+        if self.span_id is None or not enabled():
             return
-        rec = {"seq": self.span_id, "name": self.name, "kind": self.kind,
-               "trace": get_context(), "parent": parent,
-               "t0": round(self._t0, 6),
-               "dur_ms": round((time.monotonic() - self._t0) * 1e3, 4),
-               "lane": threading.get_ident() % 100000,
-               "attrs": self.attrs or None}
-        _ring.append(rec)
-        with _counts_lock:
-            _counts[self.kind] = _counts.get(self.kind, 0) + 1
+        _append({"seq": self.span_id, "name": self.name,
+                 "kind": self.kind, "trace": trace_id,
+                 "parent": stack[-1].span_id if stack else None,
+                 "t0": round(t0, 6), "dur_ms": round(self.dur_ms, 4),
+                 "lane": self._lane if self._lane is not None
+                 else threading.get_ident() % 100000,
+                 "attrs": self.attrs or None})
+
+
+def carry():
+    """This thread's trace context and open spans, for :func:`adopt` on
+    a thread that does the caller's work for it (the watchdog's bounded
+    waiter)."""
+    return get_context(), tuple(getattr(_tls, "stack", ()))
+
+
+def adopt(carried):
+    """Bind what :func:`carry` returned as this (fresh) thread's context
+    and enclosing spans: spans opened here keep the caller's trace id
+    and nest under the caller's open span."""
+    _tls.trace, stack = carried
+    _tls.stack = list(stack)
 
 
 # -------------------------------------------------------- serving requests --
@@ -303,31 +384,37 @@ def request_begin(model, rows=1, request_id=None):
 
 # ----------------------------------------------------------- trainer steps --
 
+def _step_key(n):
+    rank, gen = coords()
+    return rank, gen, f"step-g{gen}-r{rank}-{n}", 500 + (rank % 100)
+
+
+def step(n):
+    """The span one trainer step runs in: ``trainer.step`` keyed
+    ``(generation, rank, step)``, the trace id its children inherit."""
+    rank, gen, trace_id, lane = _step_key(n)
+    return span("trainer.step", kind="step", trace_id=trace_id,
+                lane=lane, step=int(n), rank=rank, generation=gen)
+
+
 def step_span(rec, t0_mono):
-    """Commit one trainer step as a span keyed ``(generation, rank,
-    step)`` with its phase children laid out in pipeline order (called
-    by :func:`mxnet_tpu.telemetry.steps.end_step`)."""
+    """Hang the finished step record's phase split on the step's span
+    (called by :func:`mxnet_tpu.telemetry.steps.end_step`, inside it):
+    the span commits with it when the step returns, its real children
+    are in the ring already. A record closed outside any :func:`step`
+    span (a caller driving ``begin_step``/``end_step`` itself) commits a
+    ``trainer.step`` of its own from the record's clock reads."""
     if not enabled():
         return
-    rank, gen = coords()
-    trace_id = f"step-g{gen}-r{rank}-{rec['step']}"
-    lane = 500 + (rank % 100)
-    parent = commit("trainer.step", t0_mono, rec["duration_ms"],
-                    kind="step", trace_id=trace_id, lane=lane,
-                    attrs={"step": rec["step"], "rank": rank,
-                           "generation": gen,
-                           "phases": dict(rec["phases"])})
-    # the phase split is accrued (durations, not timestamps); lay the
-    # children out sequentially in the order they actually execute
-    t = t0_mono
-    for name in ("data_wait", "h2d", "compute", "optimizer", "sync",
-                 "other"):
-        ms = rec["phases"].get(name, 0.0)
-        if ms <= 0.0:
-            continue
-        commit(name, t, ms, kind="phase", trace_id=trace_id,
-               parent=parent, lane=lane)
-        t += ms / 1e3
+    for sp in reversed(getattr(_tls, "stack", ())):
+        if sp.kind == "step":
+            sp.attrs["phases"] = dict(rec["phases"])
+            return
+    rank, gen, trace_id, lane = _step_key(rec["step"])
+    commit("trainer.step", t0_mono, rec["duration_ms"], kind="step",
+           trace_id=trace_id, lane=lane,
+           attrs={"step": rec["step"], "rank": rank, "generation": gen,
+                  "phases": dict(rec["phases"])})
 
 
 # ------------------------------------------------------------- inspection --
@@ -488,10 +575,8 @@ def dump(path="trace.json", run_dir=None, include_profiler=True):
 
 def _profiler_events(rank, offset, base_wall):
     """The profiler's recorded chrome events, re-based onto this dump's
-    timeline (profiler timestamps are perf_counter-relative; its
-    ``trace_info()`` carries the matching monotonic epoch)."""
-    import sys
-
+    timeline (profiler timestamps count from the ``time.monotonic``
+    epoch its ``trace_info()`` carries)."""
     prof = sys.modules.get("mxnet_tpu.profiler")
     if prof is None or not hasattr(prof, "trace_info"):
         return []

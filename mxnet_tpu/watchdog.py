@@ -85,6 +85,7 @@ import time
 
 from . import log as _log
 from .telemetry import flight as _flight
+from .telemetry import trace as _trace
 
 __all__ = ["StallError", "configure", "configure_from_env", "enabled",
            "sync", "beat", "heartbeats", "set_last_resort", "last_resort",
@@ -635,9 +636,13 @@ def sync(point, fn, label=None):
 def _bounded(cfg, span, fn):
     box = {}
     done = threading.Event()
+    carried = _trace.carry()
 
     def runner():
         _tls.in_sync = True  # inherit-suppress: the waiter IS the span
+        # the caller's trace id and open spans: what fn() times nests
+        # under the caller's span, as if it ran inline
+        _trace.adopt(carried)
         try:
             box["value"] = fn()
         except BaseException as e:
