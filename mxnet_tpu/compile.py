@@ -296,7 +296,8 @@ def _leaf_sig(obj, dt):
         return ("a", tuple(obj.shape), dt(obj.dtype),
                 _shard_sig(getattr(obj, "sharding", None)),
                 bool(getattr(obj, "weak_type", False)))
-    if isinstance(obj, _np.ndarray):
+    if isinstance(obj, (_np.ndarray, _np.generic)):
+        # a numpy scalar is the 0-d array jit makes of it
         return ("a", obj.shape, dt(obj.dtype), (), False)
     if obj is None or isinstance(obj, (bool, int, float, complex, str)):
         # traced scalar: the value is a runtime argument, only the python
@@ -346,22 +347,26 @@ def _canon(token_key, sig):
 def _site_stats(site):
     st = _SITES.get(site)
     if st is None:
-        st = _SITES[site] = [0, 0, 0, 0, 0.0, 0.0, 0]
+        st = _SITES[site] = [0, 0, 0, 0, 0.0, 0.0, 0, 0, 0]
     return st
 
 
 def stats():
     """Per-site service statistics: ``{site: {hits, misses, disk_hits,
-    compiles, compile_ms, load_ms, corrupt}}``. ``misses`` =
-    ``disk_hits + compiles`` (+ raw-jit fallbacks); ``compile_ms`` on the
-    memory path includes the first execution (dispatch-inclusive)."""
+    compiles, compile_ms, load_ms, corrupt, sig_hits, sig_misses}}``.
+    ``misses`` = ``disk_hits + compiles`` (+ raw-jit fallbacks);
+    ``compile_ms`` on the memory path includes the first execution
+    (dispatch-inclusive). ``sig_hits`` / ``sig_misses``: calls through
+    :func:`call_spanned` that took the caller's remembered signature
+    nodes for their leading arguments, and those that walked every leaf."""
     out = {}
     for site, st in sorted(_SITES.items()):
         if not (st[0] or st[1] or st[2] or st[3] or st[6]):
             continue  # registered but no traffic yet
         out[site] = {"hits": st[0], "misses": st[1], "disk_hits": st[2],
                      "compiles": st[3], "compile_ms": round(st[4], 3),
-                     "load_ms": round(st[5], 3), "corrupt": st[6]}
+                     "load_ms": round(st[5], 3), "corrupt": st[6],
+                     "sig_hits": st[7], "sig_misses": st[8]}
     return out
 
 
@@ -387,7 +392,7 @@ def reset_stats():
     # site's stat list — replacing the lists would orphan their counters
     with _lock:
         for st in _SITES.values():
-            st[0] = st[1] = st[2] = st[3] = st[6] = 0
+            st[0] = st[1] = st[2] = st[3] = st[6] = st[7] = st[8] = 0
             st[4] = st[5] = 0.0
 
 
@@ -679,7 +684,8 @@ def _spec_tree(obj):
         return {"t": "dict", "items": items}
     if isinstance(obj, _Tracer):
         return None
-    if isinstance(obj, (jax.Array, _np.ndarray, jax.ShapeDtypeStruct)):
+    if isinstance(obj, (jax.Array, _np.ndarray, _np.generic,
+                        jax.ShapeDtypeStruct)):
         from .ops.registry import dtype_str as dt
 
         sh = getattr(obj, "sharding", None)
@@ -1133,19 +1139,40 @@ def _token_key(site, token):
     return site + "|" + hashlib.sha1(repr(token).encode()).hexdigest()[:20]
 
 
-def call_spanned(fn, *args):
+def signature(args):
+    """The signature nodes of ``args``, one per argument, as the service
+    keys its executables: what :func:`call_spanned` takes as ``known``.
+    None where the service would not use them (switched off, or a tracer
+    among the leaves)."""
+    return _sig_of(args) if _ENABLED else None
+
+
+def call_spanned(fn, *args, known=None):
     """``fn(*args)`` for a caller that runs once a step, not once an op
     (the trainer): ``ServiceFunction.__call__``'s two halves each in a
-    span of their own — ``compile.signature`` (:func:`_sig_of` over every
-    leaf of the arguments) and ``compile.execute`` (the dict probe and
-    the executable's call: jit's C++ dispatch with its donation holds,
-    or on a miss the compile). ``__call__`` itself, the per-op path,
-    stays as it is."""
+    span of their own — ``compile.signature`` and ``compile.execute``
+    (the dict probe and the executable's call: jit's C++ dispatch with
+    its donation holds, or on a miss the compile). ``__call__`` itself,
+    the per-op path, stays as it is.
+
+    ``known`` is :func:`signature` of the leading ``len(known)``
+    arguments, built earlier from these very objects (the caller has seen
+    that by identity: an array's shape, dtype and sharding never change),
+    so ``compile.signature`` walks the leaves of the remaining arguments
+    only (the site's ``sig_hits``). Without it every leaf is walked
+    (``sig_misses``)."""
     if not (_ENABLED and isinstance(fn, ServiceFunction)):
         with _trace.span("compile.execute"):
             return fn(*args)
     with _trace.span("compile.signature"):
-        sig = _sig_of(args)
+        if known is None:
+            fn._st[8] += 1
+            sig = _sig_of(args)
+        else:
+            fn._st[7] += 1
+            sig = _sig_of(args[len(known):])
+            if sig is not None:
+                sig = known + sig
     with _trace.span("compile.execute"):
         if sig is None:
             return fn._jit(*args)

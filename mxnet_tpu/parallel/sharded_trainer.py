@@ -16,6 +16,9 @@ is in-place at the XLA level (no 2x parameter memory).
 """
 from __future__ import annotations
 
+import itertools
+import operator
+import weakref
 from typing import Dict, List, Optional
 
 import numpy as _np
@@ -96,8 +99,15 @@ class ShardedTrainer:
             after `max_consecutive_skips` skips in a row step() raises —
             a permanently diverged run must fail loudly, not spin.
             Reading the skip flag synchronizes the host with each step's
-            completion; pass nan_guard=False to restore fully async
-            dispatch when that latency matters more than the guard.
+            completion, so the device has nothing queued from the end of
+            one step to the enqueue of the next. step() therefore does
+            before the enqueue only what the launch needs, after the read
+            only what needs the flag, and everything else (the rng
+            stream's advance, rebinding the outputs, the next step's
+            signature, letting go of the donated inputs) between the
+            two, while the device runs. nan_guard=False runs the same
+            order without the read: fully async dispatch, for when that
+            latency matters more than the guard.
         """
         self._net = net
         self._loss_fn = loss_fn
@@ -225,6 +235,10 @@ class ShardedTrainer:
                          else 0.0 for n in self._param_names]
         self._opt_raws = self._init_opt_state()
         self._step_fn = None
+        # the signature nodes of the last step's outputs, built while the
+        # device ran it (_remember_signature): (weak references to the
+        # leaves, state slots per parameter, nodes)
+        self._sig_memo = None
         self._t = 0
         # elasticity plumbing: the manager/epoch of the newest checkpoint,
         # so a preemption drain (or watchdog abort) can write a final one
@@ -385,6 +399,10 @@ class ShardedTrainer:
             repr(self._mesh.describe()),
             repr((self._donate, self._zero, self._remat, self._accum,
                   self._nan_guard, self._grad_scatter)),
+            # what step_fn does with its arguments beyond their avals:
+            # it takes split(rng)[1] itself (an executable persisted by a
+            # build whose caller split must not be loaded by this one)
+            "rng=split-in-step",
             # kernel-dispatch identity: a retuned table or a flipped
             # MXNET_TPU_KERNELS must not reuse an executable traced
             # under the old routing
@@ -435,8 +453,7 @@ class ShardedTrainer:
 
                 _dc.check_trainer(self, x_raw, y_raw)
             self._step_fn = self._build(x_raw, y_raw)
-        _rand._ensure()
-        key = _rand._state.key  # aval only; the stream does not advance
+        key = _rand.current_key()  # aval only; the stream does not advance
 
         def aval(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -548,6 +565,10 @@ class ShardedTrainer:
             if grad_scatter else None
 
         def step_fn(praws, opt_raws, araws, x, y, rng, t, lr):
+            # `rng` is the global stream's key as the caller found it; the
+            # step's own is the draw next_key() would have made of it
+            # (the caller advances the stream once this is enqueued)
+            rng = jax.random.split(rng)[1]
             (loss, new_aux), grads = grads_of(praws, araws, x, y, rng)
             if nan_guard:
                 # one fused all-finite reduction over loss + every grad;
@@ -704,8 +725,6 @@ class ShardedTrainer:
         return _tsteps.last()
 
     def _step_exec(self, x, y):
-        import jax.numpy as jnp
-
         from .. import compile as _compile
         from .. import faults as _faults
         from .. import random as _rand
@@ -727,7 +746,12 @@ class ShardedTrainer:
 
             _distcheck.check_trainer(self, x_raw, y_raw)
         # every piece of host work below runs in a span of its own, and
-        # the phase it feeds is that span's duration (one measurement)
+        # the phase it feeds is that span's duration (one measurement).
+        # With the guard's read the device has nothing queued from the
+        # end of one step to the enqueue of the next, so each piece sits
+        # where what it depends on puts it: up to trainer.dispatch only
+        # what the launch needs, after trainer.guard_sync only what needs
+        # the flag, the rest between the two, while the device runs
         with _span("trainer.put_batch") as sp:
             x_raw = self._put_batch(
                 x_raw, self._mesh.sharding(
@@ -737,21 +761,20 @@ class ShardedTrainer:
         if self._step_fn is None:
             self._step_fn = self._build(x_raw, y_raw)
         self._t += 1
-        lr = self._lr if self._lr_scheduler is None \
-            else float(self._lr_scheduler(self._t))
-        # the key and the two scalars each launch small device programs
-        # of their own ahead of the step's
-        with _span("trainer.rng_key") as sp:
-            key = _rand.next_key()
-        _tsteps.phase("compute", sp.dur_ms)
+        # no program of their own ahead of the step's: t and lr are host
+        # scalars of the step's dtypes and ride its launch, and the step
+        # takes its key from the stream's current one itself
         with _span("trainer.scalars") as sp:
-            t = jnp.asarray(self._t, jnp.int32)
-            lr = jnp.asarray(lr, jnp.float32)
-        _tsteps.phase("compute", sp.dur_ms)
+            t = _np.int32(self._t)
+            lr = _np.float32(self._lr if self._lr_scheduler is None
+                             else self._lr_scheduler(self._t))
+            key = _rand.current_key()
+        _tsteps.phase("host", sp.dur_ms)
         with _span("trainer.gather") as sp:
             in_p = tuple(h._data for h in self._train_handles)
             in_opt = self._opt_raws
             in_aux = tuple(h._data for h in self._aux_handles)
+            known = self._remembered_signature(in_p, in_opt, in_aux)
         _tsteps.phase("host", sp.dur_ms)
         # the fused executable runs fwd+bwd+optimizer as one program, so
         # the optimizer phase is folded into compute (async dispatch:
@@ -760,7 +783,12 @@ class ShardedTrainer:
         with _span("trainer.dispatch") as sp:
             new_p, new_opt, new_aux, loss, ok = _compile.call_spanned(
                 self._step_fn, in_p, in_opt, in_aux, x_raw, y_raw, key,
-                t, lr)
+                t, lr, known=known)
+        _tsteps.phase("compute", sp.dur_ms)
+        with _span("trainer.rng_key") as sp:
+            # the stream moves on as next_key() would have moved it; its
+            # two small programs queue behind the step's
+            _rand.advance()
         _tsteps.phase("compute", sp.dur_ms)
         with _span("trainer.commit") as sp:
             if self._donate and self._distcheck:
@@ -787,20 +815,55 @@ class ShardedTrainer:
                 for h, raw in zip(self._aux_handles, new_aux):
                     h._data = raw
             self._opt_raws = new_opt
+            # the next step's arguments are these outputs: walk their
+            # ~600 leaves for its signature now, not ahead of its enqueue
+            self._remember_signature(new_p, new_opt, new_aux)
+        _tsteps.phase("host", sp.dur_ms)
+        with _span("trainer.release") as sp:
+            # the donated inputs die here, under a name and under the
+            # running step: every array's destructor and the expiry
+            # callback of its distcheck poison record
+            del in_p, in_opt, in_aux
         _tsteps.phase("host", sp.dur_ms)
         if self._nan_guard:
             with _span("trainer.guard_sync") as sp:
                 ok = bool(ok)  # blocks on step completion
             _tsteps.phase("sync", sp.dur_ms)
-        with _span("trainer.release") as sp:
-            # the donated inputs die here, under a name, and not unseen
-            # when this frame is torn down: every array's destructor and
-            # the expiry callback of its distcheck poison record
-            del in_p, in_opt, in_aux
-        _tsteps.phase("host", sp.dur_ms)
-        if self._nan_guard:
             self._account_skip(ok)
         return NDArray(loss)
+
+    def _remember_signature(self, new_p, new_opt, new_aux):
+        """Build the signature nodes of a step's outputs and remember
+        them with weak references to every leaf (a strong one would keep
+        a whole generation of parameters and state alive through a
+        load_states or a set_data)."""
+        from .. import compile as _compile
+
+        nodes = _compile.signature((new_p, new_opt, new_aux))
+        self._sig_memo = None if nodes is None else (
+            tuple(map(weakref.ref, self._leaves(new_p, new_opt, new_aux))),
+            tuple(map(len, new_opt)), nodes)
+
+    def _remembered_signature(self, in_p, in_opt, in_aux):
+        """The signature nodes built under the last step, if this step's
+        arguments are, leaf by leaf and in the same grouping, the very
+        arrays they were built from (an array's shape, dtype and sharding
+        never change, so identity is enough); None after anything rebound
+        a handle or the optimizer state (set_data, a loaded checkpoint, a
+        reshard) and on the first step."""
+        if self._sig_memo is None:
+            return None
+        refs, opt_lens, nodes = self._sig_memo
+        leaves = self._leaves(in_p, in_opt, in_aux)
+        if len(refs) == len(leaves) \
+                and opt_lens == tuple(map(len, in_opt)) \
+                and all(map(operator.is_, map(operator.call, refs), leaves)):
+            return nodes
+        return None
+
+    @staticmethod
+    def _leaves(p, opt, aux):
+        return [*p, *itertools.chain.from_iterable(opt), *aux]
 
     def _account_skip(self, ok):
         from .. import profiler as _profiler
@@ -955,14 +1018,13 @@ class ShardedTrainer:
 
         from .. import random as _rand
 
-        _rand._ensure()
         names_blob = "\n".join(self._param_names + self._aux_names)
         payload = {
             "__t__": NDArray(jnp.asarray(self._t, jnp.int32)),
             "__rng_seed__": NDArray(
                 jnp.asarray(_rand.current_seed(), jnp.int32)),
             "__rng_key__": NDArray(jnp.asarray(
-                jax.device_get(_rand._state.key))),
+                jax.device_get(_rand.current_key()))),
             "__names__": NDArray(jnp.asarray(_np.frombuffer(
                 names_blob.encode(), _np.uint8))),
         }

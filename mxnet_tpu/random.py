@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import threading
 
-__all__ = ["seed", "next_key", "current_seed"]
+__all__ = ["seed", "next_key", "current_key", "advance", "current_seed"]
 
 _state = threading.local()
 
@@ -55,3 +55,21 @@ def next_key():
     _ensure()
     _state.key, sub = jax.random.split(_state.key)
     return sub
+
+
+def current_key():
+    """The global stream's key as it stands, NOT advanced: for a compiled
+    program that takes ``jax.random.split(key)[1]`` itself (the draw
+    :func:`next_key` would have handed it) while its caller moves the
+    stream on with :func:`advance` once the program is enqueued."""
+    _ensure()
+    return _state.key
+
+
+def advance():
+    """Move the global stream on by one draw without taking it: what
+    :func:`next_key` does to the stream, the same two device programs."""
+    import jax
+
+    _ensure()
+    _state.key, _ = jax.random.split(_state.key)

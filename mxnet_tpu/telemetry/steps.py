@@ -13,17 +13,18 @@ and the profiler's trace, the phase here):
                    (``trainer.put_batch``; ~0 when the prefetcher
                    device-staged);
     ``host``       the trainer's own Python around the compiled call:
-                   gathering the handles' buffers into its arguments
-                   (``trainer.gather``), after it marking the donated
-                   buffers and rebinding the handles
-                   (``trainer.commit``), and at the end letting go of
-                   the donated inputs (``trainer.release``);
-    ``compute``    getting the step's programs launched: the PRNG key
-                   and the two scalar arguments (``trainer.rng_key``,
-                   ``trainer.scalars``: small device programs of their
-                   own) and the compiled step call
-                   (``trainer.dispatch``), which returns once the
-                   program is enqueued. The fused step runs
+                   the two host scalars and the stream's current key
+                   (``trainer.scalars``), gathering the handles' buffers
+                   into its arguments (``trainer.gather``), and after it,
+                   while the device runs, marking the donated buffers,
+                   rebinding the handles and building the next step's
+                   signature (``trainer.commit``) and letting go of the
+                   donated inputs (``trainer.release``);
+    ``compute``    getting the step's programs launched: the compiled
+                   step call (``trainer.dispatch``), which returns once
+                   the program is enqueued, and behind it the rng
+                   stream's advance (``trainer.rng_key``: two small
+                   device programs). The fused step runs
                    fwd+bwd+optimizer as ONE executable, so the optimizer
                    phase is folded in here;
     ``optimizer``  a separate optimizer executable's time (0 for the

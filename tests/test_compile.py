@@ -613,3 +613,215 @@ def test_launchers_derive_no_cache_dir_from_a_run_directory(
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/fixed/cache")
     assert fl._sup._worker_env(0, 1)["JAX_COMPILATION_CACHE_DIR"] \
         == "/fixed/cache"
+
+
+# ------------------------------- the trainer's remembered signature nodes ---
+
+def _sig_trainer(seed=0, optimizer="sgd", mesh=None, **kw):
+    from mxnet_tpu.gluon import loss as gloss, nn
+    from mxnet_tpu.parallel import DeviceMesh, ShardedTrainer
+
+    np.random.seed(seed)
+    mx.random.seed(seed)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu", in_units=8),
+            nn.Dense(4, in_units=16))
+    net.initialize(mx.init.Xavier())
+    tr = ShardedTrainer(net, gloss.L2Loss(), optimizer,
+                        {"learning_rate": 0.05},
+                        mesh=mesh or DeviceMesh({"dp": 2}), **kw)
+    return net, tr
+
+
+def _sig_batch(i, rows=8):
+    rs = np.random.RandomState(100 + i)
+    return (mx.nd.array(rs.randn(rows, 8).astype(np.float32)),
+            mx.nd.array(rs.randn(rows, 4).astype(np.float32)))
+
+
+def _sig_counts():
+    st = C.stats().get("trainer", {})
+    return {k: st.get(k, 0) for k in ("hits", "misses", "sig_hits",
+                                      "sig_misses")}
+
+
+def _sig_delta(before):
+    return {k: v - before[k] for k, v in _sig_counts().items()}
+
+
+def _set_data(net, tr, tmp_path):
+    p = list(net.collect_params().values())[0]
+    p.set_data(p.data() * 0.5)
+
+
+def _load_parameters(net, tr, tmp_path):
+    f = str(tmp_path / "net.params")
+    net.save_parameters(f)
+    net.load_parameters(f)
+
+
+def _load_states(net, tr, tmp_path):
+    f = str(tmp_path / "tr.npz")
+    tr.save_states(f)
+    tr.load_states(f)
+
+
+def _new_optimizer_state(net, tr, tmp_path):
+    tr._opt_raws = tuple(tuple(s + 0 for s in per) for per in tr._opt_raws)
+
+
+@pytest.mark.parametrize("rebind", [_set_data, _load_parameters,
+                                    _load_states, _new_optimizer_state])
+def test_trainer_signature_reuse_misses_after_a_rebind(rebind, tmp_path):
+    """Steps 2..n take the signature nodes built under the step before;
+    whatever rebinds a parameter or the optimizer state makes the next
+    step walk every leaf again, with the result of a trainer that always
+    walks."""
+    def run(always_walk):
+        net, tr = _sig_trainer(optimizer="adam")
+        out, deltas = [], []
+        for i in range(5):
+            if i == 3:
+                rebind(net, tr, tmp_path)
+            if always_walk:
+                tr._sig_memo = None
+            before = _sig_counts()
+            out.append(float(tr.step(*_sig_batch(i)).asscalar()))
+            deltas.append(_sig_delta(before))
+        params = [p.data().asnumpy() for p in net.collect_params().values()]
+        return out, params, deltas
+
+    losses, params, deltas = run(always_walk=False)
+    assert [(d["sig_hits"], d["sig_misses"]) for d in deltas] == \
+        [(0, 1), (1, 0), (1, 0), (0, 1), (1, 0)]
+    # one executable all along: the walk finds the signature it had
+    assert [d["misses"] for d in deltas] == [1, 0, 0, 0, 0]
+    ref_losses, ref_params, ref_deltas = run(always_walk=True)
+    assert all(d["sig_misses"] == 1 for d in ref_deltas)
+    assert losses == ref_losses
+    for a, b in zip(params, ref_params):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_trainer_signature_memo_keeps_no_array_alive():
+    """The remembered nodes hold their arrays weakly: a parameter that is
+    replaced is gone at once, not a step later."""
+    import weakref
+
+    net, tr = _sig_trainer(optimizer="adam", donate=False)
+    tr.step(*_sig_batch(0))
+    p = list(net.collect_params().values())[0]
+    old = weakref.ref(p.data()._data)
+    state = weakref.ref(tr._opt_raws[0][0])
+    p.set_data(p.data() * 0.5)
+    _new_optimizer_state(net, tr, None)
+    assert old() is None and state() is None
+    before = _sig_counts()
+    tr.step(*_sig_batch(1))
+    assert _sig_delta(before)["sig_misses"] == 1
+
+
+def test_trainer_signature_reuse_counts_hits_in_a_steady_loop():
+    net, tr = _sig_trainer()
+    before = _sig_counts()
+    n = 7
+    for i in range(n):
+        tr.step(*_sig_batch(i))
+    assert _sig_delta(before) == {"hits": n - 1, "misses": 1,
+                                  "sig_hits": n - 1, "sig_misses": 1}
+    assert {"sig_hits", "sig_misses"} <= set(C.stats()["trainer"])
+
+
+def test_trainer_signature_reuse_survives_a_new_batch_shape():
+    """The remembered nodes cover params, state and aux only: a batch of
+    another shape reuses them and still finds its own executable."""
+    net, tr = _sig_trainer()
+    tr.step(*_sig_batch(0))
+    tr.step(*_sig_batch(1))
+    before = _sig_counts()
+    loss = float(tr.step(*_sig_batch(2, rows=16)).asscalar())
+    assert _sig_delta(before) == {"hits": 0, "misses": 1, "sig_hits": 1,
+                                  "sig_misses": 0}
+    net2, tr2 = _sig_trainer()
+    for i in range(2):
+        tr2.step(*_sig_batch(i))
+    tr2._sig_memo = None
+    assert float(tr2.step(*_sig_batch(2, rows=16)).asscalar()) == loss
+
+
+def test_trainer_signature_reuse_after_a_nan_skipped_step():
+    net, tr = _sig_trainer()
+    tr.step(*_sig_batch(0))
+    kept = [p.data().asnumpy() for p in net.collect_params().values()]
+    x, y = _sig_batch(1)
+    bad = mx.nd.array(np.full((8, 8), np.nan, np.float32))
+    before = _sig_counts()
+    tr.step(bad, y)
+    assert tr.skipped_steps == 1
+    for a, p in zip(kept, net.collect_params().values()):
+        np.testing.assert_array_equal(a, p.data().asnumpy())
+    loss = float(tr.step(x, y).asscalar())
+    assert _sig_delta(before) == {"hits": 2, "misses": 0, "sig_hits": 2,
+                                  "sig_misses": 0}
+    net2, tr2 = _sig_trainer()
+    tr2.step(*_sig_batch(0))
+    tr2._t += 1   # the skipped step was attempted
+    tr2._sig_memo = None
+    assert float(tr2.step(x, y).asscalar()) == loss
+
+
+@pytest.mark.parametrize("flip", ["dtype", "sharding"])
+def test_trainer_never_uses_a_stale_signature_node(flip):
+    """A parameter that comes back with another dtype or sharding is
+    another array: the step walks it and takes it for what it is (a new
+    executable for the dtype; jit refuses a sharding that contradicts the
+    step's ``in_shardings``, where a stale node would have passed it on to
+    the executable it had)."""
+    import jax
+    import jax.numpy as jnp
+
+    net, tr = _sig_trainer()
+    for i in range(2):
+        tr.step(*_sig_batch(i))
+    h = tr._train_handles[0]
+    before = _sig_counts()
+    if flip == "dtype":
+        h._rebind(h._data.astype(jnp.bfloat16))
+        assert np.isfinite(float(tr.step(*_sig_batch(2)).asscalar()))
+    else:
+        h._rebind(jax.device_put(h._data, tr.mesh.sharding("dp")))
+        with pytest.raises(ValueError, match="does not match the sharding"):
+            tr.step(*_sig_batch(2))
+    assert _sig_delta(before) == {"hits": 0, "misses": 1, "sig_hits": 0,
+                                  "sig_misses": 1}
+    if flip == "dtype":   # and the loop is steady again
+        before = _sig_counts()
+        tr.step(*_sig_batch(3))
+        assert _sig_delta(before)["sig_hits"] == 1
+
+
+def test_trainer_signature_reuse_after_a_resharded_resume(tmp_path):
+    from mxnet_tpu.checkpoint import CheckpointManager
+    from mxnet_tpu.parallel import DeviceMesh
+
+    net, tr = _sig_trainer(optimizer="adam")
+    mgr = CheckpointManager(tmp_path, prefix="sig")
+    for i in range(3):
+        tr.step(*_sig_batch(i))
+    tr.save_checkpoint(mgr, 1)
+    want = float(tr.step(*_sig_batch(3)).asscalar())
+
+    net2, tr2 = _sig_trainer(seed=9, optimizer="adam",
+                             mesh=DeviceMesh({"dp": 4}))
+    for i in range(2):
+        tr2.step(*_sig_batch(i))
+    with pytest.warns(UserWarning, match="topology change"):
+        tr2.resume(mgr, reshard=True)
+    before = _sig_counts()
+    got = float(tr2.step(*_sig_batch(3)).asscalar())
+    assert _sig_delta(before) == {"hits": 1, "misses": 0, "sig_hits": 0,
+                                  "sig_misses": 1}
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    before = _sig_counts()
+    tr2.step(*_sig_batch(4))
+    assert _sig_delta(before)["sig_hits"] == 1

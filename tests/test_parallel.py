@@ -850,3 +850,115 @@ def test_sharded_trainer_instance_lr_seeds_param_scheduler():
                         mesh=DeviceMesh({"dp": 8}))
     assert sched.base_lr == 0.4
     assert tr.learning_rate == 0.4
+
+
+# ------------------------------- the rng stream around the compiled step ---
+
+def _parent_order(monkeypatch):
+    """Make a trainer run the order this one replaced: the host draws
+    ``next_key()`` BEFORE the step, the compiled step uses the key it is
+    handed as it is, and nothing moves the stream afterwards. Three
+    patches around the same trainer code: the step's own ``split`` (the
+    first ``jax.random.split`` of a tracer while the step is traced) hands
+    back its argument, ``current_key`` draws, ``advance`` does nothing."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import random as mxrand
+
+    real_split = jax.random.split
+    traced = []
+
+    def split(key, num=2):
+        if not traced and isinstance(key, jax.core.Tracer):
+            traced.append(key)
+            return jnp.stack([key, key])
+        return real_split(key, num)
+
+    monkeypatch.setattr(jax.random, "split", split)
+    monkeypatch.setattr(mxrand, "current_key", mxrand.next_key)
+    monkeypatch.setattr(mxrand, "advance", lambda: None)
+    return traced
+
+
+@pytest.mark.parametrize("optimizer", ["adam", "sgld"])
+def test_rng_stream_is_the_one_next_key_before_the_step_gave(
+        optimizer, monkeypatch):
+    """The step takes ``split(key)[1]`` of the stream's current key
+    itself and the host advances the stream behind it: losses, parameters
+    and the global key after every step are bit for bit those of a loop
+    that draws ``next_key()`` ahead of the step, with a reseed and eager
+    draws in between (dropout in the net; sgld's noise is a stochastic
+    update rule)."""
+    import jax
+
+    from mxnet_tpu import random as mxrand
+
+    rs = np.random.RandomState(5)
+    x = mx.nd.array(rs.randn(16, 12).astype(np.float32))
+    y = mx.nd.array(rs.randn(16, 4).astype(np.float32))
+
+    def run():
+        np.random.seed(1)
+        mx.random.seed(1)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(32, activation="relu", in_units=12),
+                nn.Dropout(0.4), nn.Dense(4, in_units=32))
+        net.initialize(mx.init.Xavier())
+        tr = ShardedTrainer(net, gloss.L2Loss(), optimizer,
+                            {"learning_rate": 0.01},
+                            mesh=DeviceMesh({"dp": 2}))
+        seen = []
+        for i in range(6):
+            if i == 3:
+                mx.random.seed(77)
+            if i in (2, 3, 5):
+                seen.append(mx.nd.random.uniform(shape=(3,)).asnumpy())
+            seen.append(np.float32(tr.step(x, y).asscalar()))
+            seen.extend(p.data().asnumpy()
+                        for p in net.collect_params().values())
+            seen.append(np.asarray(jax.device_get(mxrand._state.key)))
+        return seen
+
+    got = run()
+    traced = _parent_order(monkeypatch)
+    want = run()
+    assert len(traced) == 1   # the reference's step took its key as given
+    assert len(got) == len(want)
+    for i, (a, b) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(a, b, err_msg=f"item {i}")
+    # the masks differ from step to step: the stream did move
+    assert not np.array_equal(got[-1], got[len(got) // 2 - 1])
+
+
+# ------------------------------------- donated inputs let go under the step ---
+
+def test_donated_inputs_are_gone_when_the_step_returns_unless_aliased():
+    """The step lets go of its donated inputs before the guard's read:
+    once it returns nothing of them is left in distcheck's registry but
+    what the caller kept an alias of, and that alias still raises the
+    param-named error."""
+    from mxnet_tpu.analysis import distcheck
+
+    np.random.seed(0)
+    mx.random.seed(0)
+    net = _make_net()
+    st = ShardedTrainer(net, gloss.L2Loss(), "sgd",
+                        {"learning_rate": 0.05, "momentum": 0.9},
+                        mesh=DeviceMesh({"dp": 2}))
+    rng = np.random.default_rng(1)
+    x = mx.nd.array(rng.normal(size=(8, 16)).astype(np.float32))
+    y = mx.nd.array(rng.normal(size=(8, 4)).astype(np.float32))
+    st.step(x, y)
+    level = distcheck.donated_count()
+    for _ in range(3):
+        st.step(x, y)
+        assert distcheck.donated_count() == level
+    pname = st._param_names[0]
+    stale = mx.nd.NDArray(net.collect_params()[pname].data()._data)
+    st.step(x, y)
+    assert distcheck.donated_count() == level + 1
+    with pytest.raises(distcheck.DonatedBufferError) as ei:
+        stale * 2
+    assert ei.value.name == pname and "step 5" in str(ei.value)
+    del stale, ei
+    assert distcheck.donated_count() == level

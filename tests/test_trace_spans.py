@@ -15,17 +15,18 @@ from mxnet_tpu import faults, gluon, profiler, telemetry, watchdog
 from mxnet_tpu.parallel import DeviceMesh, ShardedTrainer
 from mxnet_tpu.telemetry import steps, trace
 
-#: the children of ``trainer.step``, in the order they run
-STEP_CHILDREN = ["trainer.put_batch", "trainer.rng_key", "trainer.scalars",
-                 "trainer.gather", "trainer.dispatch", "trainer.commit",
-                 "trainer.guard_sync", "trainer.release",
+#: the children of ``trainer.step``, in the order they run: up to
+#: ``trainer.dispatch`` what the launch needs, after ``trainer.guard_sync``
+#: what needs the flag, the rest between the two
+STEP_CHILDREN = ["trainer.put_batch", "trainer.scalars", "trainer.gather",
+                 "trainer.dispatch", "trainer.rng_key", "trainer.commit",
+                 "trainer.release", "trainer.guard_sync",
                  "trainer.bookkeeping"]
 #: which spans each ``steps`` phase is the sum of
 PHASE_SPANS = {"h2d": ["trainer.put_batch"],
-               "host": ["trainer.gather", "trainer.commit",
-                        "trainer.release"],
-               "compute": ["trainer.rng_key", "trainer.scalars",
-                           "trainer.dispatch"],
+               "host": ["trainer.scalars", "trainer.gather",
+                        "trainer.commit", "trainer.release"],
+               "compute": ["trainer.dispatch", "trainer.rng_key"],
                "sync": ["trainer.guard_sync"]}
 
 
@@ -281,8 +282,8 @@ def _nested_step_spans(log_dir, want_steps):
         inside = [e for e in events if e[0].startswith(
             ("trainer.", "compile.")) and lo < e[1] and e[2] < hi]
         assert [e[0] for e in sorted(inside, key=lambda e: e[1])] == \
-            STEP_CHILDREN[:5] + ["compile.signature", "compile.execute"] \
-            + STEP_CHILDREN[5:]
+            STEP_CHILDREN[:4] + ["compile.signature", "compile.execute"] \
+            + STEP_CHILDREN[4:]
 
 
 @pytest.mark.filterwarnings("ignore::DeprecationWarning")
@@ -318,6 +319,83 @@ def test_profiler_device_session_starts_stops_and_holds_the_spans(
     names = {e[0] for evs in _host_events(fname + ".device").values()
              for e in evs}
     assert not [n for n in names if n.startswith("$")]
+
+
+# ------------------------------------- what runs on which side of the line ---
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_guarded_step_launches_nothing_ahead_of_its_program(warm, tmp_path):
+    """With the guard's read the device has nothing queued between two
+    steps: no jax program of the trainer's is launched from the start of
+    ``trainer.step`` to the step's own (jit's ``PjitFunction`` events in
+    the profiler's host plane, not the service's counters), and the
+    stream's advance and the donated inputs' release end before the read
+    begins."""
+    import jax
+
+    trainer, x, y = warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            trainer.step(x, y)
+    finally:
+        jax.profiler.stop_trace()
+    (events,) = [evs for evs in _host_events(str(tmp_path)).values()
+                 if any(e[0] == "trainer.step" for e in evs)]
+    launches = [e for e in events if e[0].startswith("PjitFunction(")]
+    assert {e[0] for e in launches} >= {"PjitFunction(step_fn)"}
+    parents = [e for e in events if e[0] == "trainer.step"]
+    assert len(parents) == 3
+    for _, lo, hi, _ in parents:
+        inside = {e[0]: e for e in events
+                  if e[0].startswith(("trainer.", "compile."))
+                  and lo < e[1] and e[2] < hi}
+        execute = inside["compile.execute"]
+        early = [e[0] for e in launches if lo <= e[1] < execute[1]]
+        assert early == [], early
+        in_step = [e[0] for e in launches if lo <= e[1] < hi]
+        assert in_step[0] == "PjitFunction(step_fn)"
+        # the stream still moves by next_key()'s programs, behind the step
+        assert len(set(in_step)) > 1
+        sync = inside["trainer.guard_sync"]
+        for name in ("trainer.rng_key", "trainer.commit", "trainer.release"):
+            assert execute[2] <= inside[name][1], name
+            assert inside[name][2] <= sync[1], name
+
+
+@pytest.mark.parametrize("options, mesh", [
+    ({"nan_guard": False}, {"dp": 1}), ({"donate": False}, {"dp": 1}),
+    ({}, {"dp": 2}), ({"zero": True}, {"dp": 2})],
+    ids=["noguard", "nodonate", "dp2", "zero"])
+def test_every_trainer_runs_the_one_order(options, mesh):
+    prev = trace.configure(2048)
+    steps.reset()
+    try:
+        mx.random.seed(0)
+        net = gluon.nn.HybridSequential()
+        net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dense(4))
+        net.initialize(mx.init.Xavier())
+        rs = np.random.RandomState(0)
+        x = mx.nd.array(rs.randn(8, 8).astype(np.float32))
+        y = mx.nd.array(rs.randn(8, 4).astype(np.float32))
+        net(x)
+        trainer = ShardedTrainer(net, gluon.loss.L2Loss(), "adam",
+                                 {"learning_rate": 0.01},
+                                 mesh=DeviceMesh(mesh), **options)
+        before = mxcompile.stats().get("trainer", {}).get("sig_hits", 0)
+        trainer.step(x, y)
+        trace.clear()
+        trainer.step(x, y)
+        _, children = _one_step(trace.tail())
+        want = [c for c in STEP_CHILDREN
+                if c != "trainer.guard_sync" or options.get("nan_guard", True)]
+        assert [c["name"] for c in children] == want
+        assert mxcompile.stats()["trainer"]["sig_hits"] == before + 1
+        phases = steps.last()["phases"]
+        assert (phases["sync"] > 0) == options.get("nan_guard", True)
+    finally:
+        trace.configure(prev)
+        steps.reset()
 
 
 # ------------------------------------------------------ compile service ---
