@@ -11,7 +11,7 @@ from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
-           "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
+           "CausalLMLoss", "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss", "CosineEmbeddingLoss"]
 
 
@@ -127,6 +127,24 @@ class SoftmaxCrossEntropyLoss(Loss):
 
 
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
+
+
+class CausalLMLoss(Loss):
+    """Next-token cross-entropy of a language model (beyond the
+    reference): logits (B, S, V) against labels (B, S), the ids that
+    follow each position (the caller shifts them), per sequence the mean
+    over its tokens; the softmax statistics in float32 whatever the
+    logits' type."""
+
+    def __init__(self, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+
+    def hybrid_forward(self, F, pred, label, sample_weight=None):
+        import jax
+
+        with jax.named_scope("lm.head_loss"):
+            loss = F.invoke("_contrib_lm_cross_entropy", pred, label)
+        return _apply_weighting(F, loss, self._weight, sample_weight)
 
 
 class KLDivLoss(Loss):

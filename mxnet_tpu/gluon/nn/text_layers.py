@@ -1,0 +1,257 @@
+"""Blocks of decoder language models (beyond the reference, whose newest
+text model is a post-LN encoder): RMS norm, the gated SiLU feed-forward,
+multi-head latent attention and the sparse expert layer. The ops under
+them are in ``ops/text_ops.py``; ``gluon.model_zoo.text`` builds models of
+them.
+
+The ``jax.named_scope`` names here (``mla.project``, ``mla.attention``,
+``moe.shared``; ``moe.route`` and ``moe.experts`` inside
+``parallel.moe.routed_experts``) are what a join of the device trace with
+the HLO will group by.
+"""
+from __future__ import annotations
+
+import numpy as _np
+
+from ... import autograd
+from ...cached_op import update_state
+from ..block import HybridBlock
+from .basic_layers import Dense
+
+__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "SparseMoE"]
+
+
+def _scope(name):
+    import jax
+
+    return jax.named_scope(name)
+
+
+class RMSNorm(HybridBlock):
+    """``gamma * x / sqrt(mean(x^2) + eps)`` over the last axis, no
+    centring and no bias; statistics in float32."""
+
+    def __init__(self, in_channels, epsilon=1e-6, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init="ones")
+
+    def hybrid_forward(self, F, x, gamma=None):
+        return F.invoke("_contrib_rms_norm", x, gamma, eps=self._epsilon)
+
+    def __repr__(self):
+        return f"RMSNorm({self.gamma.shape[0]}, eps={self._epsilon})"
+
+
+class GatedMLP(HybridBlock):
+    """``down(silu(gate(x)) * up(x))``, no biases."""
+
+    def __init__(self, units, hidden_size, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.gate = Dense(hidden_size, use_bias=False, flatten=False,
+                              in_units=units)
+            self.up = Dense(hidden_size, use_bias=False, flatten=False,
+                            in_units=units)
+            self.down = Dense(units, use_bias=False, flatten=False,
+                              in_units=hidden_size)
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.invoke("_contrib_gated_silu", self.gate(x),
+                                  self.up(x)))
+
+
+class MLAttention(HybridBlock):
+    """Multi-head latent attention (DeepSeek-V2/V3) without the query's
+    low-rank step, causal, over (B, S, units).
+
+    ``q = W_q x`` gives ``num_heads`` heads of ``qk_nope + qk_rope``;
+    ``[c, k_r] = W_kva x`` a latent of ``kv_lora_rank`` and ONE rotary key
+    of ``qk_rope``; ``[k_n, v] = W_kvb RMSNorm(c)`` the heads' keys without
+    position (``qk_nope``) and values (``v_head_dim``). Rotary positions go
+    on ``q``'s last ``qk_rope`` and on ``k_r``, which every head shares;
+    ``k = [k_n, k_r]``. Softmax of ``q k^T / sqrt(qk_nope + qk_rope)``
+    through ``F.contrib.flash_attention``: keys are wider than values, so
+    the flash kernel runs with a value width of its own, in the blocks
+    ``kernels.flash.default_blocks`` gives for the shape.
+    """
+
+    def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, rope_theta=10000.0,
+                 rope_interleave=True, epsilon=1e-6, interpret=False,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads = num_heads
+        self._rank = kv_lora_rank
+        self._nope, self._rope, self._dv = \
+            qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+        self._rope_kwargs = {"theta": float(rope_theta),
+                             "interleave": bool(rope_interleave)}
+        self._interpret = interpret   # the kernel in the interpreter (CPU)
+        with self.name_scope():
+            self.q_proj = Dense(num_heads * (self._nope + self._rope),
+                                use_bias=False, flatten=False,
+                                in_units=units)
+            self.kv_a_proj = Dense(kv_lora_rank + self._rope,
+                                   use_bias=False, flatten=False,
+                                   in_units=units)
+            self.kv_a_norm = RMSNorm(kv_lora_rank, epsilon=epsilon)
+            self.kv_b_proj = Dense(num_heads * (self._nope + self._dv),
+                                   use_bias=False, flatten=False,
+                                   in_units=kv_lora_rank)
+            self.o_proj = Dense(units, use_bias=False, flatten=False,
+                                in_units=num_heads * self._dv)
+
+    def hybrid_forward(self, F, x):
+        from ...kernels.flash import default_blocks
+
+        heads, nope, rope = self._heads, self._nope, self._rope
+        blocks = {}
+        if getattr(x, "shape", None):   # a Symbol has none: the op's own
+            seq = int(x.shape[1])
+            blocks["block_q"], blocks["block_k"] = default_blocks(
+                seq, seq, nope + rope, self._dv)
+
+        def split_heads(t):  # (B, S, H * D) -> (B, H, S, D)
+            return F.transpose(F.reshape(t, shape=(0, 0, heads, -1)),
+                               axes=(0, 2, 1, 3))
+
+        def rotary(t):
+            return F.invoke("_contrib_rotary_embedding", t,
+                            **self._rope_kwargs)
+
+        with _scope("mla.project"):
+            q = split_heads(self.q_proj(x))
+            kv_a = self.kv_a_proj(x)
+            latent = self.kv_a_norm(
+                F.slice_axis(kv_a, axis=-1, begin=0, end=self._rank))
+            k_rope = F.expand_dims(
+                F.slice_axis(kv_a, axis=-1, begin=self._rank, end=None),
+                axis=1)                                     # (B, 1, S, rope)
+            kv = split_heads(self.kv_b_proj(latent))
+            q = F.concat(
+                F.slice_axis(q, axis=-1, begin=0, end=nope),
+                rotary(F.slice_axis(q, axis=-1, begin=nope, end=None)),
+                dim=-1)
+            k = F.concat(
+                F.slice_axis(kv, axis=-1, begin=0, end=nope),
+                F.broadcast_axis(rotary(k_rope), axis=(1,), size=(heads,)),
+                dim=-1)
+            v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
+        with _scope("mla.attention"):
+            out = F.contrib.flash_attention(
+                q, k, v, scale=float((nope + rope) ** -0.5), causal=True,
+                interpret=self._interpret, **blocks)
+        with _scope("mla.project"):
+            out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                            shape=(0, 0, -1))
+            return self.o_proj(out)
+
+    def __repr__(self):
+        return (f"MLAttention(heads={self._heads}, rank={self._rank}, "
+                f"qk={self._nope}|{self._rope}, v={self._dv}, "
+                f"rope={self._rope_kwargs})")
+
+
+class SparseMoE(HybridBlock):
+    """Sparse expert layer, DeepSeek-V3 style: a sigmoid router over
+    ``num_experts`` with a selection bias (``e_score_correction_bias``, a
+    buffer outside the gradient), ``top_k`` experts a token, their weights
+    renormalised and scaled; every expert a gated SiLU MLP of
+    ``hidden_size``; ``num_shared`` shared experts (one MLP of
+    ``num_shared * hidden_size``) that see every token.
+
+    ``experts_held=(first, count)`` makes this the share of an
+    expert-parallel layer that one device holds: the router keeps its
+    width, only ``count`` experts' weights exist here, and the output is
+    their part of the routed sum plus the shared experts' (which every
+    device computes alike). The default holds all: the whole layer.
+
+    In training each call adds to three counters (auxiliary state, like
+    BatchNorm's running statistics): ``load_pairs`` (count,) the (token,
+    expert) pairs each held expert got, ``load_peak`` the busiest held
+    expert's pairs, summed over calls, and ``load_calls``. ``expert_load``
+    reads them.
+    """
+
+    def __init__(self, units, hidden_size, num_experts, top_k,
+                 num_shared=0, routed_scaling_factor=1.0, norm_topk=True,
+                 experts_held=None, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        first, count = experts_held or (0, num_experts)
+        if not (0 <= first and count > 0 and first + count <= num_experts):
+            raise ValueError(
+                f"experts_held {experts_held} lies outside the "
+                f"{num_experts} experts")
+        self._held = (int(first), int(count))
+        self._num_experts = num_experts
+        self._route_kwargs = {
+            "top_k": int(top_k), "first_expert": int(first),
+            "scale": float(routed_scaling_factor),
+            "norm_topk": bool(norm_topk)}
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts, units))
+            self.router_bias = self.params.get(
+                "router_bias", shape=(num_experts,), init="zeros",
+                grad_req="null", differentiable=False)
+            self.gate_weight = self.params.get(
+                "gate_weight", shape=(count, units, hidden_size))
+            self.up_weight = self.params.get(
+                "up_weight", shape=(count, units, hidden_size))
+            self.down_weight = self.params.get(
+                "down_weight", shape=(count, hidden_size, units))
+            self.load_pairs = self.params.get(
+                "load_pairs", shape=(count,), init="zeros",
+                grad_req="null", differentiable=False)
+            self.load_peak = self.params.get(
+                "load_peak", shape=(1,), init="zeros", grad_req="null",
+                differentiable=False)
+            self.load_calls = self.params.get(
+                "load_calls", shape=(1,), init="zeros", grad_req="null",
+                differentiable=False)
+            self.shared = GatedMLP(units, num_shared * hidden_size) \
+                if num_shared else None
+
+    _FLOAT32 = ("router_bias", "load_pairs", "load_peak", "load_calls")
+
+    def cast(self, dtype):
+        """The selection bias and the counters stay float32 (the router
+        scores are float32; a bfloat16 counter stops counting at 256)."""
+        self._clear_cached_op()
+        for child in self._children.values():
+            child.cast(dtype)
+        for name, p in self._reg_params.items():
+            p.cast(_np.float32 if name in self._FLOAT32 else dtype)
+
+    def hybrid_forward(self, F, x, router_weight=None, router_bias=None,
+                       gate_weight=None, up_weight=None, down_weight=None,
+                       load_pairs=None, load_peak=None, load_calls=None):
+        y, load = F.invoke(
+            "_contrib_sparse_moe", x, router_weight, router_bias,
+            gate_weight, up_weight, down_weight, **self._route_kwargs)
+        if autograd.is_training():
+            update_state(load_pairs, load_pairs + load)
+            update_state(load_peak, load_peak + load.max())
+            update_state(load_calls, load_calls + 1)
+        if self.shared is not None:
+            with _scope("moe.shared"):
+                y = y + self.shared(x)
+        return y
+
+    def expert_load(self):
+        """``{"first_expert", "pairs": [per held expert], "peak",
+        "calls"}`` since the counters were last zeroed (one read of the
+        device)."""
+        pairs, peak, calls = (
+            p.data().asnumpy().astype(float)
+            for p in (self.load_pairs, self.load_peak, self.load_calls))
+        return {"first_expert": self._held[0], "pairs": pairs.tolist(),
+                "peak": float(peak[0]), "calls": float(calls[0])}
+
+    def __repr__(self):
+        return (f"SparseMoE(experts={self._num_experts}, "
+                f"held={self._held}, {self._route_kwargs}, "
+                f"shared={self.shared!r})")

@@ -64,7 +64,7 @@ __all__ = ["KernelEntry", "register_kernel", "entry", "families",
 
 _FAMILIES: dict = {}
 _lock = threading.Lock()
-_stats: dict = {}          # family -> {"kernel": n, "xla": n, reasons: {}}
+_stats: dict = {}          # family -> {"kernel": n, "xla": n, reasons, buckets}
 _warned_families = set()   # fallback warned once per family (latch)
 _seen_buckets: dict = {}   # family -> set of bucket keys (distcheck pass 4)
 
@@ -136,12 +136,12 @@ def on_tpu():
     return jax.devices()[0].platform == "tpu"
 
 
-def _count(family, choice, reason):
+def _count(family, choice, reason, bucket=None):
     with _lock:
         rec = _stats.setdefault(family, {"kernel": 0, "xla": 0,
-                                         "reasons": {}})
+                                         "reasons": {}, "buckets": {}})
         rec[choice] += 1
-        rec["reasons"][reason] = rec["reasons"].get(reason, 0) + 1
+        _count_by(rec, choice, reason, bucket)
     try:
         from ..telemetry import registry as _registry
 
@@ -230,8 +230,8 @@ def dispatch(family, *args, interpret=None, **kwargs):
     shapes and process state, so it is baked into the traced executable
     exactly like any other static argument."""
     e = _FAMILIES[family]
-    choice, reason, _bucket = _decide(e, args, kwargs, interpret)
-    _count(family, choice, reason)
+    choice, reason, bucket = _decide(e, args, kwargs, interpret)
+    _count(family, choice, reason, bucket)
     if choice == "kernel":
         # Pallas has no native CPU lowering: unless the caller said
         # which, off-TPU the kernel runs in the interpreter (numerics
@@ -252,11 +252,27 @@ def choice_for(family, *args, **kwargs):
     return choice, reason
 
 
+def _count_by(rec, choice, reason, bucket):
+    """One decision by its reason and, where it got that far, by its shape
+    bucket. (Down here, and ``_count`` no longer than it was: the Mosaic
+    payload of a kernel carries the source lines of its callers, so a line
+    moved above ``dispatch`` recompiles every program that holds one.)"""
+    rec["reasons"][reason] = rec["reasons"].get(reason, 0) + 1
+    if bucket is not None:
+        per = rec["buckets"].setdefault(bucket, {"kernel": 0, "xla": 0})
+        per[choice] += 1
+
+
 def dispatch_stats():
-    """Per-family dispatch decision counts (process-local)."""
+    """Per-family dispatch decision counts (process-local), in all and
+    by shape bucket (the family's ``bucket`` key; decisions taken before
+    a bucket exists, e.g. an unsupported shape, count in the totals
+    only)."""
     with _lock:
         return {f: {"kernel": r["kernel"], "xla": r["xla"],
-                    "reasons": dict(r["reasons"])}
+                    "reasons": dict(r["reasons"]),
+                    "buckets": {b: dict(c)
+                                for b, c in sorted(r["buckets"].items())}}
                 for f, r in sorted(_stats.items())}
 
 

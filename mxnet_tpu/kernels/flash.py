@@ -56,7 +56,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     def compute():
         q = q_ref[0].astype(jnp.float32)  # (block_q, d)
         k_blk = k_ref[0].astype(jnp.float32)  # (block_k, d)
-        v_blk = v_ref[0].astype(jnp.float32)
+        v_blk = v_ref[0].astype(jnp.float32)  # (block_k, dv)
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -95,11 +95,11 @@ def flash_forward(q, k, v, scale, causal, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     bh = b * h
     q3 = q.reshape(bh, sq, d)
     k3 = k.reshape(bh, sk, d)
-    v3 = v.reshape(bh, sk, d)
+    v3 = v.reshape(bh, sk, dv)
     n_kb = sk // block_k
     grid = (bh, sq // block_q, n_kb)
     kernel = _functools.partial(_flash_kernel, scale=scale, causal=causal,
@@ -111,18 +111,18 @@ def flash_forward(q, k, v, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q3, k3, v3)
-    return out.reshape(b, h, sq, d)
+    return out.reshape(b, h, sq, dv)
 
 
 def _causal_mask(s, qi, ci, bq, bk):
@@ -137,15 +137,15 @@ def _flash_backward(q, k, v, out, cot, scale, causal, bq, bk):
     memory stays O(S * block) — no (S, S) tensor ever exists, matching
     the forward kernel's memory contract for training too."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     nbq, nbk = sq // bq, sk // bk
     f32 = jnp.float32
 
     def per_head(q2, k2, v2, o2, do2):
         qb = q2.reshape(nbq, bq, d).astype(f32)
         kb = k2.reshape(nbk, bk, d).astype(f32)
-        vb = v2.reshape(nbk, bk, d).astype(f32)
-        dob = do2.reshape(nbq, bq, d).astype(f32)
+        vb = v2.reshape(nbk, bk, dv).astype(f32)
+        dob = do2.reshape(nbq, bq, dv).astype(f32)
         Dvec = (do2.astype(f32) * o2.astype(f32)).sum(-1).reshape(nbq, bq)
 
         # pass 1: per-row max and normalizer (scan over k blocks)
@@ -200,20 +200,20 @@ def _flash_backward(q, k, v, out, cot, scale, causal, bq, bk):
                 return (dk_acc + ds.T @ qblk * scale,
                         dv_acc + p.T @ doblk), None
 
-            init = (jnp.zeros((bk, d), f32), jnp.zeros((bk, d), f32))
+            init = (jnp.zeros((bk, d), f32), jnp.zeros((bk, dv), f32))
             (dk_acc, dv_acc), _ = jax.lax.scan(
                 step, init, (qb, dob, m, l, Dvec, jnp.arange(nbq)))
             return dk_acc, dv_acc
 
-        dk, dv = jax.vmap(dkv_one)(jnp.arange(nbk), kb, vb)
-        return (dq.reshape(sq, d), dk.reshape(sk, d), dv.reshape(sk, d))
+        dk, dv_ = jax.vmap(dkv_one)(jnp.arange(nbk), kb, vb)
+        return dq.reshape(sq, d), dk.reshape(sk, d), dv_.reshape(sk, dv)
 
-    flat = lambda x: x.reshape(b * h, x.shape[2], d)  # noqa: E731
-    dq, dk, dv = jax.vmap(per_head)(flat(q), flat(k), flat(v), flat(out),
-                                    flat(cot))
+    flat = lambda x: x.reshape((b * h,) + x.shape[2:])  # noqa: E731
+    dq, dk, dv_ = jax.vmap(per_head)(flat(q), flat(k), flat(v), flat(out),
+                                     flat(cot))
     return (dq.reshape(q.shape).astype(q.dtype),
             dk.reshape(k.shape).astype(k.dtype),
-            dv.reshape(v.shape).astype(v.dtype))
+            dv_.reshape(v.shape).astype(v.dtype))
 
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -250,6 +250,12 @@ def _xla(q, k, v, scale, causal=False, block_q=128, block_k=128):
     return flash_attention_reference(q, k, v, scale, causal)
 
 
+# Queries and keys share one head width ``d``; values (and so the output)
+# may have their own, ``dv`` (latent attention: 192 | 128). Where the two
+# are equal the traced program is what it was before ``dv`` existed, and so
+# is every line number above this one: a Mosaic call's payload carries the
+# source lines of its callers, and a moved line is a new executable.
+
 def _pow2(n):
     p = 1
     while p < n:
@@ -259,23 +265,44 @@ def _pow2(n):
 
 def _bucket(q, k, v, scale, causal=False, block_q=128, block_k=128):
     """Sequence lengths and batch*heads round UP to powers of two (one
-    table row covers the whole bucket); head dim and dtype are exact —
-    they change the kernel's tiling, not just its trip count."""
+    table row covers the whole bucket); head dims and dtype are exact —
+    they change the kernel's tiling, not just its trip count. A value
+    width of its own is named after the query/key width (``d192v128``);
+    equal widths keep the key they always had."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
-    return (f"bh{_pow2(b * h)}_sq{_pow2(sq)}_sk{_pow2(sk)}_d{d}_"
+    sk, dv = k.shape[2], v.shape[3]
+    width = f"d{d}" if dv == d else f"d{d}v{dv}"
+    return (f"bh{_pow2(b * h)}_sq{_pow2(sq)}_sk{_pow2(sk)}_{width}_"
             f"{jnp.dtype(q.dtype).name}_c{int(bool(causal))}_"
             f"q{block_q}k{block_k}")
 
 
 def _supports(q, k, v, scale, causal=False, block_q=128, block_k=128):
     """The statically checkable Mosaic constraints: S divisible by the
-    block sizes, D a multiple of 8 up to 512, rank-4 input."""
-    if q.ndim != 4:
+    block sizes, both head widths a multiple of 8 up to 512, rank-4
+    inputs, keys as wide as the queries and as many as the values."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         return False
-    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
+    sq, sk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
     return (sq % block_q == 0 and sk % block_k == 0
-            and d % 8 == 0 and 0 < d <= 512)
+            and k.shape[3] == d and v.shape[2] == sk
+            and d % 8 == 0 and 0 < d <= 512
+            and dv % 8 == 0 and 0 < dv <= 512)
+
+
+def default_blocks(sq, sk, d, dv):
+    """``(block_q, block_k)`` for a caller with no preference of its own.
+    Equal widths keep the 128 x 128 they always had (the only size
+    measured at d64). With a value width of its own, up to 256 wide: the
+    largest power of two up to 1024 that divides the length. Measured on
+    a v5e at 2 x 32 heads x 4096, 192 | 128, causal, forward + backward:
+    155 ms at 128 x 128, 73 at 512, 65 at 1024 (the scanned backward
+    computes every block pair, so fewer and larger blocks win twice);
+    2048 x 1024 does not fit VMEM (PERF.md, PR 26)."""
+    if d == dv or max(d, dv) > 256:
+        return 128, 128
+    # s & -s: the largest power of two that divides s
+    return (max(128, min(sq & -sq, 1024)), max(128, min(sk & -sk, 1024)))
 
 
 def _register():

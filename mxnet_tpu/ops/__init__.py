@@ -17,6 +17,7 @@ from . import la_op  # noqa: F401  (linalg_* suite)
 from . import contrib_ops  # noqa: F401  (fft/detection/roi/stn/misc)
 from . import output_ops  # noqa: F401  (regression/SVM loss heads)
 from . import pallas_ops  # noqa: F401  (flash attention TPU kernel)
+from . import text_ops  # noqa: F401  (RMS norm/rotary/gated SiLU/sparse MoE/LM loss)
 from . import custom  # noqa: F401  (Custom op — user-defined Python operators)
 
 __all__ = ["Operator", "register", "get", "list_ops", "apply_op", "infer_output"]

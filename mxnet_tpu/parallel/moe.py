@@ -17,17 +17,27 @@ compute.)
 
     fn = moe_apply(expert_fn, mesh)
     y, aux_loss = fn(stacked_expert_params, router_w, x)
+
+``moe_apply`` is that top-1, one-expert-per-device formulation. The layer
+today's sparse models use (top-k of many experts, several held per
+device, work that follows the rows routed here, no capacity and no
+dropped token) is :func:`routed_experts` below; ``gluon.nn.SparseMoE`` is
+the block over it.
 """
 from __future__ import annotations
 
-__all__ = ["moe_apply", "stack_expert_params"]
+__all__ = ["moe_apply", "stack_expert_params", "route_topk",
+           "routed_experts"]
 
 from .pipeline import _check_stacked_leading_dim
 from .pipeline import stack_stage_params as stack_expert_params
 
 
 def moe_apply(expert_fn, mesh, axis="ep"):
-    """Build the expert-parallel MoE callable.
+    """Build the expert-parallel MoE callable (top-1, one expert per
+    device, every device computes every token; for top-k over many
+    experts with several held per device see :func:`routed_experts` and
+    ``gluon.nn.SparseMoE``).
 
     Parameters
     ----------
@@ -82,3 +92,128 @@ def moe_apply(expert_fn, mesh, axis="ep"):
         return y, jnp.reshape(aux, ())
 
     return run
+
+
+# ---------------------------------------------- sparse top-k expert layer --
+
+def route_topk(x, router_w, bias, top_k, scale, norm_topk=True):
+    """Sigmoid router with a selection bias (the ``noaux_tc`` recipe of
+    DeepSeek-V3, one group): ``s = sigmoid(x W^T)`` in float32 over every
+    expert; the ``top_k`` experts are chosen by ``s + bias``; their weights
+    are ``s`` alone (the bias steers the choice and nothing else),
+    renormalised to sum to one where ``norm_topk``, times ``scale``.
+
+    x (T, h), router_w (E, h), bias (E,) -> (ids (T, k) int32,
+    weights (T, k) float32)."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router_w.astype(jnp.float32).T))
+    _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    if norm_topk:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * scale
+
+
+def _pair_gathers(top_k):
+    """The two gathers between token order and expert order, each with a
+    gather for its transpose. ``order`` is a permutation of the T * top_k
+    (token, choice) pairs and ``inv`` its inverse, so every cotangent row
+    is found by index and nothing has to be scatter-added (a row-wise
+    scatter-add serialises on the TPU, a gather does not)."""
+    import jax
+
+    @jax.custom_vjp
+    def to_experts(x, order, inv):
+        # sorted row r is pair order[r], of token order[r] // top_k
+        return x[order // top_k]
+
+    def to_experts_fwd(x, order, inv):
+        return x[order // top_k], inv
+
+    def to_experts_bwd(inv, g):
+        import jax.numpy as jnp
+
+        per_token = g[inv].reshape(-1, top_k, g.shape[-1])
+        return (per_token.astype(jnp.float32).sum(axis=1).astype(g.dtype),
+                None, None)
+
+    to_experts.defvjp(to_experts_fwd, to_experts_bwd)
+
+    @jax.custom_vjp
+    def to_tokens(ys, order, inv):
+        return ys[inv]
+
+    def to_tokens_fwd(ys, order, inv):
+        return ys[inv], order
+
+    def to_tokens_bwd(order, g):
+        return g[order], None, None
+
+    to_tokens.defvjp(to_tokens_fwd, to_tokens_bwd)
+    return to_experts, to_tokens
+
+
+def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
+                   first_expert=0, scale=1.0, norm_topk=True):
+    """The part of a sparse expert layer that the experts held here give.
+
+    ``x`` is (T, h). The router is as wide as the model (``router_w``
+    (E, h), ``bias`` (E,)); this device holds the ``n`` experts
+    ``first_expert .. first_expert + n - 1`` as stacked gated-SiLU MLPs:
+    ``w_gate``/``w_up`` (n, h, f), ``w_down`` (n, f, h). Every token is
+    routed over all E experts; of its ``top_k`` (token, expert) pairs the
+    ones that fall on a held expert are computed and summed with their
+    router weights, the others are left to the devices that hold them
+    (with every expert held this is the whole layer). On one device
+    nothing is exchanged.
+
+    The pairs are sorted by expert and multiplied group by group
+    (``jax.lax.ragged_dot``: on the TPU a grouped-matmul kernel whose work
+    follows the rows of each group). There is no capacity: the row buffer
+    has room for every pair (T * top_k rows), so no token is ever dropped,
+    however uneven the routing; rows past the held pairs are skipped by
+    the grouped product and masked.
+
+    Returns ``(y (T, h) in x's type, load (n,) float32)``: ``load[e]`` is
+    the number of pairs routed to held expert ``e`` in this call.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    t, h = x.shape
+    n = w_gate.shape[0]
+    with jax.named_scope("moe.route"):
+        ids, w = route_topk(x, router_w, bias, top_k, scale, norm_topk)
+        local = ids - first_expert
+        held = (local >= 0) & (local < n)
+        # pairs on absent experts sort behind every held group
+        group = jnp.where(held, local, n).reshape(-1)          # (T*k,)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+        live = (jnp.arange(t * top_k) < sizes.sum())[:, None]
+    to_experts, to_tokens = _pair_gathers(top_k)
+
+    def experts(x, w_gate, w_up, w_down):
+        # what the grouped product leaves in the rows it skips is not
+        # defined, forward or backward: masked on the way in (for the
+        # cotangent of x) and on the way out
+        xs = jnp.where(live, to_experts(x, order, inv), 0)
+        gate = jax.lax.ragged_dot(xs, w_gate, sizes)
+        up = jax.lax.ragged_dot(xs, w_up, sizes)
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(xs.dtype) * up
+        ys = jax.lax.ragged_dot(act, w_down, sizes)
+        return to_tokens(jnp.where(live, ys, 0), order, inv)
+
+    with jax.named_scope("moe.experts"):
+        # the expert-ordered copies (T * top_k rows of h, and of f thrice)
+        # are rebuilt in the backward pass from x and the permutation
+        # rather than kept: they would be most of the layer's activations
+        ys = jax.checkpoint(experts)(x, w_gate, w_up, w_down)
+        wk = jnp.where(held, w, 0.0)[:, :, None]
+        y = (ys.reshape(t, top_k, h).astype(jnp.float32) * wk).sum(axis=1)
+    return y.astype(x.dtype), sizes.astype(jnp.float32)

@@ -1,0 +1,102 @@
+"""Kernels of the main path compiled at their real widths for a DESCRIBED
+v5e (the TPU's compiler is installed here; nothing runs, nothing printed
+is a measurement): what Mosaic or the chip's memory would refuse on the
+chip fails here, at no chip time. Everything built from the topology is
+built inside the fixtures, so every xdist worker collects the same tests
+and only the one that runs this file loads the TPU's library."""
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def quiet_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache; keep it out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (1024, 1024)])
+def test_flash_192_128_compiles_forward_and_backward(one_chip, quiet_cache,
+                                                     blocks):
+    """Latent attention at the benchmark's shape: 2 x 32 heads, 4096
+    positions, keys 192 wide and values 128, causal, bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import flash
+
+    bq, bk = blocks
+    q = _shape((2, 32, 4096, 192), jnp.bfloat16, one_chip)
+    v = _shape((2, 32, 4096, 128), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q_, k_, v_, cot):
+        out, vjp = jax.vjp(
+            lambda *t: flash._kernel(*t, 192 ** -0.5, causal=True,
+                                     block_q=bq, block_k=bk), q_, k_, v_)
+        return (out,) + vjp(cot)
+
+    compiled = jax.jit(fwd_bwd).lower(q, q, v, v).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+    out, dq, dk, dv = jax.eval_shape(fwd_bwd, q, q, v, v)
+    assert out.shape == v.shape and dq.shape == q.shape \
+        and dk.shape == q.shape and dv.shape == v.shape
+
+
+def test_routed_experts_compile_to_grouped_kernels(one_chip, quiet_cache):
+    """The expert layer at the benchmark's widths (8,192 tokens, 128-way
+    router, top-6, 16 held experts of 2048 x 768): the three products of
+    the forward pass and their transposes are Mosaic grouped-matmul
+    calls, not 16 dense passes."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel.moe import routed_experts
+
+    bf = jnp.bfloat16
+    args = (_shape((8192, 2048), bf, one_chip),
+            _shape((128, 2048), bf, one_chip),
+            _shape((128,), jnp.float32, one_chip),
+            _shape((16, 2048, 768), bf, one_chip),
+            _shape((16, 2048, 768), bf, one_chip),
+            _shape((16, 768, 2048), bf, one_chip))
+
+    def loss(*a):
+        y, load = routed_experts(*a, top_k=6, first_expert=0, scale=2.448)
+        return y.astype(jnp.float32).sum(), load
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5),
+                                has_aux=True)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 9
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30
