@@ -209,6 +209,16 @@ def _kernel_cases():
         q, k, v = f32(1, 2, 128, 64), f32(1, 2, 128, 64), f32(1, 2, 128, 64)
         return (q, k, v), {"scale": 0.125, "causal": True}
 
+    def flash_bwd():
+        # what the forward hands its backward, from the dense softmax
+        from mxnet_tpu.kernels.flash import (flash_attention_reference,
+                                             row_log_sum_exp)
+
+        (q, k, v), kw = flash()
+        out = flash_attention_reference(q, k, v, kw["scale"], kw["causal"])
+        lse = row_log_sum_exp(q, k, kw["scale"], kw["causal"])
+        return (q, k, v, out, lse, f32(1, 2, 128, 64)), kw
+
     def opt_sgd():
         n = 65536
         return (f32(n), f32(n), f32(n), jnp.float32(0.05)), \
@@ -238,7 +248,8 @@ def _kernel_cases():
         codes = jnp.asarray(r.integers(-4, 5, 65536), dtype=jnp.int8)
         return (codes,), {"thr": 0.5}
 
-    return [("flash_attention", flash), ("opt_sgd", opt_sgd),
+    return [("flash_attention", flash), ("flash_attention_bwd", flash_bwd),
+            ("opt_sgd", opt_sgd),
             ("opt_adam", opt_adam), ("int8_gemm", int8_gemm),
             ("decode_attention", decode), ("twobit_compress", twobit_c),
             ("twobit_decompress", twobit_d)]

@@ -13,8 +13,8 @@ One process, one command, no arguments: ``python chip_smoke.py``. It
 4. runs every registered Pallas kernel family through ``kernels.dispatch``
    forced onto the Mosaic-compiled kernel against its XLA baseline;
 5. takes one ``TransformerEncoderCell`` step at BERT-base width, so flash
-   attention's Pallas forward and scanned backward compile inside a real
-   program;
+   attention's Pallas forward and its backward kernels compile inside a
+   real program;
 6. with more than one chip, repeats the ResNet-50 step data-parallel over
    all of them.
 
@@ -332,6 +332,58 @@ def _kernel_cases(attn, decode, opt, gemm, seed=2):
     return cases
 
 
+def _backward_cases(attn, seed=4):
+    """The attention backward's family at both widths the benchmark's
+    cells run (equal, no mask; keys half as wide again as values, causal),
+    in f32 and bf16: ``((label, family, arrays, kwargs, dtype), dense)``.
+    The forward's output and row log-sum-exp it is handed, and the
+    gradient ``dense`` it is also held to, come from the dense float32
+    softmax at the highest precision, not from a kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels.flash import (flash_attention_reference,
+                                         row_log_sum_exp)
+
+    r = np.random.default_rng(seed)
+    b, h, s, d = attn
+    out = []
+    for dt in (jnp.float32, jnp.bfloat16):
+        for dk, dv, causal in ((d, d, False), (3 * d, 2 * d, True)):
+            q, k, v, cot = (
+                jnp.asarray(r.standard_normal((b, h, s, w),
+                                              dtype=np.float32)).astype(dt)
+                for w in (dk, dk, dv, dv))
+            scale = dk ** -0.5
+            with jax.default_matmul_precision("highest"):
+                up = [x.astype(jnp.float32) for x in (q, k, v, cot)]
+                o, vjp = jax.vjp(lambda *t: flash_attention_reference(
+                    *t, scale, causal), *up[:3])
+                dense = vjp(up[3])
+                lse = row_log_sum_exp(up[0], up[1], scale, causal)
+            label = (f"flash_attention_bwd/{jnp.dtype(dt).name}/"
+                     f"d{dk}v{dv}")
+            out.append(((label, "flash_attention_bwd",
+                         (q, k, v, o.astype(dt), lse, cot),
+                         {"scale": scale, "causal": causal}, dt), dense))
+    return out
+
+
+def _close_gradient(got, want, dt):
+    """``_close`` at what ``flash_attention_bwd`` registers for the chip:
+    the largest error over the largest |gradient|, 1e-4 in f32 (a sum over
+    a thousand keys of Mosaic's float32 matmul passes read 2.8e-5, my chip
+    run, PR 27; an elementwise atol does not scale with the sum) and 2e-2
+    in bf16 (read 3.3e-3 to 4.6e-3)."""
+    import jax.numpy as jnp
+
+    got = np.asarray(got).astype(np.float32)
+    want = np.asarray(want).astype(np.float32)
+    err = float(np.abs(got - want).max())
+    tol = 1e-4 if dt == jnp.float32 else 2e-2
+    return err, err <= tol * float(np.abs(want).max()), tol
+
+
 def _close(got, want, dt):
     """(max abs error, inside tolerance?, the tolerance applied). The
     elementwise and integer families register bit-exactness (dt None);
@@ -352,8 +404,9 @@ def _close(got, want, dt):
 def phase_kernels(*, attn, decode, opt, gemm, interpret):
     """Every registered family through ``kernels.dispatch`` FORCED onto
     its kernel (``interpret=False``: compiled by Mosaic; True only for the
-    CPU test) and compared with the family's XLA baseline. Both sides are
-    traced at the highest matmul precision: the registered tolerances are
+    CPU test) and compared with the family's XLA baseline (the attention
+    backward also with the dense gradient). Both sides are traced at the
+    highest matmul precision: the registered tolerances are
     statements about the algorithm, and at the TPU's default (bf16 passes
     for an f32 matmul) kernel and baseline each sit ~1e-2 from the truth
     (my chip run, PR 21). The default-precision variant of flash compiles
@@ -365,6 +418,10 @@ def phase_kernels(*, attn, decode, opt, gemm, interpret):
 
     clock = _Clock()
     cases = _kernel_cases(attn, decode, opt, gemm)
+    dense = {}      # label -> the dense gradient a backward is also held to
+    for case, gradient in _backward_cases(attn):
+        cases.append(case)
+        dense[case[0]] = gradient
     missing = set(kernels.families()) - {c[1] for c in cases}
     if missing:
         raise AssertionError(f"no smoke case for families {missing}")
@@ -390,8 +447,11 @@ def phase_kernels(*, attn, decode, opt, gemm, interpret):
         fns.append((kfn, arrays))
         got_l = got if isinstance(got, tuple) else (got,)
         want_l = want if isinstance(want, tuple) else (want,)
-        errs, oks, tols = zip(*(_close(a, b, dt)
-                                for a, b in zip(got_l, want_l)))
+        close = _close_gradient if label in dense else _close
+        pairs = list(zip(got_l, want_l))
+        if label in dense:  # a backward: the scan AND the dense gradient
+            pairs += zip(got_l, dense[label])
+        errs, oks, tols = zip(*(close(a, b, dt) for a, b in pairs))
         results[label] = {"ok": all(oks), "max_abs_err": max(errs),
                           "tolerance": tols[0]}
         if not all(oks):
@@ -417,8 +477,9 @@ def phase_kernels(*, attn, decode, opt, gemm, interpret):
 def phase_encoder(*, units, heads, hidden, seq, batch, dtype, steps=2,
                   seed=3):
     """One ``TransformerEncoderCell`` through ``ShardedTrainer``: on a
-    TPU the untuned dispatch takes the Pallas flash forward and its
-    scanned backward, inside one compiled train step."""
+    TPU the untuned dispatch takes the Pallas flash forward and, as a
+    decision of its own, the backward's kernels, inside one compiled
+    train step."""
     import jax
 
     import mxnet_tpu as mx
@@ -462,6 +523,8 @@ def phase_encoder(*, units, heads, hidden, seq, batch, dtype, steps=2,
             "loss_last": round(losses[-1], 4),
             "flash_dispatch": {"kernel": flash.get("kernel", 0),
                                "xla": flash.get("xla", 0)},
+            "flash_backward_dispatch": kernels.dispatch_stats().get(
+                "flash_attention_bwd", {}).get("buckets", {}),
             **clock.fields()}
 
 

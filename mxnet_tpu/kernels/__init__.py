@@ -29,7 +29,11 @@ Families shipped (docs/PERFORMANCE.md "Pallas kernel layer"):
 =================  ====================================================
 flash_attention    blocked online-softmax attention (moved here from
                    ``ops/pallas_ops.py``; that module remains the op
-                   registration shim)
+                   registration shim); also writes the rows' log-sum-exp
+flash_attention_   its backward, a decision of its own taken inside the
+bwd                forward's ``custom_vjp``: a dK/dV and a dQ Pallas call
+                   over the block pairs under the diagonal, or the
+                   scanned float32 recurrence (its XLA side)
 opt_sgd/opt_adam   fused optimizer step — update+decay(+master cast)
                    in one kernel, wired into the ShardedTrainer update
                    rules (``parallel/opt_rules.py``)
@@ -306,7 +310,7 @@ def token_salt():
 
 
 # family registrations (import order is alphabetical, not load-bearing)
-from . import flash  # noqa: E402,F401  (flash_attention)
+from . import flash  # noqa: E402,F401  (flash_attention, flash_attention_bwd)
 from . import opt_step  # noqa: E402,F401  (opt_sgd / opt_adam)
 from . import int8_gemm  # noqa: E402,F401  (int8_gemm)
 from . import decode_attention  # noqa: E402,F401  (decode_attention)

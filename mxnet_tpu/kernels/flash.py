@@ -1,12 +1,19 @@
 """Flash attention — blocked online-softmax attention (registry family
 ``flash_attention``).
 
-Migrated verbatim from ``ops/pallas_ops.py`` (PR 8); that module is now
-the op-registration shim calling :func:`mxnet_tpu.kernels.dispatch`.
+Migrated from ``ops/pallas_ops.py`` (PR 8); that module is now the
+op-registration shim calling :func:`mxnet_tpu.kernels.dispatch`.
 Forward runs the Pallas kernel (VMEM-blocked, MXU matmuls per tile, the
-(S, S) score matrix never materializes in HBM); backward is the blocked
-flash recurrence in pure JAX (custom_vjp recomputing probabilities
-tile-by-tile), so training memory stays O(S*block) end to end.
+(S, S) score matrix never materializes in HBM) and also writes each
+query row's log-sum-exp. The backward is a family of its own,
+``flash_attention_bwd``: one fused Pallas call (two, dK/dV then dQ, where
+a head's dQ does not fit VMEM) that recomputes the probabilities tile by
+tile from ``(q, k, v, out, lse, d_out)``, only over the block pairs at or
+under the diagonal when causal, with MXU operands in the inputs' dtype
+and float32 accumulation; its XLA side is
+the scanned float32 recurrence this module always had
+(:func:`_flash_backward`). Training memory stays O(S*block) end to end
+on either side.
 
 Tolerance vs the XLA baseline (dense softmax reference): f32 inputs
 agree to rtol=2e-5/atol=2e-5 — the kernel accumulates in f32 exactly
@@ -21,7 +28,9 @@ import functools as _functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention_reference", "flash_forward"]
+__all__ = ["flash_attention_reference", "flash_forward",
+           "flash_forward_lse", "flash_backward_kernel", "row_log_sum_exp",
+           "default_blocks", "backward_blocks"]
 
 
 def flash_attention_reference(q, k, v, scale, causal):
@@ -35,13 +44,24 @@ def flash_attention_reference(q, k, v, scale, causal):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale, causal, block_q, block_k, n_kb):
+def row_log_sum_exp(q, k, scale, causal):
+    """The dense oracle of the forward's second result: float32
+    log-sum-exp of each query row's scaled, masked scores."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                  acc_ref, *, scale, causal, block_q, block_k, n_kb):
     """One (batch*head, q-block, k-block) program. The TPU grid iterates
     its LAST dimension sequentially, so the online-softmax state (m, l,
     acc) carries across k blocks in VMEM scratch — only (block, d) tiles
     ever live in VMEM, whatever the sequence length (the FlashAttention
-    recurrence)."""
+    recurrence). The last k block also writes the rows' log-sum-exp
+    ``m + log l`` as one lane-major row of ``block_q`` values, which is
+    all the backward needs to recompute a probability."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -85,12 +105,42 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(ki == n_kb - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = _column_to_row(m_ref[...] + jnp.log(l))
+
+
+def _column_to_row(col):
+    """``(n, 1)`` -> ``(1, n)``: one value a sublane to one value a lane."""
+    return col.reshape(1, col.shape[0])
+
+
+def _row_to_column(row_ref):
+    """A ``(1, 1, 1, n)`` block of row statistics as an ``(n, 1)``
+    column."""
+    return jnp.expand_dims(row_ref[0, 0, 0], -1)
+
+
+def _rows(stat, block):
+    """Row statistics ``(bh, s)`` in the layout the kernels read and write
+    them in: ``(bh, s // block, 1, block)``, so that a block's last two
+    dimensions are the array's own and any block size is a legal tile."""
+    bh, s = stat.shape
+    return stat.reshape(bh, s // block, 1, block)
 
 
 def flash_forward(q, k, v, scale, causal, block_q, block_k,
                   interpret=False):
+    return flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
+                             interpret)[0]
+
+
+def flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
+                      interpret=False):
+    """``(out, lse)``: the attention output ``(b, h, sq, dv)`` and the
+    float32 log-sum-exp of each query row's scaled, masked scores
+    ``(b, h, sq)``, both results of one ``pallas_call`` (the output
+    first: a trace names a call after its first result)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -105,7 +155,7 @@ def flash_forward(q, k, v, scale, causal, block_q, block_k,
     kernel = _functools.partial(_flash_kernel, scale=scale, causal=causal,
                                 block_q=block_q, block_k=block_k,
                                 n_kb=n_kb)
-    out = pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -113,8 +163,15 @@ def flash_forward(q, k, v, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
             pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
+        out_specs=[
+            pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda i, j, kk: (i, j, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq // block_q, 1, block_q),
+                                 jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -122,7 +179,7 @@ def flash_forward(q, k, v, scale, causal, block_q, block_k,
         ],
         interpret=interpret,
     )(q3, k3, v3)
-    return out.reshape(b, h, sq, dv)
+    return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
 def _causal_mask(s, qi, ci, bq, bk):
@@ -216,6 +273,255 @@ def _flash_backward(q, k, v, out, cot, scale, causal, bq, bk):
             dv_.reshape(v.shape).astype(v.dtype))
 
 
+# ---- the backward as kernels -----------------------------------------
+
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+# A head's dQ may stay in VMEM while its k blocks pass (the fused call) up
+# to this many bytes of float32 sum and double-buffered result (8 a bf16
+# value): 4,096 positions x 192 take 6 MiB beside ~8 MiB of tiles at 512 x
+# 512, inside the 16 MiB of scoped VMEM.
+_FUSED_DQ_BYTES = 8 * 2 ** 20
+
+
+def _dot(a, b, dims):
+    """An MXU product with a float32 sum. Operands narrower than float32
+    are multiplied as they are: a precision asked of float32 matmuls
+    (``jax.default_matmul_precision``) means nothing for them, and Mosaic
+    refuses a bf16 product at ``highest``."""
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=None if a.dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT)
+
+
+def _tile_p_ds(x, y, dx, dy, lse, dvec, scale, keep):
+    """Probabilities and score gradients of one tile, in either
+    orientation: ``p = exp(x y^T * scale - lse)`` and ``ds = p * (dx dy^T
+    - D)`` (the factor ``scale`` of ``ds`` is applied once, to the
+    accumulated product). Matmul operands keep the inputs' dtype, the
+    tile is float32; ``keep`` is the causal mask of a tile the diagonal
+    crosses, None elsewhere. ``lse`` is finite (every row sees a key), so
+    a masked score gives exactly 0."""
+    s = _dot(x, y, _NT) * scale
+    if keep is not None:
+        s = jnp.where(keep, s, -jnp.inf)
+    p = jnp.exp(s - lse)
+    return p, p * (_dot(dx, dy, _NT) - dvec)
+
+
+def _causal_keep(qi, ki, block_q, block_k, q_axis):
+    """The mask of one tile, queries along ``q_axis``: a query sees the
+    keys at or before its own position."""
+    shape = (block_q, block_k) if q_axis == 0 else (block_k, block_q)
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    1 - q_axis)
+    return q_pos >= k_pos
+
+
+def _on_causal_tiles(tile, causal, qi, ki, block_q, block_k):
+    """Run ``tile(masked)`` for this (q block, k block) pair: not at all
+    where the whole pair lies above the diagonal, with the mask only where
+    the diagonal crosses it."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        tile(False)
+        return
+    below = qi * block_q >= (ki + 1) * block_k - 1
+    reached = (qi + 1) * block_q > ki * block_k
+    pl.when(below)(lambda: tile(False))
+    pl.when(jnp.logical_and(reached, jnp.logical_not(below)))(
+        lambda: tile(True))
+
+
+def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                      dk_ref, dv_ref, *rest, scale, causal, block_q, block_k,
+                      n_qb, n_kb):
+    """One (batch*head, k-block, q-block) program of dK and dV; q blocks
+    are the sequential dimension, the two sums live in VMEM scratch. The
+    tile is held TRANSPOSED, ``(block_k, block_q)``: the rows' statistics
+    then broadcast along sublanes as the lane-major rows they are stored
+    as, and the matmuls are plain ``a @ b`` / ``a @ b.T``.
+
+    FUSED (``rest`` = dq_ref, dk_acc, dv_acc, dq_acc; else dk_acc, dv_acc):
+    the same tile also gives its rows of dQ, ``dS^T^T k``, summed over the
+    k blocks in a float32 scratch that holds the head's WHOLE sequence and
+    written out, through a block as long, while the last k block passes.
+    Scores and their gradient are then computed once, not once a call."""
+    from jax.experimental import pallas as pl
+
+    dq_ref, dk_acc, dv_acc, dq_acc = \
+        rest if len(rest) == 4 else (None,) + rest + (None,)
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if dq_acc is not None:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]),
+                                        dq_acc.dtype)
+
+    def tile(masked):
+        q, do, k = q_ref[0], do_ref[0], k_ref[0]
+        keep = _causal_keep(qi, ki, block_q, block_k, 1) if masked else None
+        p, ds = _tile_p_ds(k, q, v_ref[0], do, lse_ref[0, 0],
+                           dvec_ref[0, 0], scale, keep)
+        ds = ds.astype(q.dtype)
+        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
+        dk_acc[...] += _dot(ds, q, _NN)
+        if dq_acc is not None:
+            dq_acc[rows, :] += _dot(ds, k, _TN)
+
+    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k)
+
+    @pl.when(qi == n_qb - 1)
+    def _finish():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if dq_acc is not None:
+        @pl.when(ki == n_kb - 1)
+        def _finish_dq():
+            dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(
+                dq_ref.dtype)
+
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                     dq_ref, dq_acc, *, scale, causal, block_q, block_k,
+                     n_kb):
+    """One (batch*head, q-block, k-block) program of dQ; k blocks are the
+    sequential dimension. The tile is ``(block_q, block_k)`` as in the
+    forward, so the rows' statistics turn into columns here."""
+    from jax.experimental import pallas as pl
+
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def tile(masked):
+        k = k_ref[0]
+        keep = _causal_keep(qi, ki, block_q, block_k, 0) if masked else None
+        _, ds = _tile_p_ds(q_ref[0], k, do_ref[0], v_ref[0],
+                           _row_to_column(lse_ref),
+                           _row_to_column(dvec_ref), scale, keep)
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
+
+    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k)
+
+    @pl.when(ki == n_kb - 1)
+    def _finish():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
+                          block_k, interpret=False, fused=None):
+    """``(dq, dk, dv)`` from the forward's operands, output and row
+    log-sum-exp: ``D = rowsum(d_out * out)`` in plain JAX, then ONE Pallas
+    call for all three where a head's dQ fits VMEM beside the tiles
+    (``_FUSED_DQ_BYTES``; ``fused`` overrides the rule, for the tests and
+    the sweep), else one for dK and dV and one for dQ, which computes the
+    scores and their gradient a second time but holds one block of dQ.
+    The first result of either call is dK or dQ, as wide as the keys: a
+    trace names a call after its first result, and only the forward's is
+    the attention output's shape. When causal, a block pair above the
+    diagonal is neither computed nor fetched: its grid step keeps the
+    block index of the nearest pair that is, and an unchanged index moves
+    nothing.
+
+    The fused call measured 26 % under the two at 4,096 positions and
+    14 % at 384 (``backward_blocks`` has the sweep)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, sq, d = q.shape
+    sk, dv = k.shape[2], v.shape[3]
+    bh, n_qb, n_kb = b * h, sq // block_q, sk // block_k
+    q3, k3 = q.reshape(bh, sq, d), k.reshape(bh, sk, d)
+    v3, do3 = v.reshape(bh, sk, dv), cot.reshape(bh, sq, dv)
+    f32 = jnp.float32
+    dvec = (cot.astype(f32) * out.astype(f32)).sum(-1)
+    lse4 = _rows(lse.reshape(bh, sq).astype(f32), block_q)
+    dvec4 = _rows(dvec.reshape(bh, sq), block_q)
+    if fused is None:
+        fused = sq * d * (4 + 2 * q.dtype.itemsize) <= _FUSED_DQ_BYTES
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, n_kb=n_kb)
+
+    if causal:
+        # the first q block that reaches k block j / the last k block
+        # that q block i reaches
+        def q_of(i, j):
+            return jnp.maximum(i, (j * block_k) // block_q)
+
+        def k_of(i, j):
+            return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+    else:
+        def q_of(i, j):
+            return i
+
+        def k_of(i, j):
+            return j
+
+    def specs(q_at, k_at):
+        return [
+            pl.BlockSpec((1, block_q, d), lambda *g: (g[0], q_at(*g), 0)),
+            pl.BlockSpec((1, block_k, d), lambda *g: (g[0], k_at(*g), 0)),
+            pl.BlockSpec((1, block_k, dv), lambda *g: (g[0], k_at(*g), 0)),
+            pl.BlockSpec((1, block_q, dv), lambda *g: (g[0], q_at(*g), 0)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda *g: (g[0], q_at(*g), 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda *g: (g[0], q_at(*g), 0, 0)),
+        ]
+
+    operands = (q3, k3, v3, do3, lse4, dvec4)
+    dq_shape = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
+    # grid (batch*head, k block, q block); fused, dQ is its third result
+    dk, dv_, *dq = pl.pallas_call(
+        _functools.partial(_flash_dkv_kernel, n_qb=n_qb, **static),
+        grid=(bh, n_kb, n_qb),
+        in_specs=specs(lambda i, j, qq: q_of(qq, j), lambda i, j, qq: j),
+        out_specs=[
+            pl.BlockSpec((1, block_k, d), lambda i, j, qq: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j, qq: (i, j, 0)),
+        ] + [pl.BlockSpec((1, sq, d), lambda i, j, qq: (i, 0, 0))] * fused,
+        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
+                   jax.ShapeDtypeStruct((bh, sk, dv), v.dtype)]
+        + [dq_shape] * fused,
+        scratch_shapes=[pltpu.VMEM((block_k, d), f32),
+                        pltpu.VMEM((block_k, dv), f32)]
+        + [pltpu.VMEM((sq, d), f32)] * fused,
+        interpret=interpret,
+    )(*operands)
+    if not fused:
+        # grid (batch*head, q block, k block)
+        dq = [pl.pallas_call(
+            _functools.partial(_flash_dq_kernel, **static),
+            grid=(bh, n_qb, n_kb),
+            in_specs=specs(lambda i, j, kk: j,
+                           lambda i, j, kk: k_of(j, kk)),
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda i, j, kk: (i, j, 0)),
+            out_shape=dq_shape,
+            scratch_shapes=[pltpu.VMEM((block_q, d), f32)],
+            interpret=interpret,
+        )(*operands)]
+    return (dq[0].reshape(q.shape), dk.reshape(k.shape),
+            dv_.reshape(v.shape))
+
+
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
     return flash_forward(q, k, v, scale, causal, block_q, block_k,
@@ -223,15 +529,20 @@ def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out = flash_forward(q, k, v, scale, causal, block_q, block_k,
-                        interpret)
-    return out, (q, k, v, out)
+    out, lse = flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
+                                 interpret)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, cot):
-    q, k, v, out = res
-    return _flash_backward(q, k, v, out, cot, scale, causal, block_q,
-                           block_k)
+    """The backward is a dispatch of its own (``flash_attention_bwd``). A
+    forward that ran in the interpreter asks for the same; on the chip the
+    table, or the family's default, decides."""
+    from . import dispatch
+
+    return dispatch("flash_attention_bwd", *res, cot, scale, causal=causal,
+                    block_q=block_q, block_k=block_k,
+                    interpret=True if interpret else None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -295,14 +606,76 @@ def default_blocks(sq, sk, d, dv):
     Equal widths keep the 128 x 128 they always had (the only size
     measured at d64). With a value width of its own, up to 256 wide: the
     largest power of two up to 1024 that divides the length. Measured on
-    a v5e at 2 x 32 heads x 4096, 192 | 128, causal, forward + backward:
-    155 ms at 128 x 128, 73 at 512, 65 at 1024 (the scanned backward
-    computes every block pair, so fewer and larger blocks win twice);
-    2048 x 1024 does not fit VMEM (PERF.md, PR 26)."""
+    a v5e at 2 x 32 heads x 4096, 192 | 128, causal, forward alone: 34.3
+    ms at 128 x 128, 13.4 at 256, 7.2 at 512, 5.6 at 512 x 1024, 4.9 at
+    1024; 2048 x 1024 does not fit VMEM (PERF.md, PR 26). These are the
+    forward's blocks, and the scan's where it runs; the backward kernels
+    take their own (``backward_blocks``)."""
     if d == dv or max(d, dv) > 256:
         return 128, 128
     # s & -s: the largest power of two that divides s
     return (max(128, min(sq & -sq, 1024)), max(128, min(sk & -sk, 1024)))
+
+
+def backward_blocks(sq, sk, d, dv):
+    """``(block_q, block_k)`` of the backward kernels, from the shape alone
+    and independent of the forward's: a backward tile keeps four
+    (block_q x block_k) float32 arrays live (scores, probabilities, their
+    two gradients), so the forward's 1024 x 1024 does not fit the 16 MiB of
+    scoped VMEM beside a head's dQ. A sequence of up to 512 is one block
+    (BERT's 384: one program a head, nothing re-read); a longer one takes
+    the largest power of two up to 512 that divides it. Measured on a v5e,
+    bf16, ms a layer alone in a jit, fused call / two calls (my chip run,
+    PR 27): 2 x 32 heads x 4096, 192 | 128, causal: 128 x 128 31.6 / 51.4,
+    256 x 256 13.6 / 19.3, 256 x 512 11.8 / 16.4, 512 x 256 11.8 / 16.0,
+    **512 x 512 10.5 / 14.2**, 1024 x 512 10.5 / 14.0, 1024 x 256 11.2 /
+    14.9, 256 x 1024 11.2 / 15.5, 512 x 1024 out of VMEM / 14.3; the scan
+    at the forward's 1024 x 1024: 60.7. 32 x 12 heads x 384, d64, no mask:
+    128 x 128 3.36 / 4.53, 384 x 128 2.47 / 2.95, 128 x 384 2.35 / 2.98,
+    **384 x 384 2.04 / 2.37**; the scan at 128 x 128: 4.78. Inside the
+    cells' steps the fused call reads 7.7 and 0.53 ms."""
+    del d, dv  # every width measured takes the same blocks
+
+    def one(s):
+        return s if s <= 512 else min(s & -s, 512)
+
+    return one(sq), one(sk)
+
+
+def _blocks_for(q, k, v):
+    return backward_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3])
+
+
+def _bwd_kernel(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
+                block_k=128, interpret=False):
+    del block_q, block_k  # the forward's, which the scan shares
+    bq, bk = _blocks_for(q, k, v)
+    return flash_backward_kernel(q, k, v, out, lse, cot, float(scale),
+                                 bool(causal), bq, bk, bool(interpret))
+
+
+def _bwd_xla(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
+             block_k=128):
+    del lse  # the scan computes the rows' statistics in a pass of its own
+    return _flash_backward(q, k, v, out, cot, scale, causal, block_q,
+                           block_k)
+
+
+def _bwd_bucket(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
+                block_k=128):
+    """The forward's key with the backward's own blocks."""
+    return _bucket(q, k, v, scale, causal, *_blocks_for(q, k, v))
+
+
+def _bwd_supports(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
+                  block_k=128):
+    """What the forward kernel takes, at the backward's blocks, each the
+    whole sequence or a multiple of the 128 lanes a row of statistics is
+    tiled by."""
+    bq, bk = _blocks_for(q, k, v)
+    return (_supports(q, k, v, scale, causal, bq, bk)
+            and (bq == q.shape[2] or bq % 128 == 0)
+            and (bk == k.shape[2] or bk % 128 == 0))
 
 
 def _register():
@@ -313,6 +686,16 @@ def _register():
         supports=_supports, default_tpu=True,
         tolerance="f32 rtol=2e-5 atol=2e-5 vs dense softmax (softmax "
                   "normalizer reassociated across k blocks)")
+    register_kernel(
+        "flash_attention_bwd", kernel=_bwd_kernel, xla=_bwd_xla,
+        bucket=_bwd_bucket, supports=_bwd_supports, default_tpu=True,
+        tolerance="f32 rtol=2e-4 atol=2e-5 vs the dense gradient and vs "
+                  "the scan in the interpreter (256 positions); compiled, "
+                  "at 1024 positions and highest precision, within 1e-4 "
+                  "of the largest |gradient|; bf16 operands (p and dS "
+                  "rounded to bf16 before their matmuls, float32 sums): "
+                  "within 2e-2 of the largest |gradient| of the float32 "
+                  "dense gradient of the same rounded inputs")
 
 
 _register()

@@ -38,8 +38,10 @@ def _contrib_flash_attention(q, k, v, scale=None, causal=False,
     Mosaic constraints AND the dispatch table (or the on-TPU default)
     picks it; dense XLA softmax otherwise. `interpret=True` forces the
     kernel through the Pallas interpreter (CPU CI). Training memory
-    stays O(S*block): the backward is the blocked flash recurrence, not
-    a dense recompute."""
+    stays O(S*block): the kernel's backward is a dispatch of its own
+    (family ``flash_attention_bwd``: Pallas dK/dV and dQ calls that
+    recompute the probabilities tile by tile from the saved row
+    log-sum-exp, or the scanned recurrence), not a dense recompute."""
     if q.ndim != 4:
         raise ValueError(
             f"flash_attention expects (B, H, S, D) inputs, got rank "

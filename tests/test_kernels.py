@@ -27,8 +27,9 @@ from mxnet_tpu.kernels import table as ktable
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-FAMILIES = ("decode_attention", "flash_attention", "int8_gemm",
-            "opt_adam", "opt_sgd", "twobit_compress", "twobit_decompress")
+FAMILIES = ("decode_attention", "flash_attention", "flash_attention_bwd",
+            "int8_gemm", "opt_adam", "opt_sgd", "twobit_compress",
+            "twobit_decompress")
 
 
 @pytest.fixture
@@ -420,6 +421,33 @@ def test_opperf_kernels_writes_table(kernel_cache_dir):
     key = "twobit_compress|" + \
         kernels.entry("twobit_compress").bucket(g, r0, 0.5)
     assert choice == t["entries"][key]["winner"]
+
+
+def test_opperf_kernels_has_a_row_for_the_attention_backward(
+        kernel_cache_dir):
+    """The backward is a family of its own: the autotuner times its
+    kernels against the scan and the table holds the row under the
+    backward's bucket (its own blocks), which then routes its dispatch
+    while the forward's stays untuned."""
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import opperf
+
+    res = opperf.bench_kernels(runs=1, warmup=1,
+                               families=["flash_attention_bwd"])
+    (row,) = res["results"]
+    assert row["family"] == "flash_attention_bwd"
+    assert row["bucket"] == "bh2_sq128_sk128_d64_float32_c1_q128k128"
+    assert row["kernel_ms"] > 0 and row["xla_ms"] > 0
+    args, kw = dict(opperf._kernel_cases())["flash_attention_bwd"]()
+    assert kernels.choice_for("flash_attention_bwd", *args, **kw) \
+        == (row["winner"], "tuned")
+    assert kernels.choice_for("flash_attention", *args[:3], **kw)[1] \
+        == "untuned_default"
+    # and a table that changed is another executable identity
+    salt = kernels.token_salt()
+    ktable.record("flash_attention_bwd", row["bucket"],
+                  "xla" if row["winner"] == "kernel" else "kernel", 1.0, 2.0)
+    assert kernels.token_salt() != salt
 
 
 # ===================================================================== #

@@ -264,6 +264,186 @@ def test_flash_kernel_forward_and_backward(d, dv, causal):
                                    rtol=2e-4, atol=2e-5, err_msg=name)
 
 
+def _attention_case(d, dv, causal, sq=256, sk=256, dtype=jnp.float32):
+    rng = np.random.RandomState(d + dv + sk)
+    q = jnp.asarray(rng.randn(1, 2, sq, d), dtype)
+    k = jnp.asarray(rng.randn(1, 2, sk, d), dtype)
+    v = jnp.asarray(rng.randn(1, 2, sk, dv), dtype)
+    cot = jnp.asarray(rng.randn(1, 2, sq, dv), dtype)
+    return q, k, v, cot, d ** -0.5
+
+
+def _dense_gradient(q, k, v, cot, scale, causal):
+    _, vjp = jax.vjp(lambda a, b, c: flash.flash_attention_reference(
+        a, b, c, scale, causal), q, k, v)
+    return vjp(cot)
+
+
+# the forward runs at 128 x 128; the backward at the same blocks, at
+# unequal ones, and at the whole sequence in one block; and once with
+# fewer keys than queries and no mask; each as one fused call and as two
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_calls"])
+@pytest.mark.parametrize("d,dv,sk,causal,blocks", [
+    (d, dv, 256, causal, blocks)
+    for d, dv in [(64, 64), (192, 128), (32, 16)]
+    for causal in (False, True)
+    for blocks in [(128, 128), (64, 128), (256, 256)]
+] + [(32, 16, 128, False, (64, 128))])
+def test_flash_backward_kernel_against_dense_and_scan(d, dv, sk, causal,
+                                                      blocks, fused):
+    q, k, v, cot, scale = _attention_case(d, dv, causal, sk=sk)
+    out, lse = flash.flash_forward_lse(q, k, v, scale, causal, 128, 128,
+                                       interpret=True)
+    got = flash.flash_backward_kernel(q, k, v, out, lse, cot, scale, causal,
+                                      *blocks, interpret=True, fused=fused)
+    dense = _dense_gradient(q, k, v, cot, scale, causal)
+    scan = flash._flash_backward(q, k, v, out, cot, scale, causal, 128, 128)
+    for g, w, s, name in zip(got, dense, scan, ("dq", "dk", "dv")):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5, err_msg=name + " vs dense")
+        np.testing.assert_allclose(np.asarray(g), np.asarray(s), rtol=2e-4,
+                                   atol=2e-5, err_msg=name + " vs scan")
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_calls"])
+def test_flash_backward_kernel_in_bfloat16(fused):
+    """MXU operands in the inputs' dtype: q, k, v, d_out, and p / dS where
+    they feed a matmul, are bf16; everything else float32. Against the
+    float32 dense gradient of the SAME rounded inputs, at the tolerance
+    the family registers."""
+    assert "within 2e-2 of the largest |gradient|" in " ".join(
+        kernels.entry("flash_attention_bwd").tolerance.split())
+    q, k, v, cot, scale = _attention_case(192, 128, True,
+                                          dtype=jnp.bfloat16)
+    out, lse = flash.flash_forward_lse(q, k, v, scale, True, 128, 128,
+                                       interpret=True)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    got = flash.flash_backward_kernel(q, k, v, out, lse, cot, scale, True,
+                                      128, 64, interpret=True, fused=fused)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, cot)]
+    want = _dense_gradient(*f32, scale, True)
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16
+        err = np.abs(np.asarray(g, np.float32) - np.asarray(w)).max()
+        assert err <= 2e-2 * np.abs(np.asarray(w)).max(), (name, err)
+    # the operands of the five matmuls are bf16 in the traced kernels,
+    # their sums float32
+    dots = _dot_generals(jax.make_jaxpr(
+        lambda *a: flash.flash_backward_kernel(
+            *a, scale, True, 128, 64, interpret=True, fused=fused))(
+                q, k, v, out, lse, cot).jaxpr)
+    # a masked and an unmasked tile, each with S, dP, dV, dK, dQ; two
+    # calls compute S and dP in both
+    assert len(dots) == (10 if fused else 14)
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+def _dot_generals(jaxpr):
+    """Every ``dot_general`` equation of a jaxpr and of the jaxprs its
+    equations hold (the kernel of a ``pallas_call``, a ``cond``'s
+    branches)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found.extend(_dot_generals(inner))
+    return found
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_forward_writes_the_rows_log_sum_exp(causal):
+    q, k, v, _, scale = _attention_case(32, 16, causal, sk=128)
+    out, lse = flash.flash_forward_lse(q, k, v, scale, causal, 64, 32,
+                                       interpret=True)
+    # written out here and not through ``flash.row_log_sum_exp``, which
+    # the smoke run and the autotuner use: that oracle is held to this too
+    s = np.einsum("bhqd,bhkd->bhqk", np.asarray(q, np.float64),
+                  np.asarray(k, np.float64)) * scale
+    if causal:
+        s = np.where(np.tril(np.ones(s.shape[-2:], bool)), s, -np.inf)
+    want = np.log(np.exp(s - s.max(-1, keepdims=True)).sum(-1)) + s.max(-1)
+    assert lse.shape == (1, 2, 256) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse), want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(flash.row_log_sum_exp(q, k, scale, causal)), want,
+        rtol=1e-5, atol=1e-5)
+    # the output is still the call's first result, and the same array
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(flash.flash_forward(
+            q, k, v, scale, causal, 64, 32, interpret=True)))
+
+
+def test_flash_backward_is_a_counted_decision_of_its_own(monkeypatch):
+    q, k, v, cot, scale = _attention_case(32, 16, True)
+
+    def grads(**kw):
+        _, vjp = jax.vjp(lambda a, b, c: kernels.dispatch(
+            "flash_attention", a, b, c, scale, causal=True, **kw), q, k, v)
+        return vjp(cot)
+
+    kernels.reset_stats()
+    got = grads(interpret=True)
+    stats = kernels.dispatch_stats()
+    # the backward's own blocks name its bucket: 256 positions, one block
+    assert flash.backward_blocks(256, 256, 32, 16) == (256, 256)
+    assert stats["flash_attention_bwd"] == {
+        "kernel": 1, "xla": 0, "reasons": {"interpret_forced": 1},
+        "buckets": {"bh2_sq256_sk256_d32v16_float32_c1_q256k256":
+                    {"kernel": 1, "xla": 0}}}
+    assert list(stats["flash_attention"]["buckets"]) \
+        == ["bh2_sq256_sk256_d32v16_float32_c1_q128k128"]
+    # the table decides it like any family: a row for the bucket that
+    # names the scan sends the backward there, and the result agrees
+    e = kernels.entry("flash_attention_bwd")
+    monkeypatch.setattr(
+        kernels.table, "lookup", lambda family, bucket: {"winner": "xla"}
+        if family == "flash_attention_bwd" else None)
+    out, lse = flash.flash_forward_lse(q, k, v, scale, True, 128, 128,
+                                       interpret=True)
+    assert kernels.choice_for("flash_attention_bwd", q, k, v, out, lse, cot,
+                              scale, causal=True) == ("xla", "tuned")
+    scan = kernels.dispatch("flash_attention_bwd", q, k, v, out, lse, cot,
+                            scale, causal=True)
+    for g, s in zip(got, scan):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(s), rtol=2e-4,
+                                   atol=2e-5)
+    monkeypatch.undo()
+    # MXNET_TPU_KERNELS=0 covers it
+    monkeypatch.setenv("MXNET_TPU_KERNELS", "0")
+    assert kernels.choice_for("flash_attention_bwd", q, k, v, out, lse, cot,
+                              scale, causal=True) == ("xla", "env_disabled")
+    # one call where a head's dQ fits VMEM beside the tiles, two beyond
+    def calls(sq, d):
+        z = jnp.zeros((1, 1, sq, d), jnp.bfloat16)
+        text = str(jax.make_jaxpr(lambda a, l: flash.flash_backward_kernel(
+            a, a, a, a, l, a, 0.1, True, 512, 512, interpret=True))(
+                z, z[..., 0].astype(jnp.float32)))
+        return text.count("pallas_call")
+
+    assert calls(4096, 192) == 1 and calls(8192, 192) == 2
+    # blocks follow the shape: 512 at 4,096 positions, the whole sequence
+    # at BERT's 384, and a length no block of 128s divides is the scan's
+    assert flash.backward_blocks(4096, 4096, 192, 128) == (512, 512)
+    assert flash.backward_blocks(384, 384, 64, 64) == (384, 384)
+    assert flash.backward_blocks(1536, 640, 64, 64) == (512, 128)
+    z = jnp.zeros((1, 1, 1000, 64))
+    assert flash.backward_blocks(1000, 1000, 64, 64) == (8, 8)
+    assert not e.supports(z, z, z, z, z[..., 0], z, 0.125)
+    assert e.bucket(
+        *(jnp.zeros((2, 32, 4096, w), jnp.bfloat16) for w in (192, 192, 128,
+                                                               128)),
+        jnp.zeros((2, 32, 4096)), jnp.zeros((2, 32, 4096, 128)), 0.07,
+        causal=True, block_q=1024, block_k=1024) \
+        == "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k512"
+
+
 def test_flash_bucket_and_supports_know_both_widths():
     def arrays(d, dv, sk=256):
         return (jnp.zeros((2, 4, 256, d), jnp.bfloat16),

@@ -72,6 +72,52 @@ def test_flash_192_128_compiles_forward_and_backward(one_chip, quiet_cache,
         and dk.shape == q.shape and dv.shape == v.shape
 
 
+@pytest.mark.parametrize("shape,causal,forward_blocks", [
+    ((2, 32, 4096, 192, 128), True, (1024, 1024)),   # the language model
+    ((32, 12, 384, 64, 64), False, (128, 128)),      # BERT-base at 384
+], ids=["bh64_s4096_d192v128_c1", "bh384_s384_d64_c0"])
+def test_flash_backward_kernels_compile_at_the_cells_buckets(
+        one_chip, quiet_cache, shape, causal, forward_blocks):
+    """The forward with its second result and the fused backward call,
+    at the blocks ``backward_blocks`` picks, for both buckets the
+    benchmark runs: two Mosaic calls, inside the scoped VMEM; and as the
+    two calls a longer sequence takes: three."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import flash
+
+    b, h, s, d, dv = shape
+    q = _shape((b, h, s, d), jnp.bfloat16, one_chip)
+    v = _shape((b, h, s, dv), jnp.bfloat16, one_chip)
+    blocks = flash.backward_blocks(s, s, d, dv)
+    assert blocks == ((512, 512) if s == 4096 else (s, s))
+
+    def fwd_bwd(q_, k_, v_, cot):
+        out, lse = flash.flash_forward_lse(q_, k_, v_, d ** -0.5, causal,
+                                           *forward_blocks)
+        return (out, lse) + flash.flash_backward_kernel(
+            q_, k_, v_, out, lse, cot, d ** -0.5, causal, *blocks)
+
+    compiled = jax.jit(fwd_bwd).lower(q, q, v, v).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    two = jax.jit(lambda q_, k_, v_, o_, l_, c_: flash.flash_backward_kernel(
+        q_, k_, v_, o_, l_, c_, d ** -0.5, causal, *blocks, fused=False))
+    lse_ = _shape((b, h, s), jnp.float32, one_chip)
+    assert two.lower(q, q, v, v, lse_, v).compile().as_text().count(
+        "tpu_custom_call") == 2
+    # bf16 operands stay bf16 products whatever precision float32 matmuls
+    # are asked for (Mosaic refuses a bf16 product at "highest", which is
+    # how chip_smoke.py traces its kernels)
+    with jax.default_matmul_precision("highest"):
+        assert two.lower(q, q, v, v, lse_, v).compile().as_text().count(
+            "tpu_custom_call") == 2
+    out, lse, dq, dk, dv_ = jax.eval_shape(fwd_bwd, q, q, v, v)
+    assert lse.shape == (b, h, s) and lse.dtype == jnp.float32
+    assert (dq.shape, dk.shape, dv_.shape) == (q.shape, q.shape, v.shape)
+    assert dq.dtype == dk.dtype == dv_.dtype == jnp.bfloat16
+
+
 def test_routed_experts_compile_to_grouped_kernels(one_chip, quiet_cache):
     """The expert layer at the benchmark's widths (8,192 tokens, 128-way
     router, top-6, 16 held experts of 2048 x 768): the three products of
