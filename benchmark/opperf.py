@@ -219,16 +219,6 @@ def _kernel_cases():
         lse = row_log_sum_exp(q, k, kw["scale"], kw["causal"])
         return (q, k, v, out, lse, f32(1, 2, 128, 64)), kw
 
-    def opt_sgd():
-        n = 65536
-        return (f32(n), f32(n), f32(n), jnp.float32(0.05)), \
-            {"momentum": 0.9, "wd": 1e-4}
-
-    def opt_adam():
-        n = 65536
-        return (f32(n), f32(n), f32(n), f32(n), jnp.float32(1e-3)), \
-            {"wd": 1e-4}
-
     def int8_gemm():
         qx = jnp.asarray(r.integers(-127, 128, (128, 256)), dtype=jnp.int8)
         w = jnp.asarray(r.integers(-127, 128, (256, 256)), dtype=jnp.int8)
@@ -249,8 +239,7 @@ def _kernel_cases():
         return (codes,), {"thr": 0.5}
 
     return [("flash_attention", flash), ("flash_attention_bwd", flash_bwd),
-            ("opt_sgd", opt_sgd),
-            ("opt_adam", opt_adam), ("int8_gemm", int8_gemm),
+            ("int8_gemm", int8_gemm),
             ("decode_attention", decode), ("twobit_compress", twobit_c),
             ("twobit_decompress", twobit_d)]
 

@@ -371,7 +371,7 @@ def stats():
 
 
 def totals():
-    """Aggregate over sites (the bench.py JSON fields)."""
+    """Aggregate over sites."""
     agg = {"hits": 0, "misses": 0, "disk_hits": 0, "compiles": 0,
            "compile_ms": 0.0, "load_ms": 0.0, "corrupt": 0}
     for st in _SITES.values():

@@ -91,7 +91,7 @@ class Context:
         devices so test suites written against ``mx.tpu()`` run anywhere —
         the same trick the reference uses with ``default_context()``
         (`python/mxnet/test_utils.py:58`). Entry points that must run on
-        the chip (``chip_smoke.py``, ``bench.py``) assert the platform
+        the chip (``chip_smoke.py``, ``chipbench/run.py``) assert the platform
         themselves. A ``tpu`` id past the last device raises.
         """
         import jax

@@ -163,7 +163,7 @@ class MultiHeadAttention(HybridBlock):
     """
 
     def __init__(self, units, num_heads, dropout=0.0, causal=False,
-                 block_q=128, block_k=128, interpret=False, **kwargs):
+                 interpret=False, **kwargs):
         super().__init__(**kwargs)
         if units % num_heads:
             raise ValueError(f"units {units} not divisible by "
@@ -171,11 +171,9 @@ class MultiHeadAttention(HybridBlock):
         self._units = units
         self._heads = num_heads
         self._causal = causal
-        # kernel knobs pass straight through to _contrib_flash_attention
-        # (interpret=True runs the Pallas kernel in interpreter mode, so
-        # the kernel path is testable on CPU CI)
-        self._flash_kwargs = {"block_q": block_q, "block_k": block_k,
-                              "interpret": interpret}
+        # interpret=True runs the Pallas kernel in interpreter mode, so
+        # the kernel path is testable on CPU CI
+        self._interpret = interpret
         with self.name_scope():
             from ...nn import Dense, Dropout
 
@@ -204,7 +202,7 @@ class MultiHeadAttention(HybridBlock):
         k = split(self.key(kv))
         v = split(self.value(kv))
         out = F.contrib.flash_attention(q, k, v, causal=self._causal,
-                                        **self._flash_kwargs)
+                                        interpret=self._interpret)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(0, 0, -1))
         return self.drop(self.proj(out))

@@ -74,8 +74,8 @@ class MLAttention(HybridBlock):
     on ``q``'s last ``qk_rope`` and on ``k_r``, which every head shares;
     ``k = [k_n, k_r]``. Softmax of ``q k^T / sqrt(qk_nope + qk_rope)``
     through ``F.contrib.flash_attention``: keys are wider than values, so
-    the flash kernel runs with a value width of its own, in the blocks
-    ``kernels.flash.default_blocks`` gives for the shape.
+    the flash kernel runs with a value width of its own, in blocks it
+    picks from the shape.
     """
 
     def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
@@ -105,14 +105,7 @@ class MLAttention(HybridBlock):
                                 in_units=num_heads * self._dv)
 
     def hybrid_forward(self, F, x):
-        from ...kernels.flash import default_blocks
-
         heads, nope, rope = self._heads, self._nope, self._rope
-        blocks = {}
-        if getattr(x, "shape", None):   # a Symbol has none: the op's own
-            seq = int(x.shape[1])
-            blocks["block_q"], blocks["block_k"] = default_blocks(
-                seq, seq, nope + rope, self._dv)
 
         def split_heads(t):  # (B, S, H * D) -> (B, H, S, D)
             return F.transpose(F.reshape(t, shape=(0, 0, heads, -1)),
@@ -143,7 +136,7 @@ class MLAttention(HybridBlock):
         with _scope("mla.attention"):
             out = F.contrib.flash_attention(
                 q, k, v, scale=float((nope + rope) ** -0.5), causal=True,
-                interpret=self._interpret, **blocks)
+                interpret=self._interpret)
         with _scope("mla.project"):
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, 0, -1))

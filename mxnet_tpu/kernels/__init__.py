@@ -31,12 +31,10 @@ flash_attention    blocked online-softmax attention (moved here from
                    ``ops/pallas_ops.py``; that module remains the op
                    registration shim); also writes the rows' log-sum-exp
 flash_attention_   its backward, a decision of its own taken inside the
-bwd                forward's ``custom_vjp``: a dK/dV and a dQ Pallas call
-                   over the block pairs under the diagonal, or the
-                   scanned float32 recurrence (its XLA side)
-opt_sgd/opt_adam   fused optimizer step — update+decay(+master cast)
-                   in one kernel, wired into the ShardedTrainer update
-                   rules (``parallel/opt_rules.py``)
+bwd                forward's ``custom_vjp``: one fused Pallas call (two,
+                   dK/dV then dQ, for long sequences) over the block
+                   pairs under the diagonal; its XLA side is the
+                   gradient of the dense reference
 int8_gemm          int8×int8→int32 GEMM with fused dequant+bias+relu
                    (the ``_contrib_quantized_*`` MXU path)
 decode_attention   single-query flash against a padded KV cache (the
@@ -145,7 +143,10 @@ def _count(family, choice, reason, bucket=None):
         rec = _stats.setdefault(family, {"kernel": 0, "xla": 0,
                                          "reasons": {}, "buckets": {}})
         rec[choice] += 1
-        _count_by(rec, choice, reason, bucket)
+        rec["reasons"][reason] = rec["reasons"].get(reason, 0) + 1
+        if bucket is not None:
+            per = rec["buckets"].setdefault(bucket, {"kernel": 0, "xla": 0})
+            per[choice] += 1
     try:
         from ..telemetry import registry as _registry
 
@@ -256,17 +257,6 @@ def choice_for(family, *args, **kwargs):
     return choice, reason
 
 
-def _count_by(rec, choice, reason, bucket):
-    """One decision by its reason and, where it got that far, by its shape
-    bucket. (Down here, and ``_count`` no longer than it was: the Mosaic
-    payload of a kernel carries the source lines of its callers, so a line
-    moved above ``dispatch`` recompiles every program that holds one.)"""
-    rec["reasons"][reason] = rec["reasons"].get(reason, 0) + 1
-    if bucket is not None:
-        per = rec["buckets"].setdefault(bucket, {"kernel": 0, "xla": 0})
-        per[choice] += 1
-
-
 def dispatch_stats():
     """Per-family dispatch decision counts (process-local), in all and
     by shape bucket (the family's ``bucket`` key; decisions taken before
@@ -311,7 +301,6 @@ def token_salt():
 
 # family registrations (import order is alphabetical, not load-bearing)
 from . import flash  # noqa: E402,F401  (flash_attention, flash_attention_bwd)
-from . import opt_step  # noqa: E402,F401  (opt_sgd / opt_adam)
 from . import int8_gemm  # noqa: E402,F401  (int8_gemm)
 from . import decode_attention  # noqa: E402,F401  (decode_attention)
 from . import twobit  # noqa: E402,F401  (twobit_compress/_decompress)

@@ -10,10 +10,11 @@ query row's log-sum-exp. The backward is a family of its own,
 a head's dQ does not fit VMEM) that recomputes the probabilities tile by
 tile from ``(q, k, v, out, lse, d_out)``, only over the block pairs at or
 under the diagonal when causal, with MXU operands in the inputs' dtype
-and float32 accumulation; its XLA side is
-the scanned float32 recurrence this module always had
-(:func:`_flash_backward`). Training memory stays O(S*block) end to end
-on either side.
+and float32 accumulation; its XLA side is the gradient of the dense
+reference. Training memory stays O(S*block) end to end wherever the
+kernels run. Both families pick their blocks from the shape, here and
+nowhere else (:func:`default_blocks`, :func:`backward_blocks`); a caller
+names a pair only to force a tile.
 
 Tolerance vs the XLA baseline (dense softmax reference): f32 inputs
 agree to rtol=2e-5/atol=2e-5 — the kernel accumulates in f32 exactly
@@ -180,97 +181,6 @@ def flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
         interpret=interpret,
     )(q3, k3, v3)
     return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
-
-
-def _causal_mask(s, qi, ci, bq, bk):
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = ci * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(q_pos >= k_pos, s, -jnp.inf)
-
-
-def _flash_backward(q, k, v, out, cot, scale, causal, bq, bk):
-    """Blocked flash backward (FlashAttention eq. 13-16) in pure JAX:
-    probabilities are recomputed per (q-block, k-block) tile, so live
-    memory stays O(S * block) — no (S, S) tensor ever exists, matching
-    the forward kernel's memory contract for training too."""
-    b, h, sq, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
-    nbq, nbk = sq // bq, sk // bk
-    f32 = jnp.float32
-
-    def per_head(q2, k2, v2, o2, do2):
-        qb = q2.reshape(nbq, bq, d).astype(f32)
-        kb = k2.reshape(nbk, bk, d).astype(f32)
-        vb = v2.reshape(nbk, bk, dv).astype(f32)
-        dob = do2.reshape(nbq, bq, dv).astype(f32)
-        Dvec = (do2.astype(f32) * o2.astype(f32)).sum(-1).reshape(nbq, bq)
-
-        # pass 1: per-row max and normalizer (scan over k blocks)
-        def ml_one(qi, qblk):
-            def step(carry, kc):
-                m, l = carry
-                kcblk, ci = kc
-                s = qblk @ kcblk.T * scale
-                if causal:
-                    s = _causal_mask(s, qi, ci, bq, bk)
-                m_new = jnp.maximum(m, s.max(-1))
-                l = l * jnp.exp(m - m_new) + \
-                    jnp.exp(s - m_new[:, None]).sum(-1)
-                return (m_new, l), None
-
-            init = (jnp.full((bq,), -jnp.inf, f32), jnp.zeros((bq,), f32))
-            (m, l), _ = jax.lax.scan(step, init,
-                                     (kb, jnp.arange(nbk)))
-            return m, jnp.maximum(l, 1e-30)
-
-        m, l = jax.vmap(ml_one)(jnp.arange(nbq), qb)
-
-        # dq: per q block, accumulate over k blocks
-        def dq_one(qi, qblk, doblk, mrow, lrow, Drow):
-            def step(acc, kc):
-                kcblk, vcblk, ci = kc
-                s = qblk @ kcblk.T * scale
-                if causal:
-                    s = _causal_mask(s, qi, ci, bq, bk)
-                p = jnp.exp(s - mrow[:, None]) / lrow[:, None]
-                dp = doblk @ vcblk.T
-                ds = p * (dp - Drow[:, None])
-                return acc + ds @ kcblk * scale, None
-
-            acc, _ = jax.lax.scan(step, jnp.zeros((bq, d), f32),
-                                  (kb, vb, jnp.arange(nbk)))
-            return acc
-
-        dq = jax.vmap(dq_one)(jnp.arange(nbq), qb, dob, m, l, Dvec)
-
-        # dk, dv: per k block, accumulate over q blocks
-        def dkv_one(ci, kcblk, vcblk):
-            def step(carry, qc):
-                dk_acc, dv_acc = carry
-                qblk, doblk, mrow, lrow, Drow, qi = qc
-                s = qblk @ kcblk.T * scale
-                if causal:
-                    s = _causal_mask(s, qi, ci, bq, bk)
-                p = jnp.exp(s - mrow[:, None]) / lrow[:, None]
-                dp = doblk @ vcblk.T
-                ds = p * (dp - Drow[:, None])
-                return (dk_acc + ds.T @ qblk * scale,
-                        dv_acc + p.T @ doblk), None
-
-            init = (jnp.zeros((bk, d), f32), jnp.zeros((bk, dv), f32))
-            (dk_acc, dv_acc), _ = jax.lax.scan(
-                step, init, (qb, dob, m, l, Dvec, jnp.arange(nbq)))
-            return dk_acc, dv_acc
-
-        dk, dv_ = jax.vmap(dkv_one)(jnp.arange(nbk), kb, vb)
-        return dq.reshape(sq, d), dk.reshape(sk, d), dv_.reshape(sk, dv)
-
-    flat = lambda x: x.reshape((b * h,) + x.shape[2:])  # noqa: E731
-    dq, dk, dv_ = jax.vmap(per_head)(flat(q), flat(k), flat(v), flat(out),
-                                     flat(cot))
-    return (dq.reshape(q.shape).astype(q.dtype),
-            dk.reshape(k.shape).astype(k.dtype),
-            dv_.reshape(v.shape).astype(v.dtype))
 
 
 # ---- the backward as kernels -----------------------------------------
@@ -535,86 +445,44 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, cot):
-    """The backward is a dispatch of its own (``flash_attention_bwd``). A
-    forward that ran in the interpreter asks for the same; on the chip the
-    table, or the family's default, decides."""
+    """The backward is a dispatch of its own (``flash_attention_bwd``), at
+    blocks of its own. A forward that ran in the interpreter asks for the
+    same; on the chip the table, or the family's default, decides."""
     from . import dispatch
 
+    del block_q, block_k  # the forward's
     return dispatch("flash_attention_bwd", *res, cot, scale, causal=causal,
-                    block_q=block_q, block_k=block_k,
                     interpret=True if interpret else None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# ---- registry wiring -------------------------------------------------
-
-def _kernel(q, k, v, scale, causal=False, block_q=128, block_k=128,
-            interpret=False):
-    return _flash(q, k, v, float(scale), bool(causal), int(block_q),
-                  int(block_k), bool(interpret))
-
-
-def _xla(q, k, v, scale, causal=False, block_q=128, block_k=128):
-    del block_q, block_k  # dense path has no blocking
-    return flash_attention_reference(q, k, v, scale, causal)
-
-
+# ---- the blocks, from the shape ---------------------------------------
+#
 # Queries and keys share one head width ``d``; values (and so the output)
-# may have their own, ``dv`` (latent attention: 192 | 128). Where the two
-# are equal the traced program is what it was before ``dv`` existed, and so
-# is every line number above this one: a Mosaic call's payload carries the
-# source lines of its callers, and a moved line is a new executable.
-
-def _pow2(n):
-    p = 1
-    while p < n:
-        p *= 2
-    return p
-
-
-def _bucket(q, k, v, scale, causal=False, block_q=128, block_k=128):
-    """Sequence lengths and batch*heads round UP to powers of two (one
-    table row covers the whole bucket); head dims and dtype are exact —
-    they change the kernel's tiling, not just its trip count. A value
-    width of its own is named after the query/key width (``d192v128``);
-    equal widths keep the key they always had."""
-    b, h, sq, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
-    width = f"d{d}" if dv == d else f"d{d}v{dv}"
-    return (f"bh{_pow2(b * h)}_sq{_pow2(sq)}_sk{_pow2(sk)}_{width}_"
-            f"{jnp.dtype(q.dtype).name}_c{int(bool(causal))}_"
-            f"q{block_q}k{block_k}")
-
-
-def _supports(q, k, v, scale, causal=False, block_q=128, block_k=128):
-    """The statically checkable Mosaic constraints: S divisible by the
-    block sizes, both head widths a multiple of 8 up to 512, rank-4
-    inputs, keys as wide as the queries and as many as the values."""
-    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
-        return False
-    sq, sk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
-    return (sq % block_q == 0 and sk % block_k == 0
-            and k.shape[3] == d and v.shape[2] == sk
-            and d % 8 == 0 and 0 < d <= 512
-            and dv % 8 == 0 and 0 < dv <= 512)
-
+# may have their own, ``dv`` (latent attention: 192 | 128).
 
 def default_blocks(sq, sk, d, dv):
-    """``(block_q, block_k)`` for a caller with no preference of its own.
+    """``(block_q, block_k)`` of the forward kernel, from the shape alone.
     Equal widths keep the 128 x 128 they always had (the only size
     measured at d64). With a value width of its own, up to 256 wide: the
     largest power of two up to 1024 that divides the length. Measured on
     a v5e at 2 x 32 heads x 4096, 192 | 128, causal, forward alone: 34.3
     ms at 128 x 128, 13.4 at 256, 7.2 at 512, 5.6 at 512 x 1024, 4.9 at
-    1024; 2048 x 1024 does not fit VMEM (PERF.md, PR 26). These are the
-    forward's blocks, and the scan's where it runs; the backward kernels
-    take their own (``backward_blocks``)."""
+    1024; 2048 x 1024 does not fit VMEM (PERF.md, PR 26). The backward
+    kernels take their own (``backward_blocks``)."""
     if d == dv or max(d, dv) > 256:
         return 128, 128
     # s & -s: the largest power of two that divides s
     return (max(128, min(sq & -sq, 1024)), max(128, min(sk & -sk, 1024)))
+
+
+def _blocks(q, k, v, block_q=None, block_k=None):
+    """The forward's blocks: the shape's, unless the caller forced a
+    tile."""
+    bq, bk = default_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3])
+    return int(block_q or bq), int(block_k or bk)
 
 
 def backward_blocks(sq, sk, d, dv):
@@ -629,10 +497,9 @@ def backward_blocks(sq, sk, d, dv):
     PR 27): 2 x 32 heads x 4096, 192 | 128, causal: 128 x 128 31.6 / 51.4,
     256 x 256 13.6 / 19.3, 256 x 512 11.8 / 16.4, 512 x 256 11.8 / 16.0,
     **512 x 512 10.5 / 14.2**, 1024 x 512 10.5 / 14.0, 1024 x 256 11.2 /
-    14.9, 256 x 1024 11.2 / 15.5, 512 x 1024 out of VMEM / 14.3; the scan
-    at the forward's 1024 x 1024: 60.7. 32 x 12 heads x 384, d64, no mask:
-    128 x 128 3.36 / 4.53, 384 x 128 2.47 / 2.95, 128 x 384 2.35 / 2.98,
-    **384 x 384 2.04 / 2.37**; the scan at 128 x 128: 4.78. Inside the
+    14.9, 256 x 1024 11.2 / 15.5, 512 x 1024 out of VMEM / 14.3. 32 x 12
+    heads x 384, d64, no mask: 128 x 128 3.36 / 4.53, 384 x 128 2.47 /
+    2.95, 128 x 384 2.35 / 2.98, **384 x 384 2.04 / 2.37**. Inside the
     cells' steps the fused call reads 7.7 and 0.53 ms."""
     del d, dv  # every width measured takes the same blocks
 
@@ -646,36 +513,90 @@ def _blocks_for(q, k, v):
     return backward_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3])
 
 
-def _bwd_kernel(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
-                block_k=128, interpret=False):
-    del block_q, block_k  # the forward's, which the scan shares
-    bq, bk = _blocks_for(q, k, v)
+# ---- registry wiring -------------------------------------------------
+
+def _kernel(q, k, v, scale, causal=False, block_q=None, block_k=None,
+            interpret=False):
+    return _flash(q, k, v, float(scale), bool(causal),
+                  *_blocks(q, k, v, block_q, block_k), bool(interpret))
+
+
+def _xla(q, k, v, scale, causal=False, block_q=None, block_k=None):
+    del block_q, block_k  # dense path has no blocking
+    return flash_attention_reference(q, k, v, scale, causal)
+
+
+def _pow2(n):
+    p = 1
+    while p < n:
+        p *= 2
+    return p
+
+
+def _bucket(q, k, v, scale, causal=False, block_q=None, block_k=None):
+    """Sequence lengths and batch*heads round UP to powers of two (one
+    table row covers the whole bucket); head dims and dtype are exact —
+    they change the kernel's tiling, not just its trip count. A value
+    width of its own is named after the query/key width (``d192v128``);
+    equal widths keep the key they always had. The blocks are the ones
+    the kernel will run with."""
+    b, h, sq, d = q.shape
+    sk, dv = k.shape[2], v.shape[3]
+    block_q, block_k = _blocks(q, k, v, block_q, block_k)
+    width = f"d{d}" if dv == d else f"d{d}v{dv}"
+    return (f"bh{_pow2(b * h)}_sq{_pow2(sq)}_sk{_pow2(sk)}_{width}_"
+            f"{jnp.dtype(q.dtype).name}_c{int(bool(causal))}_"
+            f"q{block_q}k{block_k}")
+
+
+def _supports(q, k, v, scale, causal=False, block_q=None, block_k=None):
+    """The statically checkable Mosaic constraints, of the forward and of
+    the backward it will ask for: rank-4 inputs, keys as wide as the
+    queries and as many as the values, both head widths a multiple of 8
+    up to 512, S divisible by the forward's blocks, and each of the
+    backward's blocks the whole sequence or a multiple of the 128 lanes a
+    row of statistics is tiled by. No length the default blocks divide
+    fails the last; a forced pair under 128 can (64 on 576 positions),
+    and the shape then takes dense XLA forward and backward."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+        return False
+    sq, sk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
+    block_q, block_k = _blocks(q, k, v, block_q, block_k)
+    return (sq % block_q == 0 and sk % block_k == 0
+            and k.shape[3] == d and v.shape[2] == sk
+            and d % 8 == 0 and 0 < d <= 512
+            and dv % 8 == 0 and 0 < dv <= 512
+            and all(b == s or b % 128 == 0 for b, s in zip(
+                backward_blocks(sq, sk, d, dv), (sq, sk))))
+
+
+def _bwd_kernel(q, k, v, out, lse, cot, scale, causal=False,
+                interpret=False):
     return flash_backward_kernel(q, k, v, out, lse, cot, float(scale),
-                                 bool(causal), bq, bk, bool(interpret))
+                                 bool(causal), *_blocks_for(q, k, v),
+                                 bool(interpret))
 
 
-def _bwd_xla(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
-             block_k=128):
-    del lse  # the scan computes the rows' statistics in a pass of its own
-    return _flash_backward(q, k, v, out, cot, scale, causal, block_q,
-                           block_k)
+def _bwd_xla(q, k, v, out, lse, cot, scale, causal=False):
+    """The gradient of the dense reference: the XLA side of the pair is
+    one function, forward and backward. It holds the (S, S) probabilities
+    the kernels exist to avoid (2.1 GB a layer at the language model's
+    shape), and no default decision takes it: only a table row or
+    ``MXNET_TPU_KERNELS=0`` sends a bucket here."""
+    del out, lse
+    _, vjp = jax.vjp(lambda a, b, c: flash_attention_reference(
+        a, b, c, scale, causal), q, k, v)
+    return vjp(cot)
 
 
-def _bwd_bucket(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
-                block_k=128):
+def _bwd_bucket(q, k, v, out, lse, cot, scale, causal=False):
     """The forward's key with the backward's own blocks."""
     return _bucket(q, k, v, scale, causal, *_blocks_for(q, k, v))
 
 
-def _bwd_supports(q, k, v, out, lse, cot, scale, causal=False, block_q=128,
-                  block_k=128):
-    """What the forward kernel takes, at the backward's blocks, each the
-    whole sequence or a multiple of the 128 lanes a row of statistics is
-    tiled by."""
-    bq, bk = _blocks_for(q, k, v)
-    return (_supports(q, k, v, scale, causal, bq, bk)
-            and (bq == q.shape[2] or bq % 128 == 0)
-            and (bk == k.shape[2] or bk % 128 == 0))
+def _bwd_supports(q, k, v, out, lse, cot, scale, causal=False):
+    """The forward's condition, at the backward's blocks."""
+    return _supports(q, k, v, scale, causal, *_blocks_for(q, k, v))
 
 
 def _register():
@@ -689,10 +610,10 @@ def _register():
     register_kernel(
         "flash_attention_bwd", kernel=_bwd_kernel, xla=_bwd_xla,
         bucket=_bwd_bucket, supports=_bwd_supports, default_tpu=True,
-        tolerance="f32 rtol=2e-4 atol=2e-5 vs the dense gradient and vs "
-                  "the scan in the interpreter (256 positions); compiled, "
-                  "at 1024 positions and highest precision, within 1e-4 "
-                  "of the largest |gradient|; bf16 operands (p and dS "
+        tolerance="f32 rtol=2e-4 atol=2e-5 vs the dense gradient in the "
+                  "interpreter (256 positions); compiled, at 1024 positions "
+                  "and highest precision, within 1e-4 of the largest "
+                  "|gradient|; bf16 operands (p and dS "
                   "rounded to bf16 before their matmuls, float32 sums): "
                   "within 2e-2 of the largest |gradient| of the float32 "
                   "dense gradient of the same rounded inputs")

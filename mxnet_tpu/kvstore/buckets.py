@@ -380,8 +380,8 @@ def census():
 
 def comm_stats():
     """Aggregate gradient-comms stats over live pipelines — the
-    telemetry collector's source for ``mxtpu_kvstore_overlap_ratio`` /
-    fused-collective counters, and the bench.py train-line fields."""
+    telemetry collector's source for ``mxtpu_kvstore_overlap_ratio`` and
+    the fused-collective counters."""
     agg = {"fused": 0, "keys": 0, "bytes": 0, "partial": 0, "drains": 0,
            "resolved": 0, "wait_ms": 0.0, "window_ms": 0.0, "pending": 0,
            "max_pending": 0, "pipelines": 0}

@@ -30,18 +30,20 @@ __all__ = ["flash_attention_reference", "decode_attention_reference"]
 
 @register("_contrib_flash_attention")
 def _contrib_flash_attention(q, k, v, scale=None, causal=False,
-                             block_q=128, block_k=128, interpret=False):
+                             block_q=None, block_k=None, interpret=False):
     """Fused attention over (B, H, S, D) tensors.
 
     Dispatches to the Pallas flash kernel (registry family
     ``flash_attention``) when the shape passes the statically checkable
     Mosaic constraints AND the dispatch table (or the on-TPU default)
     picks it; dense XLA softmax otherwise. `interpret=True` forces the
-    kernel through the Pallas interpreter (CPU CI). Training memory
-    stays O(S*block): the kernel's backward is a dispatch of its own
-    (family ``flash_attention_bwd``: Pallas dK/dV and dQ calls that
-    recompute the probabilities tile by tile from the saved row
-    log-sum-exp, or the scanned recurrence), not a dense recompute."""
+    kernel through the Pallas interpreter (CPU CI). ``block_q`` /
+    ``block_k`` None means the kernel's own, picked from the shape
+    (``kernels/flash.py``); a pair forces a tile. Training memory stays
+    O(S*block): the kernel's backward is a dispatch of its own (family
+    ``flash_attention_bwd``: Pallas calls that recompute the
+    probabilities tile by tile from the saved row log-sum-exp), not a
+    dense recompute."""
     if q.ndim != 4:
         raise ValueError(
             f"flash_attention expects (B, H, S, D) inputs, got rank "
@@ -52,7 +54,7 @@ def _contrib_flash_attention(q, k, v, scale=None, causal=False,
 
     return _kernels.dispatch(
         "flash_attention", q, k, v, float(scale), causal=bool(causal),
-        block_q=int(block_q), block_k=int(block_k),
+        block_q=block_q, block_k=block_k,
         interpret=bool(interpret) or None)
 
 

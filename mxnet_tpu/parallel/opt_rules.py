@@ -86,15 +86,8 @@ def _sgd_update(opt, w, g, st, lr, wd, t, rng):
     kw = dict(lr=_lr_of(lr, w), wd=wd, rescale_grad=opt.rescale_grad,
               clip_gradient=_clip(opt))
     if opt.momentum:
-        # fused Pallas step (registry family opt_sgd) where the dispatch
-        # table proved it; the XLA baseline is sgd_mom_update itself, so
-        # routing is numerics-neutral (bit-exact contract under jit)
-        from .. import kernels as _kernels
-
-        w2, m2 = _kernels.dispatch(
-            "opt_sgd", w, g, st[0], kw["lr"], momentum=opt.momentum,
-            wd=wd, rescale_grad=opt.rescale_grad,
-            clip_gradient=_clip(opt))
+        w2, m2 = K.sgd_mom_update.fn(w, g, st[0], momentum=opt.momentum,
+                                     **kw)
         return w2, (m2,)
     return K.sgd_update.fn(w, g, **kw), ()
 
@@ -199,15 +192,11 @@ def _dcasgd_update(opt, w, g, st, lr, wd, t, rng):
 # ----------------------------------------------------------- Adam family ---
 
 def _adam_update(opt, w, g, st, lr, wd, t, rng):
-    # bias correction folded into lr (reference Adam semantics); the
-    # fused Pallas step (family opt_adam) routes by dispatch table with
-    # adam_update as its bit-exact XLA baseline
+    # bias correction folded into lr (reference Adam semantics)
     lr_eff = lr * jnp.sqrt(1.0 - opt.beta2 ** t) / (1.0 - opt.beta1 ** t)
-    from .. import kernels as _kernels
-
-    w2, m2, v2 = _kernels.dispatch(
-        "opt_adam", w, g, st[0], st[1], _lr_of(lr_eff, w),
-        beta1=opt.beta1, beta2=opt.beta2, epsilon=opt.epsilon, wd=wd,
+    w2, m2, v2 = K.adam_update.fn(
+        w, g, st[0], st[1], lr=_lr_of(lr_eff, w), beta1=opt.beta1,
+        beta2=opt.beta2, epsilon=opt.epsilon, wd=wd,
         rescale_grad=opt.rescale_grad, clip_gradient=_clip(opt))
     return w2, (m2, v2)
 

@@ -16,7 +16,7 @@ both sides:
   override preserved); a device kind the table does not know is an
   error, never a default.
 
-``bench.py`` and ``ShardedTrainer`` report ``mfu_xla`` =
+``ShardedTrainer`` reports ``mfu_xla`` =
 ``measured flops/step x steps/s ÷ (peak x devices)`` alongside the
 legacy nominal ``mfu``: a ratio whose numerator is what XLA actually
 scheduled.

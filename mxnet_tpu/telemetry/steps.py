@@ -44,8 +44,7 @@ Each finished step publishes gauges (``mxtpu_step_time_ms``,
 counter, and — when the compile service captured ``cost_analysis()``
 flops for the step executable — ``mxtpu_step_mfu_xla`` (measured flops ÷
 the per-device-kind peak table), plus ``step.begin``/``step.end`` flight
-events. ``bench.py`` and ``ShardedTrainer.step_report()`` read the same
-records.
+events. ``ShardedTrainer.step_report()`` reads the same records.
 """
 from __future__ import annotations
 

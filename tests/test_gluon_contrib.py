@@ -296,6 +296,28 @@ def test_transformer_encoder_cell_trains():
     assert net(x).shape == (B, S, U)
 
 
+def test_multi_head_attention_names_no_block():
+    """The layer hands the op no block and the kernel family picks them
+    from the shape; a pair named at the op forces a tile and lands in the
+    bucket's key."""
+    from mxnet_tpu import kernels
+    from mxnet_tpu.gluon.contrib.nn import MultiHeadAttention
+
+    attn = MultiHeadAttention(32, 4, interpret=True)
+    attn.initialize(mx.init.Xavier())
+    kernels.reset_stats()
+    assert attn(mx.nd.random.uniform(-1, 1, (2, 256, 32))).shape \
+        == (2, 256, 32)
+    assert list(kernels.dispatch_stats()["flash_attention"]["buckets"]) \
+        == ["bh8_sq256_sk256_d8_float32_c0_q128k128"]
+    kernels.reset_stats()
+    q = mx.nd.random.uniform(-1, 1, (1, 2, 128, 8))
+    mx.nd.contrib.flash_attention(q, q, q, block_q=64, block_k=32,
+                                  interpret=True)
+    assert list(kernels.dispatch_stats()["flash_attention"]["buckets"]) \
+        == ["bh2_sq128_sk128_d8_float32_c0_q64k32"]
+
+
 def test_multi_head_attention_kernel_path_and_export(tmp_path):
     """Kernel-friendly shapes through the Pallas interpreter (d%8==0,
     S%block==0) match the dense fallback; the block exports to Symbol
@@ -305,8 +327,7 @@ def test_multi_head_attention_kernel_path_and_export(tmp_path):
 
     B, S, U, H = 1, 128, 32, 4  # head dim 8, S == block size
     mx.random.seed(2)
-    flash = MultiHeadAttention(U, H, causal=True, interpret=True,
-                               block_q=64, block_k=64)
+    flash = MultiHeadAttention(U, H, causal=True, interpret=True)
     flash.initialize(mx.init.Xavier())
     x = mx.nd.random.uniform(-1, 1, (B, S, U))
     out_kernel = flash(x)

@@ -2,7 +2,7 @@
 
 Numeric contracts asserted here (each family's ``tolerance`` field):
 
-* opt_sgd / opt_adam / int8_gemm / twobit_* are **bit-exact vs their XLA
+* int8_gemm / twobit_* are **bit-exact vs their XLA
   baseline under jit** — both sides compiled, XLA applies the same FMA
   contraction to both, so ``==`` holds elementwise. (Eager-vs-jit is NOT
   bit-exact — op-by-op eager dispatch skips contraction — so the eager
@@ -28,7 +28,7 @@ from mxnet_tpu.kernels import table as ktable
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FAMILIES = ("decode_attention", "flash_attention", "flash_attention_bwd",
-            "int8_gemm", "opt_adam", "opt_sgd", "twobit_compress",
+            "int8_gemm", "twobit_compress",
             "twobit_decompress")
 
 
@@ -86,6 +86,93 @@ def test_flash_kernel_vs_xla(causal):
                                rtol=2e-5, atol=2e-5)
 
 
+_BERT = ((32, 12, 384, 64), 64, "bfloat16", False)       # q shape, dv
+_LM = ((2, 32, 4096, 192), 128, "bfloat16", True)
+
+
+@pytest.mark.parametrize("shape,family,blocks,key", [
+    # the four buckets the benchmark's attention cells print (PERF.md
+    # section 3): nobody names a block, the family reads the shape
+    (_BERT, "flash_attention", {},
+     "bh512_sq512_sk512_d64_bfloat16_c0_q128k128"),
+    (_BERT, "flash_attention_bwd", {},
+     "bh512_sq512_sk512_d64_bfloat16_c0_q384k384"),
+    (_LM, "flash_attention", {},
+     "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q1024k1024"),
+    (_LM, "flash_attention_bwd", {},
+     "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k512"),
+    # equal widths stay at 128 x 128 however long the sequence
+    (((1, 8, 2048, 128), 128, "float32", True), "flash_attention", {},
+     "bh8_sq2048_sk2048_d128_float32_c1_q128k128"),
+    # a pair the caller names forces the tile and lands in the key
+    (_LM, "flash_attention", {"block_q": 512, "block_k": 256},
+     "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k256"),
+    (_BERT, "flash_attention", {"block_k": 384},
+     "bh512_sq512_sk512_d64_bfloat16_c0_q128k384"),
+], ids=["bert_fwd", "bert_bwd", "lm_fwd", "lm_bwd", "equal_widths_2048",
+        "forced_pair", "forced_one"])
+def test_flash_blocks_come_from_the_shape(shape, family, blocks, key):
+    import jax
+    import jax.numpy as jnp
+
+    (b, h, s, d), dv, dtype, causal = shape
+    q = k = jax.ShapeDtypeStruct((b, h, s, d), jnp.dtype(dtype))
+    v = out = cot = jax.ShapeDtypeStruct((b, h, s, dv), jnp.dtype(dtype))
+    lse = jax.ShapeDtypeStruct((b, h, s), jnp.float32)
+    args = (q, k, v) if family == "flash_attention" \
+        else (q, k, v, out, lse, cot)
+    e = kernels.entry(family)
+    assert e.supports(*args, d ** -0.5, causal=causal, **blocks)
+    assert e.bucket(*args, d ** -0.5, causal=causal, **blocks) == key
+
+
+def test_flash_supports_is_one_condition_forward_and_backward():
+    """A shape the backward kernels cannot take is refused at the forward
+    (dense XLA and autodiff), which only a forced pair under 128 can
+    meet: 64 x 64 on 576 positions leaves the backward a block of 64."""
+    import jax
+    import jax.numpy as jnp
+
+    e = kernels.entry("flash_attention")
+    q = jax.ShapeDtypeStruct((1, 2, 576, 64), jnp.float32)
+    assert not e.supports(q, q, q, 0.125, block_q=64, block_k=64)
+    q = jax.ShapeDtypeStruct((1, 2, 512, 64), jnp.float32)
+    assert e.supports(q, q, q, 0.125, block_q=64, block_k=64)
+    q = jax.ShapeDtypeStruct((1, 2, 100, 64), jnp.float32)
+    assert not e.supports(q, q, q, 0.125)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_backward_xla_side_is_the_dense_gradient(causal):
+    """The XLA side of the pair is one function forward and backward: the
+    backward family's is the gradient of the forward family's."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import flash
+
+    rng = np.random.RandomState(3)
+    q, k = (jnp.asarray(rng.randn(1, 2, 128, 32), jnp.float32)
+            for _ in range(2))
+    v, cot = (jnp.asarray(rng.randn(1, 2, 128, 16), jnp.float32)
+              for _ in range(2))
+    scale = 32 ** -0.5
+    out, vjp = jax.vjp(lambda a, b, c: kernels.entry("flash_attention").xla(
+        a, b, c, scale, causal=causal), q, k, v)
+    lse = flash.row_log_sum_exp(q, k, scale, causal)
+    got = kernels.entry("flash_attention_bwd").xla(
+        q, k, v, out, lse, cot, scale, causal=causal)
+    for g, w in zip(got, vjp(cot)):
+        assert g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # and the kernel side of the same family agrees with it
+    kern = kernels.entry("flash_attention_bwd").kernel(
+        q, k, v, out, lse, cot, scale, causal=causal, interpret=True)
+    for g, w in zip(kern, got):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5)
+
+
 def test_decode_attention_kernel_vs_xla():
     import jax.numpy as jnp
 
@@ -108,55 +195,6 @@ def test_decode_attention_kernel_vs_xla():
     out2 = e.kernel(q, k2, v, lengths, 0.125, interpret=True)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(out),
                                rtol=2e-5, atol=2e-5)
-
-
-def _opt_inputs(n=5000, seed=2):
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(seed)
-    return [jnp.asarray(rng.randn(n).astype(np.float32) * s)
-            for s in (1.0, 0.1, 0.01, 0.001)]
-
-
-def test_opt_sgd_bit_exact_under_jit():
-    w, g, mom, _ = _opt_inputs()
-    e = kernels.entry("opt_sgd")
-    kw = dict(momentum=0.9, wd=1e-4, rescale_grad=0.5, clip_gradient=1.0)
-    kfn = _jit(lambda *a: e.kernel(*a, interpret=True, **kw))
-    xfn = _jit(lambda *a: e.xla(*a, **kw))
-    w_k, m_k = kfn(w, g, mom, 0.05)
-    w_x, m_x = xfn(w, g, mom, 0.05)
-    assert np.array_equal(np.asarray(w_k), np.asarray(w_x))
-    assert np.array_equal(np.asarray(m_k), np.asarray(m_x))
-    # ... and the eager op it replaces (1-ULP-scale tolerance: the eager
-    # path skips the FMA contraction jit applies to both sides above)
-    from mxnet_tpu.ops import optimizer_op as op
-
-    w_e, m_e = op.sgd_mom_update.fn(w, g, mom, lr=0.05, **kw)
-    np.testing.assert_allclose(np.asarray(w_k), np.asarray(w_e),
-                               rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(m_k), np.asarray(m_e),
-                               rtol=1e-6, atol=1e-7)
-
-
-def test_opt_adam_bit_exact_under_jit():
-    w, g, mean, var = _opt_inputs(seed=3)
-    var = abs(var)
-    e = kernels.entry("opt_adam")
-    kw = dict(beta1=0.9, beta2=0.999, epsilon=1e-8, wd=1e-4,
-              rescale_grad=1.0, clip_gradient=-1.0)
-    kfn = _jit(lambda *a: e.kernel(*a, interpret=True, **kw))
-    xfn = _jit(lambda *a: e.xla(*a, **kw))
-    got = kfn(w, g, mean, var, 0.001)
-    want = xfn(w, g, mean, var, 0.001)
-    for a, b in zip(got, want):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    from mxnet_tpu.ops import optimizer_op as op
-
-    eager = op.adam_update.fn(w, g, mean, var, lr=0.001, **kw)
-    for a, b in zip(got, eager):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
 
 
 @pytest.mark.parametrize("relu,bias", [(False, False), (True, True)])
@@ -426,7 +464,7 @@ def test_opperf_kernels_writes_table(kernel_cache_dir):
 def test_opperf_kernels_has_a_row_for_the_attention_backward(
         kernel_cache_dir):
     """The backward is a family of its own: the autotuner times its
-    kernels against the scan and the table holds the row under the
+    kernels against the dense gradient and the table holds the row under the
     backward's bucket (its own blocks), which then routes its dispatch
     while the forward's stays untuned."""
     sys.path.insert(0, os.path.join(REPO, "benchmark"))
@@ -451,7 +489,7 @@ def test_opperf_kernels_has_a_row_for_the_attention_backward(
 
 
 # ===================================================================== #
-# trainer integration — fused optimizer step parity                     #
+# trainer integration — the MXNET_TPU_KERNELS=0 opt-out                 #
 # ===================================================================== #
 
 @pytest.mark.slow

@@ -344,26 +344,6 @@ def test_trainer_aot_lower_compile_clean():
     assert onp.isfinite(float(loss.asscalar()))
 
 
-def test_bench_gradcomms_fields():
-    from mxnet_tpu.gluon import loss as gloss, nn
-    from mxnet_tpu.parallel import DeviceMesh, ShardedTrainer
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    import bench
-
-    net = nn.Dense(4)
-    net.initialize()
-    net(mx.nd.ones((4, 8)))
-    tr = ShardedTrainer(net, gloss.L2Loss(), "sgd",
-                        {"learning_rate": 0.01}, mesh=DeviceMesh({"dp": 1}))
-    for _ in range(2):
-        tr.step(mx.nd.ones((4, 8)), mx.nd.ones((4, 4))).wait_to_read()
-    line = bench._gradcomms_fields({}, steps=2)
-    assert line["sync_ms_mean"] >= 0  # the nan-guard's blocking read
-    assert "overlap_ratio" in line  # null single-host, present always
-
-
 def test_diagnose_grad_comms_section(monkeypatch):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, os.path.join(repo, "tools"))

@@ -12,8 +12,8 @@ Headline guarantees under test:
   preemption drain event (``flight_tail``) — an injected hang's bundle
   names the wedged point and carries the preceding step events;
 * the compile service captures XLA ``cost_analysis``/``memory_analysis``
-  per executable, from which ``ShardedTrainer.step_report()`` and
-  ``bench.py`` derive ``mfu_xla`` and the per-step phase breakdown;
+  per executable, from which ``ShardedTrainer.step_report()`` derives
+  ``mfu_xla`` and the per-step phase breakdown;
 * trace integrity: a full ``profiler.dump()`` of a bulked + compile +
   serving run is a valid Chrome-trace envelope with monotone-timestamped
   counter tracks;
@@ -465,24 +465,6 @@ def test_trace_integrity_bulk_compile_serving(tmp_path):
 
 
 # ------------------------------------------------------------- satellites ---
-
-def test_bench_mfu_xla_fields(monkeypatch):
-    """bench.py's mfu_xla fields come from the cost analysis the compile
-    service captured for the trainer step; the peak is the caller's
-    (here an explicit override: the CPU backend has none)."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    trainer, x, y = small_trainer(seed=5)
-    trainer.step(x, y).wait_to_read()
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "100")
-    line = bench._mfu_xla_fields({}, "trainer", 10.0)
-    assert line.get("xla_flops_per_call", 0) > 0
-    assert 0 <= line["mfu_xla"] < 1.0
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS")
-    with pytest.raises(LookupError, match="no peak"):
-        bench._mfu_xla_fields({}, "trainer", 10.0)
-
 
 def test_telemetry_describe_and_snapshot():
     d = telemetry.describe()

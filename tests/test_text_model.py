@@ -289,21 +289,18 @@ def _dense_gradient(q, k, v, cot, scale, causal):
     for causal in (False, True)
     for blocks in [(128, 128), (64, 128), (256, 256)]
 ] + [(32, 16, 128, False, (64, 128))])
-def test_flash_backward_kernel_against_dense_and_scan(d, dv, sk, causal,
-                                                      blocks, fused):
+def test_flash_backward_kernel_against_dense(d, dv, sk, causal, blocks,
+                                             fused):
     q, k, v, cot, scale = _attention_case(d, dv, causal, sk=sk)
     out, lse = flash.flash_forward_lse(q, k, v, scale, causal, 128, 128,
                                        interpret=True)
     got = flash.flash_backward_kernel(q, k, v, out, lse, cot, scale, causal,
                                       *blocks, interpret=True, fused=fused)
     dense = _dense_gradient(q, k, v, cot, scale, causal)
-    scan = flash._flash_backward(q, k, v, out, cot, scale, causal, 128, 128)
-    for g, w, s, name in zip(got, dense, scan, ("dq", "dk", "dv")):
+    for g, w, name in zip(got, dense, ("dq", "dk", "dv")):
         assert g.shape == w.shape and g.dtype == w.dtype
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
-                                   atol=2e-5, err_msg=name + " vs dense")
-        np.testing.assert_allclose(np.asarray(g), np.asarray(s), rtol=2e-4,
-                                   atol=2e-5, err_msg=name + " vs scan")
+                                   atol=2e-5, err_msg=name)
 
 
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_calls"])
@@ -398,9 +395,10 @@ def test_flash_backward_is_a_counted_decision_of_its_own(monkeypatch):
         "buckets": {"bh2_sq256_sk256_d32v16_float32_c1_q256k256":
                     {"kernel": 1, "xla": 0}}}
     assert list(stats["flash_attention"]["buckets"]) \
-        == ["bh2_sq256_sk256_d32v16_float32_c1_q128k128"]
+        == ["bh2_sq256_sk256_d32v16_float32_c1_q256k256"]
     # the table decides it like any family: a row for the bucket that
-    # names the scan sends the backward there, and the result agrees
+    # names XLA sends the backward to the dense gradient, and the result
+    # agrees
     e = kernels.entry("flash_attention_bwd")
     monkeypatch.setattr(
         kernels.table, "lookup", lambda family, bucket: {"winner": "xla"}
@@ -409,10 +407,10 @@ def test_flash_backward_is_a_counted_decision_of_its_own(monkeypatch):
                                        interpret=True)
     assert kernels.choice_for("flash_attention_bwd", q, k, v, out, lse, cot,
                               scale, causal=True) == ("xla", "tuned")
-    scan = kernels.dispatch("flash_attention_bwd", q, k, v, out, lse, cot,
-                            scale, causal=True)
-    for g, s in zip(got, scan):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(s), rtol=2e-4,
+    dense = kernels.dispatch("flash_attention_bwd", q, k, v, out, lse, cot,
+                             scale, causal=True)
+    for g, w in zip(got, dense):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
                                    atol=2e-5)
     monkeypatch.undo()
     # MXNET_TPU_KERNELS=0 covers it
@@ -429,7 +427,7 @@ def test_flash_backward_is_a_counted_decision_of_its_own(monkeypatch):
 
     assert calls(4096, 192) == 1 and calls(8192, 192) == 2
     # blocks follow the shape: 512 at 4,096 positions, the whole sequence
-    # at BERT's 384, and a length no block of 128s divides is the scan's
+    # at BERT's 384, and a length no block of 128s divides is refused
     assert flash.backward_blocks(4096, 4096, 192, 128) == (512, 512)
     assert flash.backward_blocks(384, 384, 64, 64) == (384, 384)
     assert flash.backward_blocks(1536, 640, 64, 64) == (512, 128)
@@ -440,8 +438,7 @@ def test_flash_backward_is_a_counted_decision_of_its_own(monkeypatch):
         *(jnp.zeros((2, 32, 4096, w), jnp.bfloat16) for w in (192, 192, 128,
                                                                128)),
         jnp.zeros((2, 32, 4096)), jnp.zeros((2, 32, 4096, 128)), 0.07,
-        causal=True, block_q=1024, block_k=1024) \
-        == "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k512"
+        causal=True) == "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k512"
 
 
 def test_flash_bucket_and_supports_know_both_widths():
@@ -454,7 +451,7 @@ def test_flash_bucket_and_supports_know_both_widths():
     assert flash._bucket(*arrays(64, 64), 0.125) \
         == "bh8_sq256_sk256_d64_bfloat16_c0_q128k128"
     assert flash._bucket(*arrays(192, 128), 0.1, causal=True) \
-        == "bh8_sq256_sk256_d192v128_bfloat16_c1_q128k128"
+        == "bh8_sq256_sk256_d192v128_bfloat16_c1_q256k256"
     assert flash._supports(*arrays(192, 128), 0.1)
     assert not flash._supports(*arrays(192, 100), 0.1)     # dv % 8
     q, k, v = arrays(192, 128)
@@ -465,7 +462,7 @@ def test_flash_bucket_and_supports_know_both_widths():
                      interpret=True)
     stats = kernels.dispatch_stats()["flash_attention"]
     assert stats["buckets"] == {
-        "bh8_sq256_sk256_d32v16_bfloat16_c1_q128k128":
+        "bh8_sq256_sk256_d32v16_bfloat16_c1_q256k256":
             {"kernel": 1, "xla": 0}}
 
 
@@ -480,9 +477,17 @@ def test_default_blocks_follow_the_shape():
     assert flash.default_blocks(1536, 384, 192, 128) == (512, 128)
     assert flash.default_blocks(100, 100, 192, 128) == (128, 128)
     assert flash.default_blocks(4096, 4096, 512, 256) == (128, 128)
-    # and that is what latent attention runs with
+
+
+@pytest.mark.parametrize("hybridize", [False, True],
+                         ids=["imperative", "hybridized"])
+def test_mlattention_names_no_block(hybridize):
+    """The layer hands the op no block: the kernel family picks them from
+    the shape, on the imperative path and through a traced Symbol alike."""
     attn = nn.MLAttention(64, 4, 32, 24, 8, 16, interpret=True)
     attn.initialize(mx.init.Xavier())
+    if hybridize:
+        attn.hybridize()
     kernels.reset_stats()
     out = attn(mx.nd.array(np.random.RandomState(0).randn(1, 256, 64)))
     assert out.shape == (1, 256, 64)

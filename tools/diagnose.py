@@ -861,7 +861,7 @@ def check_dataplane():
     except (OSError, ValueError):
         out["last_iter_bench"] = None
         _p("last bench    : none recorded (run benchmark/iter_bench.py "
-           "--augment or bench.py)")
+           "--augment)")
     return out
 
 

@@ -31,8 +31,7 @@ default      in-process ModelServer over --models small MLPs
 --workers N  multi-process mode: an N-worker ``ServingFleet`` (one
              ModelServer process per worker behind the router front
              door) driven closed-loop over HTTP — the 1→N rps scaling
-             measurement (bench.py's ``serving_fleet_rps_*`` line runs
-             it at workers=1 and workers=4)
+             measurement (run it at workers=1 and workers=4)
 --dtype D    model-pair mode: ONE embedding-lookup fixture served as
              fp32 and as its entropy-calibrated int8 twin from the same
              warm ladder; ``--dtype both`` drives each variant with the
@@ -65,8 +64,7 @@ Examples::
     python tools/loadgen.py --workers 2 --priority-mix 4:1 \
         --deadline-ms 50 --hot-key-frac 0.3 --duration 10
 
-The last stdout line is one JSON report (bench.py --serve embeds it into
-its serving line).
+The last stdout line is one JSON report.
 """
 from __future__ import annotations
 
