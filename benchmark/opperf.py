@@ -298,6 +298,96 @@ def bench_kernels(runs=10, warmup=3, families=None):
     return {"table_path": path, "stamp": stamp, "results": results}
 
 
+# The flash forward's blocks on the chip, one command (PERF.md section 5
+# quotes its output): the benchmark's two attention buckets and one
+# equal-width long sequence no cell holds, each at the tiles a rule could
+# pick, (block_q, block_k, heads a program). The row of the tile the
+# kernel picks from the shape (``flash.default_blocks``,
+# ``flash.heads_a_program``) is marked ``chosen``.
+_FLASH_SWEEP = [
+    ("bert_base_s384", (32, 12, 384, 64, 64), False,
+     [(128, 128, 1), (384, 128, 1), (128, 384, 1), (384, 384, 1),
+      (384, 384, 2), (384, 384, 4), (384, 384, 8)]),
+    ("kanana2_s4096", (2, 32, 4096, 192, 128), True,
+     [(512, 512, 1), (512, 1024, 1), (1024, 512, 1), (1024, 1024, 1),
+      (1024, 2048, 1), (2048, 1024, 1)]),
+    ("gpt_like_s4096", (2, 32, 4096, 128, 128), True,
+     [(128, 128, 1), (512, 512, 1), (512, 1024, 1), (1024, 1024, 1),
+      (1024, 2048, 1)]),
+]
+
+
+def _device_ms(fn, args, runs, match=""):
+    """Device time a call of ``fn`` spends in the operations whose name
+    holds ``match``, from a profiler trace of ``runs`` calls, read as the
+    benchmark reads its own (``chipbench/harness/trace_reduce.py``); None
+    where the trace has no TPU plane (the interpreter, the CPU)."""
+    import tempfile
+
+    import jax
+    from chipbench.harness import trace_reduce
+
+    with tempfile.TemporaryDirectory() as log_dir:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        for _ in range(runs):
+            jax.block_until_ready(fn(*args))
+        jax.profiler.stop_trace()
+        trace = trace_reduce.load(trace_reduce.find_xplane(log_dir))
+    ns = [self_ns for events in trace["devices"].values()
+          for name, self_ns in trace_reduce.self_times(events)
+          if match in name]
+    return round(sum(ns) / 1e6 / runs, 4) if ns else None
+
+
+def sweep_flash_forward(runs=10, warmup=3, cases=None, dtype="bfloat16"):
+    """Time the flash forward (output and row log-sum-exp, one Mosaic
+    call) alone in a jit at every tile of ``cases`` (``_FLASH_SWEEP``):
+    ``wall_ms`` a call by the host's clock, ``device_ms`` the call's own
+    time in a device trace. A tile the compiler refuses (VMEM) is a row
+    with its ``error``; each case ends with a row for dense XLA."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import kernels as klayer
+    from mxnet_tpu.kernels import flash
+
+    interp = not klayer.on_tpu()
+    r = np.random.default_rng(0)
+    rows = []
+    for label, (b, h, s, d, dv), causal, tiles in cases or _FLASH_SWEEP:
+        q, k, v = (jnp.asarray(r.standard_normal((b, h, s, w),
+                                                 dtype=np.float32), dtype)
+                   for w in (d, d, dv))
+        chosen = flash.default_blocks(s, s, d, dv,
+                                      jnp.dtype(dtype).itemsize)
+        head = {"case": label, "shape": [b, h, s, d, dv], "dtype": dtype,
+                "causal": causal, "interpret": interp}
+        for bq, bk, heads in tiles:
+            fn = jax.jit(lambda *a, _t=(bq, bk), _h=heads, _c=causal, _d=d:
+                         flash.flash_forward_lse(*a, _d ** -0.5, _c, *_t,
+                                                 interpret=interp, heads=_h))
+            row = {**head, "blocks": [bq, bk], "heads": heads,
+                   "chosen": (bq, bk) == chosen
+                   and heads == flash.heads_a_program(b * h, s, s, bq, bk)}
+            try:
+                row["wall_ms"] = round(_time_jitted(fn, (q, k, v), runs,
+                                                    warmup), 4)
+                row["device_ms"] = _device_ms(fn, (q, k, v), runs,
+                                              "tpu_custom_call")
+            except Exception as e:  # the compiler's refusal is the row
+                row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+            rows.append(row)
+        xfn = jax.jit(lambda *a, _c=causal, _d=d:
+                      flash.flash_attention_reference(*a, _d ** -0.5, _c))
+        rows.append({**head, "blocks": "dense XLA", "heads": None,
+                     "chosen": False,
+                     "wall_ms": round(_time_jitted(xfn, (q, k, v), runs,
+                                                   warmup), 4),
+                     "device_ms": _device_ms(xfn, (q, k, v), runs)})
+    return rows
+
+
 def run_benchmark(ops, size=_DEFAULT_SIZE, runs=10, warmup=2):
     results = []
     for name in ops:
@@ -328,11 +418,37 @@ def main():
     parser.add_argument("--families", type=str, default="",
                         help="comma-separated kernel families for "
                              "--kernels (default: all registered)")
+    parser.add_argument("--flash-sweep", type=str, default="",
+                        metavar="DIR",
+                        help="time the flash forward at every tile of "
+                             "_FLASH_SWEEP, print the table and keep it as "
+                             "DIR/flash_forward_sweep.json")
     parser.add_argument("--chain", type=int, default=16,
                         help="op-chain length for --dispatch")
     parser.add_argument("--bulk", type=int, default=16,
                         help="bulk_size for the bulked side of --dispatch")
     args = parser.parse_args()
+
+    if args.flash_sweep:
+        rows = sweep_flash_forward(runs=args.runs, warmup=args.warmup)
+        os.makedirs(args.flash_sweep, exist_ok=True)
+        with open(os.path.join(args.flash_sweep,
+                               "flash_forward_sweep.json"), "w") as f:
+            json.dump(rows, f, indent=1)
+        print(f"{'Case':<16s} {'Blocks':<12s} {'Heads':>5s} "
+              f"{'Wall ms':>9s} {'Device ms':>10s}")
+        for r in rows:
+            blocks = r["blocks"] if isinstance(r["blocks"], str) \
+                else "{} x {}".format(*r["blocks"])
+            print(f"{r['case']:<16s} {blocks:<12s} {r['heads'] or '-':>5} "
+                  f"{r.get('wall_ms', '-'):>9} "
+                  f"{r.get('device_ms') or '-':>10}"
+                  + (" <- the shape's" if r["chosen"] else "")
+                  + ("  " + r["error"][-120:] if "error" in r else ""))
+        if rows and rows[0]["interpret"]:
+            print("timed in the Pallas INTERPRETER (no TPU here): not a "
+                  "hardware speed claim")
+        return
 
     if args.kernels:
         fams = [f for f in args.families.split(",") if f] or None

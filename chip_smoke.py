@@ -50,6 +50,7 @@ REAL = {
     # a handful of requests of 1-3 rows each
     "serve": {"rows": (1, 3, 2, 1, 3)},
     "kernels": {"attn": (2, 12, 1024, 64),        # (B, H, S, D)
+                "attn_whole": (32, 12, 384, 64),  # BERT's bucket: one block
                 "decode": (8, 12, 2048, 64),      # (B, H, S_max, D)
                 "opt": (512, 512, 3, 3),          # ResNet-50's largest conv
                 "gemm": (512, 1024, 1024)},       # (M, N, K)
@@ -364,18 +365,49 @@ def _backward_cases(attn, seed=4):
     return out
 
 
-def _close_gradient(got, want, dt):
-    """``_close`` at what ``flash_attention_bwd`` registers for the chip:
-    the largest error over the largest |gradient|, 1e-4 in f32 (a sum over
-    a thousand keys of Mosaic's float32 matmul passes read 2.8e-5, my chip
-    run, PR 27; an elementwise atol does not scale with the sum) and 2e-2
-    in bf16 (read 3.3e-3 to 4.6e-3)."""
+def _whole_block_cases(attn, seed=5):
+    """The attention forward at BERT's bucket (a head's whole sequence
+    one block, no mask), in f32 and bf16, held like the backward to the
+    float32 dense softmax of the same rounded inputs at the highest
+    precision: ``((label, family, arrays, kwargs, dtype), dense)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels.flash import flash_attention_reference
+
+    r = np.random.default_rng(seed)
+    b, h, s, d = attn
+    out = []
+    for dt in (jnp.float32, jnp.bfloat16):
+        q, k, v = (jnp.asarray(r.standard_normal((b, h, s, d),
+                                                 dtype=np.float32)).astype(dt)
+                   for _ in range(3))
+        with jax.default_matmul_precision("highest"):
+            dense = flash_attention_reference(
+                *(x.astype(jnp.float32) for x in (q, k, v)), d ** -0.5,
+                False)
+        out.append(((f"flash_attention/{jnp.dtype(dt).name}/s{s}_whole",
+                     "flash_attention", (q, k, v),
+                     {"scale": d ** -0.5, "causal": False}, dt), dense))
+    return out
+
+
+def _close_to_dense(got, want, dt, family):
+    """``_close`` at what the attention families register for the chip
+    against the float32 dense softmax: the largest error over the largest
+    |entry|. The backward: 1e-4 in f32 (a sum over a thousand keys of
+    Mosaic's float32 matmul passes read 2.8e-5, my chip run, PR 27; an
+    elementwise atol does not scale with the sum) and 2e-2 in bf16 (read
+    3.3e-3 to 4.6e-3). The forward: 1e-4 in f32 and 1e-2 in bf16 (``p``
+    rounded to bf16 before ``p @ v`` and the output to bf16: read 2.4e-3
+    to 2.9e-3 in the interpreter, PR 29)."""
     import jax.numpy as jnp
 
     got = np.asarray(got).astype(np.float32)
     want = np.asarray(want).astype(np.float32)
     err = float(np.abs(got - want).max())
-    tol = 1e-4 if dt == jnp.float32 else 2e-2
+    tol = 1e-4 if dt == jnp.float32 \
+        else 2e-2 if family == "flash_attention_bwd" else 1e-2
     return err, err <= tol * float(np.abs(want).max()), tol
 
 
@@ -396,12 +428,13 @@ def _close(got, want, dt):
     return err, bool(np.allclose(got, want, rtol=tol, atol=tol)), tol
 
 
-def phase_kernels(*, attn, decode, opt, gemm, interpret):
+def phase_kernels(*, attn, attn_whole, decode, opt, gemm, interpret):
     """Every registered family through ``kernels.dispatch`` FORCED onto
     its kernel (``interpret=False``: compiled by Mosaic; True only for the
     CPU test) and compared with the family's XLA baseline (the attention
-    backward with the float32 dense gradient of the same inputs, which is
-    what its XLA side computes in the inputs' dtype). Both sides are
+    backward, and the forward at BERT's bucket ``attn_whole``, with the
+    float32 dense gradient / softmax of the same inputs, which is what
+    their XLA sides compute in the inputs' dtype). Both sides are
     traced at the highest matmul precision: the registered tolerances are
     statements about the algorithm, and at the TPU's default (bf16 passes
     for an f32 matmul) kernel and baseline each sit ~1e-2 from the truth
@@ -414,10 +447,10 @@ def phase_kernels(*, attn, decode, opt, gemm, interpret):
 
     clock = _Clock()
     cases = _kernel_cases(attn, decode, opt, gemm)
-    dense = {}      # label -> the dense gradient a backward is held to
-    for case, gradient in _backward_cases(attn):
+    dense = {}      # label -> the float32 dense result a case is held to
+    for case, want in _backward_cases(attn) + _whole_block_cases(attn_whole):
         cases.append(case)
-        dense[case[0]] = gradient
+        dense[case[0]] = want
     missing = set(kernels.families()) - {c[1] for c in cases}
     if missing:
         raise AssertionError(f"no smoke case for families {missing}")
@@ -444,9 +477,9 @@ def phase_kernels(*, attn, decode, opt, gemm, interpret):
         fns.append((kfn, arrays))
         got_l = got if isinstance(got, tuple) else (got,)
         want_l = want if isinstance(want, tuple) else (want,)
-        close = _close_gradient if label in dense else _close
-        errs, oks, tols = zip(*(close(a, b, dt)
-                                for a, b in zip(got_l, want_l)))
+        errs, oks, tols = zip(*(
+            _close_to_dense(a, b, dt, family) if label in dense
+            else _close(a, b, dt) for a, b in zip(got_l, want_l)))
         results[label] = {"ok": all(oks), "max_abs_err": max(errs),
                           "tolerance": tols[0]}
         if not all(oks):

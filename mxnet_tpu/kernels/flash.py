@@ -5,22 +5,28 @@ Migrated from ``ops/pallas_ops.py`` (PR 8); that module is now the
 op-registration shim calling :func:`mxnet_tpu.kernels.dispatch`.
 Forward runs the Pallas kernel (VMEM-blocked, MXU matmuls per tile, the
 (S, S) score matrix never materializes in HBM) and also writes each
-query row's log-sum-exp. The backward is a family of its own,
-``flash_attention_bwd``: one fused Pallas call (two, dK/dV then dQ, where
-a head's dQ does not fit VMEM) that recomputes the probabilities tile by
-tile from ``(q, k, v, out, lse, d_out)``, only over the block pairs at or
-under the diagonal when causal, with MXU operands in the inputs' dtype
-and float32 accumulation; its XLA side is the gradient of the dense
-reference. Training memory stays O(S*block) end to end wherever the
-kernels run. Both families pick their blocks from the shape, here and
-nowhere else (:func:`default_blocks`, :func:`backward_blocks`); a caller
-names a pair only to force a tile.
+query row's log-sum-exp. A head whose sequence fits one tile (up to 1024
+positions a side) is one plain softmax, several such heads to a program;
+a longer one carries the online softmax from k block to k block. The
+backward is a family of its own, ``flash_attention_bwd``: one fused
+Pallas call (two, dK/dV then dQ, where a head's dQ does not fit VMEM)
+that recomputes the probabilities tile by tile from ``(q, k, v, out, lse,
+d_out)``; its XLA side is the gradient of the dense reference. Forward and
+backward alike feed the MXU operands in the inputs' dtype and keep scores,
+statistics and sums float32, and when causal neither compute nor fetch a
+block pair above the diagonal. Training memory stays O(S*block) end to
+end wherever the kernels run. Both families pick their blocks from the
+shape, here and nowhere else (:func:`default_blocks`,
+:func:`backward_blocks`); a caller names a pair only to force a tile.
 
 Tolerance vs the XLA baseline (dense softmax reference): f32 inputs
 agree to rtol=2e-5/atol=2e-5 — the kernel accumulates in f32 exactly
 like the reference but reassociates the softmax normalizer across k
-blocks, so parity is close-but-not-bitwise (tests/test_pallas.py and
-tests/test_kernels.py assert these bounds).
+blocks, so parity is close-but-not-bitwise. bf16 inputs: ``p`` is rounded
+to bf16 before ``p @ v`` (as the reference rounds it), the output within
+1e-2 of the largest |out| of the float32 dense softmax of the same
+rounded inputs, the log-sum-exp at 2e-5 (tests/test_pallas.py,
+tests/test_kernels.py and tests/test_text_model.py assert these bounds).
 """
 from __future__ import annotations
 
@@ -54,61 +60,125 @@ def row_log_sum_exp(q, k, scale, causal):
     return jax.nn.logsumexp(s, axis=-1)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                  acc_ref, *, scale, causal, block_q, block_k, n_kb):
-    """One (batch*head, q-block, k-block) program. The TPU grid iterates
-    its LAST dimension sequentially, so the online-softmax state (m, l,
-    acc) carries across k blocks in VMEM scratch — only (block, d) tiles
-    ever live in VMEM, whatever the sequence length (the FlashAttention
-    recurrence). The last k block also writes the rows' log-sum-exp
-    ``m + log l`` as one lane-major row of ``block_q`` values, which is
-    all the backward needs to recompute a probability."""
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims):
+    """An MXU product with a float32 sum. Operands narrower than float32
+    are multiplied as they are: a precision asked of float32 matmuls
+    (``jax.default_matmul_precision``) means nothing for them, and Mosaic
+    refuses a bf16 product at ``highest``."""
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=None if a.dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT)
+
+
+def _causal_keep(qi, ki, block_q, block_k, q_axis):
+    """The mask of one tile, queries along ``q_axis``: a query sees the
+    keys at or before its own position."""
+    shape = (block_q, block_k) if q_axis == 0 else (block_k, block_q)
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    1 - q_axis)
+    return q_pos >= k_pos
+
+
+def _on_causal_tiles(tile, causal, qi, ki, block_q, block_k):
+    """Run ``tile(masked)`` for this (q block, k block) pair: not at all
+    where the whole pair lies above the diagonal, with the mask only where
+    the diagonal crosses it."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        tile(False)
+        return
+    below = qi * block_q >= (ki + 1) * block_k - 1
+    reached = (qi + 1) * block_q > ki * block_k
+    pl.when(below)(lambda: tile(False))
+    pl.when(jnp.logical_and(reached, jnp.logical_not(below)))(
+        lambda: tile(True))
+
+
+def _causal_maps(causal, block_q, block_k):
+    """``(q_of, k_of)``, the block a grid step ``(q block i, k block j)``
+    names: when causal, the first q block that reaches k block j and the
+    last k block that q block i reaches, so that a step above the
+    diagonal keeps the block index of the nearest pair that is not, and an
+    unchanged index moves nothing."""
+    if not causal:
+        return (lambda i, j: i), (lambda i, j: j)
+    return (lambda i, j: jnp.maximum(i, (j * block_k) // block_q),
+            lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k))
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry, scale, causal,
+                  block_q, block_k, n_kb):
+    """One (heads, q-block, k-block) program over ``q_ref.shape[0]`` heads
+    of the ``batch*head`` axis. Both matmuls take their operands in the
+    inputs' dtype (``_dot``); scores, running max, normaliser, ``exp`` and
+    the sum are float32, and ``p`` is rounded to the values' dtype before
+    ``p @ v``. A sequence that is one k block (``n_kb == 1``) is a plain
+    softmax and carries nothing. A longer one is the FlashAttention
+    recurrence: the TPU grid iterates its LAST dimension sequentially, so
+    the online-softmax state ``carry`` = (m, l, acc) passes from k block
+    to k block in VMEM scratch and only (block, d) tiles ever live there,
+    whatever the sequence length. When causal, a tile above the diagonal
+    is not computed (nor fetched: the caller clamps its index maps) and
+    only a tile the diagonal crosses is masked. The last k block writes
+    the output and the rows' log-sum-exp ``m + log l``, one lane-major row
+    of ``block_q`` values a head, which is all the backward needs to
+    recompute a probability."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    heads = range(q_ref.shape[0])
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def finish(g, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[g] = (acc / l).astype(o_ref.dtype)
+        lse_ref[g, 0] = _column_to_row(m + jnp.log(l))
 
-    def compute():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-        k_blk = k_ref[0].astype(jnp.float32)  # (block_k, d)
-        v_blk = v_ref[0].astype(jnp.float32)  # (block_k, dv)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    if carry:
+        m_ref, l_ref, acc_ref = carry
 
-    if causal:
-        # blocks entirely above the diagonal contribute nothing
-        @pl.when(ki * block_k < (qi + 1) * block_q)
-        def _():
-            compute()
-    else:
-        compute()
+        @pl.when(ki == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ki == n_kb - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = _column_to_row(m_ref[...] + jnp.log(l))
+    def tile(masked):
+        keep = _causal_keep(qi, ki, block_q, block_k, 0) if masked else None
+        for g in heads:
+            v = v_ref[g]
+            s = _dot(q_ref[g], k_ref[g], _NT) * scale
+            if masked:
+                s = jnp.where(keep, s, -jnp.inf)
+            m_new = s.max(axis=-1, keepdims=True)
+            if not carry:
+                p = jnp.exp(s - m_new)
+                finish(g, m_new, p.sum(axis=-1, keepdims=True),
+                       _dot(p.astype(v.dtype), v, _NN))
+                continue
+            m = m_ref[g]
+            m_new = jnp.maximum(m, m_new)
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[g] = m_new
+            l_ref[g] = l_ref[g] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + _dot(p.astype(v.dtype), v, _NN)
+
+    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k)
+
+    if carry:
+        @pl.when(ki == n_kb - 1)
+        def _finish():
+            for g in heads:
+                finish(g, m_ref[g], l_ref[g], acc_ref[g])
 
 
 def _column_to_row(col):
@@ -137,47 +207,50 @@ def flash_forward(q, k, v, scale, causal, block_q, block_k,
 
 
 def flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
-                      interpret=False):
+                      interpret=False, heads=None):
     """``(out, lse)``: the attention output ``(b, h, sq, dv)`` and the
     float32 log-sum-exp of each query row's scaled, masked scores
     ``(b, h, sq)``, both results of one ``pallas_call`` (the output
-    first: a trace names a call after its first result)."""
+    first: a trace names a call after its first result). ``heads`` of the
+    ``b * h`` axis go to one program: :func:`heads_a_program`'s, unless
+    the caller forces a number (the sweep)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     bh = b * h
+    heads = heads or heads_a_program(bh, sq, sk, block_q, block_k)
     q3 = q.reshape(bh, sq, d)
     k3 = k.reshape(bh, sk, d)
     v3 = v.reshape(bh, sk, dv)
     n_kb = sk // block_k
-    grid = (bh, sq // block_q, n_kb)
-    kernel = _functools.partial(_flash_kernel, scale=scale, causal=causal,
-                                block_q=block_q, block_k=block_k,
-                                n_kb=n_kb)
+    _, k_of = _causal_maps(causal, block_q, block_k)
+    f32 = jnp.float32
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        _functools.partial(_flash_kernel, scale=scale, causal=causal,
+                           block_q=block_q, block_k=block_k, n_kb=n_kb),
+        grid=(bh // heads, sq // block_q, n_kb),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((heads, block_q, d), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((heads, block_k, d),
+                         lambda i, j, kk: (i, k_of(j, kk), 0)),
+            pl.BlockSpec((heads, block_k, dv),
+                         lambda i, j, kk: (i, k_of(j, kk), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, 1, 1, block_q), lambda i, j, kk: (i, j, 0, 0)),
+            pl.BlockSpec((heads, block_q, dv), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((heads, 1, 1, block_q),
+                         lambda i, j, kk: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq // block_q, 1, block_q),
-                                 jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq // block_q, 1, block_q), f32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
+        # the online softmax's state, where there is a next k block
+        scratch_shapes=[pltpu.VMEM((heads, block_q, 1), f32),
+                        pltpu.VMEM((heads, block_q, 1), f32),
+                        pltpu.VMEM((heads, block_q, dv), f32)] * (n_kb > 1),
         interpret=interpret,
     )(q3, k3, v3)
     return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
@@ -185,25 +258,11 @@ def flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
 
 # ---- the backward as kernels -----------------------------------------
 
-_NN = (((1,), (0,)), ((), ()))  # a @ b
-_NT = (((1,), (1,)), ((), ()))  # a @ b.T
-_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 # A head's dQ may stay in VMEM while its k blocks pass (the fused call) up
 # to this many bytes of float32 sum and double-buffered result (8 a bf16
 # value): 4,096 positions x 192 take 6 MiB beside ~8 MiB of tiles at 512 x
 # 512, inside the 16 MiB of scoped VMEM.
 _FUSED_DQ_BYTES = 8 * 2 ** 20
-
-
-def _dot(a, b, dims):
-    """An MXU product with a float32 sum. Operands narrower than float32
-    are multiplied as they are: a precision asked of float32 matmuls
-    (``jax.default_matmul_precision``) means nothing for them, and Mosaic
-    refuses a bf16 product at ``highest``."""
-    return jax.lax.dot_general(
-        a, b, dims, preferred_element_type=jnp.float32,
-        precision=None if a.dtype == jnp.float32
-        else jax.lax.Precision.DEFAULT)
 
 
 def _tile_p_ds(x, y, dx, dy, lse, dvec, scale, keep):
@@ -219,32 +278,6 @@ def _tile_p_ds(x, y, dx, dy, lse, dvec, scale, keep):
         s = jnp.where(keep, s, -jnp.inf)
     p = jnp.exp(s - lse)
     return p, p * (_dot(dx, dy, _NT) - dvec)
-
-
-def _causal_keep(qi, ki, block_q, block_k, q_axis):
-    """The mask of one tile, queries along ``q_axis``: a query sees the
-    keys at or before its own position."""
-    shape = (block_q, block_k) if q_axis == 0 else (block_k, block_q)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
-                                                    1 - q_axis)
-    return q_pos >= k_pos
-
-
-def _on_causal_tiles(tile, causal, qi, ki, block_q, block_k):
-    """Run ``tile(masked)`` for this (q block, k block) pair: not at all
-    where the whole pair lies above the diagonal, with the mask only where
-    the diagonal crosses it."""
-    from jax.experimental import pallas as pl
-
-    if not causal:
-        tile(False)
-        return
-    below = qi * block_q >= (ki + 1) * block_k - 1
-    reached = (qi + 1) * block_q > ki * block_k
-    pl.when(below)(lambda: tile(False))
-    pl.when(jnp.logical_and(reached, jnp.logical_not(below)))(
-        lambda: tile(True))
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
@@ -369,20 +402,7 @@ def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
     static = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, n_kb=n_kb)
 
-    if causal:
-        # the first q block that reaches k block j / the last k block
-        # that q block i reaches
-        def q_of(i, j):
-            return jnp.maximum(i, (j * block_k) // block_q)
-
-        def k_of(i, j):
-            return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
-    else:
-        def q_of(i, j):
-            return i
-
-        def k_of(i, j):
-            return j
+    q_of, k_of = _causal_maps(causal, block_q, block_k)
 
     def specs(q_at, k_at):
         return [
@@ -463,25 +483,89 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # Queries and keys share one head width ``d``; values (and so the output)
 # may have their own, ``dv`` (latent attention: 192 | 128).
 
-def default_blocks(sq, sk, d, dv):
-    """``(block_q, block_k)`` of the forward kernel, from the shape alone.
-    Equal widths keep the 128 x 128 they always had (the only size
-    measured at d64). With a value width of its own, up to 256 wide: the
-    largest power of two up to 1024 that divides the length. Measured on
-    a v5e at 2 x 32 heads x 4096, 192 | 128, causal, forward alone: 34.3
-    ms at 128 x 128, 13.4 at 256, 7.2 at 512, 5.6 at 512 x 1024, 4.9 at
-    1024; 2048 x 1024 does not fit VMEM (PERF.md, PR 26). The backward
-    kernels take their own (``backward_blocks``)."""
-    if d == dv or max(d, dv) > 256:
-        return 128, 128
-    # s & -s: the largest power of two that divides s
-    return (max(128, min(sq & -sq, 1024)), max(128, min(sk & -sk, 1024)))
+# The longest side of a forward tile, and what a Mosaic call may take of
+# the 16 MiB of scoped VMEM a v5e gives it, by ``_forward_vmem_bytes``.
+_BLOCK_CAP = 1024
+_VMEM_BUDGET = 15 * 2 ** 20
+
+
+def _forward_vmem_bytes(block_q, block_k, d, dv, itemsize):
+    """VMEM the forward call takes at a tile, fitted to what Mosaic
+    allocated at the shapes compiled for a v5e (PR 29; never under the
+    least limit a compile passed with, at most 2 MiB over): the q, out, k
+    and v tiles double-buffered, a row padded to whole lanes; 4.25 bytes
+    a score (one float32 tile live, not two); the float32 sum and five
+    (block_q, 1) columns of statistics, a column padded to 128 lanes; and
+    6 bytes an element of q where float32 operands are split into bf16
+    parts for the MXU."""
+    def lanes(width):
+        return -(-width // 128) * 128
+
+    tiles = 2 * itemsize * (block_q + block_k) * (lanes(d) + lanes(dv))
+    scores = 17 * block_q * block_k // 4
+    state = 4 * block_q * (lanes(dv) + 5 * 128)
+    split = 6 * block_q * lanes(d) * (itemsize == 4)
+    return tiles + scores + state + split
+
+
+def default_blocks(sq, sk, d, dv, itemsize=2):
+    """``(block_q, block_k)`` of the forward kernel, from the shape alone
+    (``itemsize``: bytes of an operand's element; bf16 unless said). A
+    side of up to 1024 positions is ONE block (BERT's 384 x 384: K and V
+    read once, no online-softmax state carried from program to program);
+    a longer one takes the largest power of two up to 1024 that divides
+    it; while the tile does not fit VMEM (``_forward_vmem_bytes``: wide
+    heads, float32 operands) the longer side steps down, to 128 at the
+    least. A side no multiple of 128 divides gets 128, which
+    ``_supports`` refuses. The backward kernels take their own
+    (``backward_blocks``).
+
+    Measured on a v5e, bf16, the forward alone in a jit, ms of the Mosaic
+    call in a device trace (my chip run, PR 29; ``benchmark/opperf.py
+    --flash-sweep``; in brackets PR 28's kernel, float32 operands, every
+    executed tile masked, at the same tile). 32 x 12 heads x 384, d64, no
+    mask: 128 x 128 1.85 [1.86], 384 x 128 1.49, 128 x 384 0.64, 384 x 384
+    0.51 [0.72], and with 2 / **4** / 8 heads a program 0.41 / **0.36** /
+    0.36 (``heads_a_program``); dense XLA 0.57. 2 x 32 heads x 4096,
+    192 | 128, causal: 512 x 512 5.65 [6.31], 512 x 1024 4.31, 1024 x 512
+    6.33, **1024 x 1024 3.81** [4.05], 1024 x 2048 4.53, 2048 x 1024 out
+    of VMEM; dense XLA 9.56. The same with keys and values 128 wide (no
+    cell has it): 128 x 128 23.8 [27.8], 512 x 512 4.72, 512 x 1024 3.25,
+    **1024 x 1024 2.94** [3.12], 1024 x 2048 3.55; dense XLA 9.42."""
+    def sizes(s):  # largest first
+        if s % 128:
+            return [128]
+        top = min(s & -s, _BLOCK_CAP)
+        whole = [s] if top < s <= _BLOCK_CAP else []
+        return whole + [top >> i for i in range(top.bit_length() - 7)]
+
+    qs, ks = sizes(sq), sizes(sk)
+    while (len(qs) + len(ks) > 2 and _forward_vmem_bytes(
+            qs[0], ks[0], d, dv, itemsize) > _VMEM_BUDGET):
+        (ks if len(qs) == 1 or (len(ks) > 1 and ks[0] >= qs[0])
+         else qs).pop(0)
+    return qs[0], ks[0]
+
+
+def heads_a_program(bh, sq, sk, block_q, block_k):
+    """How many heads of the ``batch*head`` axis one program of the
+    forward takes, from the shape alone: one, unless a head is a single
+    tile too small to hide a grid step's fixed cost; then the largest
+    power of two up to 4 that divides ``bh`` and keeps the program at the
+    1024 x 1024 scores the longest tile has (BERT's 384 x 384: four)."""
+    heads = 1
+    while ((block_q, block_k) == (sq, sk) and heads < 4
+           and bh % (2 * heads) == 0
+           and 2 * heads * sq * sk <= _BLOCK_CAP ** 2):
+        heads *= 2
+    return heads
 
 
 def _blocks(q, k, v, block_q=None, block_k=None):
     """The forward's blocks: the shape's, unless the caller forced a
     tile."""
-    bq, bk = default_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3])
+    bq, bk = default_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3],
+                            jnp.dtype(q.dtype).itemsize)
     return int(block_q or bq), int(block_k or bk)
 
 
@@ -606,7 +690,11 @@ def _register():
         "flash_attention", kernel=_kernel, xla=_xla, bucket=_bucket,
         supports=_supports, default_tpu=True,
         tolerance="f32 rtol=2e-5 atol=2e-5 vs dense softmax (softmax "
-                  "normalizer reassociated across k blocks)")
+                  "normalizer reassociated across k blocks); bf16 operands "
+                  "(p rounded to bf16 before p @ v; scores, statistics and "
+                  "sums float32): within 1e-2 of the largest |out| of the "
+                  "float32 dense softmax of the same rounded inputs, the "
+                  "rows' log-sum-exp at 2e-5")
     register_kernel(
         "flash_attention_bwd", kernel=_bwd_kernel, xla=_bwd_xla,
         bucket=_bwd_bucket, supports=_bwd_supports, default_tpu=True,

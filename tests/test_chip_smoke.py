@@ -16,7 +16,8 @@ TOY = {
     "train": {"model": "resnet18_v1", "batch": 4, "image": 32,
               "classes": 10, "steps": 2, "dtype": "bfloat16"},
     "serve": {"rows": (1, 3, 2)},
-    "kernels": {"attn": (1, 2, 256, 64), "decode": (2, 2, 256, 64),
+    "kernels": {"attn": (1, 2, 256, 64), "attn_whole": (1, 2, 384, 64),
+                "decode": (2, 2, 256, 64),
                 "opt": (40, 130), "gemm": (64, 128, 256)},
     "encoder": {"units": 64, "heads": 2, "hidden": 128, "seq": 128,
                 "batch": 2, "dtype": "bfloat16"},
@@ -79,6 +80,12 @@ def test_kernel_phase_covers_every_family_in_interpret_mode():
     assert {k.split("/")[0] for k in rep["families"]} \
         == set(kernels.families())
     assert all(r["ok"] for r in rep["families"].values())
+    # BERT's bucket (one block a head) in both dtypes, bf16 at the bound
+    # the forward registers against the float32 dense softmax
+    assert rep["families"]["flash_attention/bfloat16/s384_whole"][
+        "tolerance"] == 1e-2
+    assert rep["families"]["flash_attention/float32/s384_whole"][
+        "tolerance"] == 1e-4
     # forced onto the kernel, never the XLA baseline
     assert all(n >= 1 for n in rep["dispatched_kernel"].values())
 

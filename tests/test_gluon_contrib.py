@@ -309,7 +309,7 @@ def test_multi_head_attention_names_no_block():
     assert attn(mx.nd.random.uniform(-1, 1, (2, 256, 32))).shape \
         == (2, 256, 32)
     assert list(kernels.dispatch_stats()["flash_attention"]["buckets"]) \
-        == ["bh8_sq256_sk256_d8_float32_c0_q128k128"]
+        == ["bh8_sq256_sk256_d8_float32_c0_q256k256"]
     kernels.reset_stats()
     q = mx.nd.random.uniform(-1, 1, (1, 2, 128, 8))
     mx.nd.contrib.flash_attention(q, q, q, block_q=64, block_k=32,

@@ -94,21 +94,21 @@ _LM = ((2, 32, 4096, 192), 128, "bfloat16", True)
     # the four buckets the benchmark's attention cells print (PERF.md
     # section 3): nobody names a block, the family reads the shape
     (_BERT, "flash_attention", {},
-     "bh512_sq512_sk512_d64_bfloat16_c0_q128k128"),
+     "bh512_sq512_sk512_d64_bfloat16_c0_q384k384"),
     (_BERT, "flash_attention_bwd", {},
      "bh512_sq512_sk512_d64_bfloat16_c0_q384k384"),
     (_LM, "flash_attention", {},
      "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q1024k1024"),
     (_LM, "flash_attention_bwd", {},
      "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k512"),
-    # equal widths stay at 128 x 128 however long the sequence
+    # equal widths take the shape's blocks like any other
     (((1, 8, 2048, 128), 128, "float32", True), "flash_attention", {},
-     "bh8_sq2048_sk2048_d128_float32_c1_q128k128"),
+     "bh8_sq2048_sk2048_d128_float32_c1_q1024k1024"),
     # a pair the caller names forces the tile and lands in the key
     (_LM, "flash_attention", {"block_q": 512, "block_k": 256},
      "bh64_sq4096_sk4096_d192v128_bfloat16_c1_q512k256"),
-    (_BERT, "flash_attention", {"block_k": 384},
-     "bh512_sq512_sk512_d64_bfloat16_c0_q128k384"),
+    (_BERT, "flash_attention", {"block_k": 128},
+     "bh512_sq512_sk512_d64_bfloat16_c0_q384k128"),
 ], ids=["bert_fwd", "bert_bwd", "lm_fwd", "lm_bwd", "equal_widths_2048",
         "forced_pair", "forced_one"])
 def test_flash_blocks_come_from_the_shape(shape, family, blocks, key):
@@ -459,6 +459,31 @@ def test_opperf_kernels_writes_table(kernel_cache_dir):
     key = "twobit_compress|" + \
         kernels.entry("twobit_compress").bucket(g, r0, 0.5)
     assert choice == t["entries"][key]["winner"]
+
+
+def test_opperf_flash_sweep_marks_the_tile_the_shape_picks():
+    """``opperf.py --flash-sweep``: one row a tile with its blocks and
+    heads a program, the shape's own marked, a row for dense XLA last;
+    off the TPU the interpreter's clock and no device time."""
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import opperf
+
+    # the real sweep holds both attention cells' buckets at the tile
+    # the kernel picks for them
+    from mxnet_tpu.kernels import flash
+    for label, (b, h, s, d, dv), _causal, tiles in opperf._FLASH_SWEEP:
+        bq, bk = flash.default_blocks(s, s, d, dv)
+        assert (bq, bk, flash.heads_a_program(b * h, s, s, bq, bk)) \
+            in tiles, label
+    rows = opperf.sweep_flash_forward(
+        runs=1, warmup=1, dtype="float32",
+        cases=[("toy", (1, 4, 256, 32, 16), True,
+                [(128, 128, 1), (256, 256, 1), (256, 256, 4)])])
+    assert [r["blocks"] for r in rows] \
+        == [[128, 128], [256, 256], [256, 256], "dense XLA"]
+    assert [r["chosen"] for r in rows] == [False, False, True, False]
+    assert all(r["wall_ms"] > 0 and r["device_ms"] is None
+               and r["interpret"] for r in rows)
 
 
 def test_opperf_kernels_has_a_row_for_the_attention_backward(

@@ -240,25 +240,34 @@ def test_every_token_on_one_held_expert_is_not_dropped(model):
 
 # ------------------------------------- (d) the kernel with two widths -----
 
+# the shape's own blocks (the whole 256, BERT's whole 384) and a forced
+# 128 x 128, whose forward carries the online softmax over k blocks and,
+# when causal, skips the block above the diagonal; the backward reads the
+# forward's ``out`` and ``lse`` in all three
 @pytest.mark.parametrize("d,dv", [(32, 16), (192, 128), (64, 64)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_kernel_forward_and_backward(d, dv, causal):
+@pytest.mark.parametrize("s,blocks", [(256, {}), (384, {}),
+                                      (256, {"block_q": 128, "block_k": 128})],
+                         ids=["s256_whole", "s384_whole", "s256_q128k128"])
+def test_flash_kernel_forward_and_backward(d, dv, causal, s, blocks):
     rng = np.random.RandomState(d + dv)
-    q = jnp.asarray(rng.randn(1, 2, 256, d), jnp.float32)
-    k = jnp.asarray(rng.randn(1, 2, 256, d), jnp.float32)
-    v = jnp.asarray(rng.randn(1, 2, 256, dv), jnp.float32)
-    cot = jnp.asarray(rng.randn(1, 2, 256, dv), jnp.float32)
+    q = jnp.asarray(rng.randn(1, 2, s, d), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 2, s, d), jnp.float32)
+    v = jnp.asarray(rng.randn(1, 2, s, dv), jnp.float32)
+    cot = jnp.asarray(rng.randn(1, 2, s, dv), jnp.float32)
     scale = d ** -0.5
+    assert flash._blocks(q, k, v, **blocks) == (
+        blocks.get("block_q", s), blocks.get("block_k", s))
 
     def run(fn):
         out, vjp = jax.vjp(lambda a, b, c: fn(a, b, c), q, k, v)
         return (out,) + vjp(cot)
 
     got = run(lambda a, b, c: flash._kernel(a, b, c, scale, causal=causal,
-                                            interpret=True))
+                                            interpret=True, **blocks))
     want = run(lambda a, b, c: flash.flash_attention_reference(
         a, b, c, scale, causal))
-    assert got[0].shape == (1, 2, 256, dv)
+    assert got[0].shape == (1, 2, s, dv)
     for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-4, atol=2e-5, err_msg=name)
@@ -271,6 +280,123 @@ def _attention_case(d, dv, causal, sq=256, sk=256, dtype=jnp.float32):
     v = jnp.asarray(rng.randn(1, 2, sk, dv), dtype)
     cot = jnp.asarray(rng.randn(1, 2, sq, dv), dtype)
     return q, k, v, cot, d ** -0.5
+
+
+# the forward alone: BERT's bucket (384 x 384 whole, d64), the same
+# causal, and sequences of several blocks: square and both oblongs with
+# block pairs above the diagonal (their index maps clamped), and more
+# keys than queries with no mask
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("d,dv,sq,sk,causal,blocks", [
+    (64, 64, 384, 384, False, (384, 384)),
+    (64, 64, 384, 384, True, (384, 384)),
+    (32, 16, 512, 512, True, (128, 128)),
+    (32, 16, 512, 512, True, (128, 256)),
+    (32, 16, 512, 512, True, (256, 128)),
+    (192, 128, 256, 512, False, (128, 256)),
+])
+@pytest.mark.parametrize("heads", [None, 1],
+                         ids=["heads_from_shape", "one_head"])
+def test_flash_forward_in_the_input_dtype(dtype, d, dv, sq, sk, causal,
+                                          blocks, heads):
+    """``out`` against the dense reference and ``lse`` against
+    ``row_log_sum_exp``, both float32 on the SAME (rounded) inputs:
+    float32 at the 2e-5 the family registers; bf16 (operands of both
+    matmuls bf16, ``p`` rounded to bf16 before ``p @ v``, everything else
+    float32) within 1e-2 of the largest |out|, ``lse`` at 2e-5 still: a
+    bf16 product is exact in the float32 sum."""
+    tolerance = " ".join(kernels.entry("flash_attention").tolerance.split())
+    assert "f32 rtol=2e-5 atol=2e-5" in tolerance
+    assert "within 1e-2 of the largest |out|" in tolerance
+    q, k, v, _, scale = _attention_case(d, dv, causal, sq=sq, sk=sk,
+                                        dtype=dtype)
+    # both heads of a whole-sequence tile go to one program, unless told
+    per_program = heads or (2 if blocks == (sq, sk) else 1)
+    assert flash.heads_a_program(2, sq, sk, *blocks) == \
+        (2 if blocks == (sq, sk) else 1)
+    out, lse = flash.flash_forward_lse(q, k, v, scale, causal, *blocks,
+                                       interpret=True, heads=heads)
+    assert out.dtype == dtype and out.shape == (1, 2, sq, dv)
+    assert lse.dtype == jnp.float32 and lse.shape == (1, 2, sq)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = np.asarray(flash.flash_attention_reference(*f32, scale, causal))
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(flash.row_log_sum_exp(
+            f32[0], f32[1], scale, causal)), rtol=2e-5, atol=2e-5)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5,
+                                   atol=2e-5)
+    else:
+        err = np.abs(np.asarray(out, np.float32) - want).max()
+        assert err <= 1e-2 * np.abs(want).max(), err
+    # both matmuls of every traced tile take the inputs' dtype and sum
+    # in float32: one tile a head of the program, or a masked and an
+    # unmasked one when causal
+    dots = _dot_generals(jax.make_jaxpr(
+        lambda *a: flash.flash_forward_lse(
+            *a, scale, causal, *blocks, interpret=True,
+            heads=heads))(q, k, v).jaxpr)
+    assert len(dots) == (4 if causal else 2) * per_program
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [dtype] * 2
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("shape,heads", [
+    # (batch*heads, sq, sk, block_q, block_k): BERT's bucket and the
+    # benchmark's toy shape take four heads a program
+    ((384, 384, 384, 384, 384), 4),
+    ((8, 128, 128, 128, 128), 4),
+    # as many as divide batch*heads, and as keep a program at 1024 x 1024
+    # scores
+    ((6, 384, 384, 384, 384), 2),
+    ((7, 384, 384, 384, 384), 1),
+    ((64, 640, 640, 640, 640), 2),
+    ((64, 1024, 1024, 1024, 1024), 1),
+    # a head of several tiles is its own program
+    ((64, 4096, 4096, 1024, 1024), 1),
+    ((384, 384, 384, 384, 128), 1),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_heads_a_program_follow_the_shape(shape, heads):
+    assert flash.heads_a_program(*shape) == heads
+
+
+def test_flash_forward_takes_several_heads_a_program_with_a_carry():
+    """Forced: two heads a program over several k blocks, each head with
+    its own online-softmax state."""
+    q, k, v, _, scale = _attention_case(32, 16, True, sq=512, sk=512)
+    one = flash.flash_forward_lse(q, k, v, scale, True, 256, 128,
+                                  interpret=True)
+    two = flash.flash_forward_lse(q, k, v, scale, True, 256, 128,
+                                  interpret=True, heads=2)
+    for a, b in zip(one, two):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(
+        np.asarray(two[0]), np.asarray(flash.flash_attention_reference(
+            q, k, v, scale, True)), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256),
+                                             (256, 128), (384, 128)])
+def test_causal_maps_name_no_block_above_the_diagonal(block_q, block_k):
+    """A grid step above the diagonal names the block of the nearest step
+    that is not (so its fetch moves nothing); a step at or under the
+    diagonal names its own."""
+    q_of, k_of = flash._causal_maps(True, block_q, block_k)
+    s = 3 * 256 * 2
+    for i in range(s // block_q):
+        for j in range(s // block_k):
+            reached = (i + 1) * block_q > j * block_k
+            if reached:
+                assert (int(q_of(i, j)), int(k_of(i, j))) == (i, j)
+                continue
+            # the last k block q block i reaches / the first q block
+            # that reaches k block j
+            assert int(k_of(i, j)) == ((i + 1) * block_q - 1) // block_k < j
+            assert int(q_of(i, j)) == (j * block_k) // block_q > i
+    q_of, k_of = flash._causal_maps(False, block_q, block_k)
+    assert (q_of(0, 3), k_of(0, 3)) == (0, 3)
 
 
 def _dense_gradient(q, k, v, cot, scale, causal):
@@ -447,9 +573,9 @@ def test_flash_bucket_and_supports_know_both_widths():
                 jnp.zeros((2, 4, sk, d), jnp.bfloat16),
                 jnp.zeros((2, 4, sk, dv), jnp.bfloat16))
 
-    # equal widths keep the key they had before values had their own
+    # equal widths keep the key's form from before values had their own
     assert flash._bucket(*arrays(64, 64), 0.125) \
-        == "bh8_sq256_sk256_d64_bfloat16_c0_q128k128"
+        == "bh8_sq256_sk256_d64_bfloat16_c0_q256k256"
     assert flash._bucket(*arrays(192, 128), 0.1, causal=True) \
         == "bh8_sq256_sk256_d192v128_bfloat16_c1_q256k256"
     assert flash._supports(*arrays(192, 128), 0.1)
@@ -466,17 +592,31 @@ def test_flash_bucket_and_supports_know_both_widths():
             {"kernel": 1, "xla": 0}}
 
 
-def test_default_blocks_follow_the_shape():
-    # equal widths keep what they had; BERT's bucket stays q128k128
-    assert flash.default_blocks(384, 384, 64, 64) == (128, 128)
-    assert flash.default_blocks(4096, 4096, 128, 128) == (128, 128)
-    # a value width of its own: the largest power of two up to 1024 that
-    # divides the length, never under 128 (then ``_supports`` decides)
-    assert flash.default_blocks(4096, 4096, 192, 128) == (1024, 1024)
-    assert flash.default_blocks(8192, 2048, 192, 128) == (1024, 1024)
-    assert flash.default_blocks(1536, 384, 192, 128) == (512, 128)
-    assert flash.default_blocks(100, 100, 192, 128) == (128, 128)
-    assert flash.default_blocks(4096, 4096, 512, 256) == (128, 128)
+@pytest.mark.parametrize("shape,blocks", [
+    # a side of up to 1024 positions is one block, whatever the widths:
+    # BERT's bucket, the benchmark's toy shape, a length no power of two
+    ((384, 384, 64, 64), (384, 384)),
+    ((128, 128, 32, 16), (128, 128)),
+    ((640, 1024, 64, 64), (640, 1024)),
+    # a longer one: the largest power of two up to 1024 that divides it,
+    # with a value width of its own (the language model's bucket) or not
+    ((4096, 4096, 192, 128), (1024, 1024)),
+    ((4096, 4096, 128, 128), (1024, 1024)),
+    ((8192, 2048, 192, 128), (1024, 1024)),
+    ((1536, 384, 192, 128), (512, 384)),
+    ((1152, 4096, 64, 64), (128, 1024)),
+    # no multiple of 128 divides it: 128, and ``_supports`` decides
+    ((100, 100, 192, 128), (128, 128)),
+    # heads too wide for the tile to fit VMEM: the longer side steps down
+    ((4096, 4096, 512, 512), (1024, 512)),
+    ((4096, 4096, 512, 256, 4), (512, 512)),
+    # float32 operands at the language model's widths still fit
+    ((4096, 4096, 192, 128, 4), (1024, 1024)),
+], ids=lambda v: "x".join(map(str, v)))
+def test_default_blocks_follow_the_shape(shape, blocks):
+    assert flash.default_blocks(*shape) == blocks
+    assert flash._forward_vmem_bytes(
+        *blocks, *shape[2:4], *(shape[4:] or (2,))) <= flash._VMEM_BUDGET
 
 
 @pytest.mark.parametrize("hybridize", [False, True],

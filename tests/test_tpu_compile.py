@@ -74,14 +74,15 @@ def test_flash_192_128_compiles_forward_and_backward(one_chip, quiet_cache,
 
 @pytest.mark.parametrize("shape,causal,forward_blocks", [
     ((2, 32, 4096, 192, 128), True, (1024, 1024)),   # the language model
-    ((32, 12, 384, 64, 64), False, (128, 128)),      # BERT-base at 384
+    ((32, 12, 384, 64, 64), False, (384, 384)),      # BERT-base at 384
 ], ids=["bh64_s4096_d192v128_c1", "bh384_s384_d64_c0"])
 def test_flash_backward_kernels_compile_at_the_cells_buckets(
         one_chip, quiet_cache, shape, causal, forward_blocks):
-    """The forward with its second result and the fused backward call,
-    at the blocks ``backward_blocks`` picks, for both buckets the
-    benchmark runs: two Mosaic calls, inside the scoped VMEM; and as the
-    two calls a longer sequence takes: three."""
+    """The forward with its second result, at the blocks (and heads a
+    program) the shape picks, and the fused backward call, at the blocks
+    ``backward_blocks`` picks, for both buckets the benchmark runs: two
+    Mosaic calls, inside the scoped VMEM; and as the two calls a longer
+    sequence takes: three."""
     import jax
     import jax.numpy as jnp
 
@@ -92,6 +93,9 @@ def test_flash_backward_kernels_compile_at_the_cells_buckets(
     v = _shape((b, h, s, dv), jnp.bfloat16, one_chip)
     blocks = flash.backward_blocks(s, s, d, dv)
     assert blocks == ((512, 512) if s == 4096 else (s, s))
+    assert flash.default_blocks(s, s, d, dv) == forward_blocks
+    assert flash.heads_a_program(b * h, s, s, *forward_blocks) \
+        == (1 if s == 4096 else 4)
 
     def fwd_bwd(q_, k_, v_, cot):
         out, lse = flash.flash_forward_lse(q_, k_, v_, d ** -0.5, causal,
@@ -116,6 +120,39 @@ def test_flash_backward_kernels_compile_at_the_cells_buckets(
     assert lse.shape == (b, h, s) and lse.dtype == jnp.float32
     assert (dq.shape, dk.shape, dv_.shape) == (q.shape, q.shape, v.shape)
     assert dq.dtype == dk.dtype == dv_.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("sq,sk,d,dv", [
+    (4096, 4096, 192, 128),   # float32 at the language model's widths
+    (4096, 4096, 512, 512),   # the widest heads ``_supports`` lets in
+    (1024, 4096, 512, 256),
+    (896, 896, 512, 512),     # a whole sequence no power of two
+    (1024, 1024, 64, 64),     # the longest whole sequence
+    (512, 512, 64, 64),       # four heads a program of 512 x 512
+])
+def test_flash_forward_compiles_at_the_blocks_the_shape_picks(
+        one_chip, quiet_cache, dtype, sq, sk, d, dv):
+    """``default_blocks`` bounds a tile by ``_forward_vmem_bytes``, a fit
+    to what Mosaic allocates: where the fit says a tile is inside the
+    scoped VMEM, the compiler has to agree, masked or not."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import flash
+
+    dt = jnp.dtype(dtype)
+    blocks = flash.default_blocks(sq, sk, d, dv, dt.itemsize)
+    assert flash._forward_vmem_bytes(*blocks, d, dv, dt.itemsize) \
+        <= flash._VMEM_BUDGET
+    q = _shape((1, 4, sq, d), dt, one_chip)
+    k = _shape((1, 4, sk, d), dt, one_chip)
+    v = _shape((1, 4, sk, dv), dt, one_chip)
+    for causal in (False, True):
+        text = jax.jit(lambda q_, k_, v_: flash._kernel(
+            q_, k_, v_, d ** -0.5, causal=causal)).lower(
+                q, k, v).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
 
 
 def test_routed_experts_compile_to_grouped_kernels(one_chip, quiet_cache):
