@@ -388,6 +388,70 @@ def sweep_flash_forward(runs=10, warmup=3, cases=None, dtype="bfloat16"):
     return rows
 
 
+# The windowed, grouped calls of the hybrid decoder's cell (20 query and 10
+# key heads a softmax, 64 | 128 wide, window 512 of 4,096 positions) at the
+# tiles ``flash._inside_band`` could pick, forward and fused backward; the
+# last tile is the shape's own without a window, run with the window.
+_FLASH_WINDOW_SWEEP = [
+    ("phi4_flash_w512", (1, 20, 10, 4096, 64, 128), 512,
+     [(128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
+      (1024, 1024)]),
+]
+
+
+def sweep_flash_window(runs=10, warmup=3, cases=None, dtype="bfloat16"):
+    """Time the causal flash forward and the fused backward with a
+    ``window`` and grouped keys, each alone in a jit, at every tile of
+    ``cases`` (``_FLASH_WINDOW_SWEEP``): ``device_ms`` is the Mosaic
+    call's own time in a device trace. ``chosen`` marks the tile the
+    forward / the backward picks (``flash._inside_band``)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import kernels as klayer
+    from mxnet_tpu.kernels import flash
+
+    interp = not klayer.on_tpu()
+    r = np.random.default_rng(0)
+    rows = []
+    for label, (b, h, hk, s, d, dv), window, tiles in \
+            cases or _FLASH_WINDOW_SWEEP:
+        q, k, v, cot = (jnp.asarray(r.standard_normal(
+            (b, heads, s, w), dtype=np.float32), dtype)
+            for heads, w in ((h, d), (hk, d), (hk, dv), (h, dv)))
+        scale = d ** -0.5
+        out, lse = flash.flash_forward_lse(
+            q, k, v, scale, True, *flash._blocks(q, k, v, window=window),
+            interpret=interp, window=window)
+        picks = {"forward": flash._blocks(q, k, v, window=window),
+                 "backward": flash._blocks_for(q, k, v, window=window)}
+        for bq, bk in tiles:
+            sides = {
+                "forward": (jax.jit(lambda *a, _t=(bq, bk):
+                                    flash.flash_forward_lse(
+                                        *a, scale, True, *_t,
+                                        interpret=interp, window=window)),
+                            (q, k, v)),
+                "backward": (jax.jit(lambda *a, _t=(bq, bk):
+                                     flash.flash_backward_kernel(
+                                         *a, scale, True, *_t,
+                                         interpret=interp, window=window)),
+                             (q, k, v, out, lse, cot))}
+            for side, (fn, args) in sides.items():
+                row = {"case": label, "shape": [b, h, hk, s, d, dv],
+                       "window": window, "dtype": dtype, "side": side,
+                       "blocks": [bq, bk], "interpret": interp,
+                       "chosen": (bq, bk) == picks[side]}
+                try:
+                    row["wall_ms"] = round(_time_jitted(fn, args, runs,
+                                                        warmup), 4)
+                    row["device_ms"] = _device_ms(fn, args, runs,
+                                                  "tpu_custom_call")
+                except Exception as e:  # the compiler's refusal is the row
+                    row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+                rows.append(row)
+    return rows
+
+
 def run_benchmark(ops, size=_DEFAULT_SIZE, runs=10, warmup=2):
     results = []
     for name in ops:
@@ -421,8 +485,10 @@ def main():
     parser.add_argument("--flash-sweep", type=str, default="",
                         metavar="DIR",
                         help="time the flash forward at every tile of "
-                             "_FLASH_SWEEP, print the table and keep it as "
-                             "DIR/flash_forward_sweep.json")
+                             "_FLASH_SWEEP and the windowed forward and "
+                             "backward at those of _FLASH_WINDOW_SWEEP, "
+                             "print the tables and keep them as "
+                             "DIR/flash_{forward,window}_sweep.json")
     parser.add_argument("--chain", type=int, default=16,
                         help="op-chain length for --dispatch")
     parser.add_argument("--bulk", type=int, default=16,
@@ -435,6 +501,10 @@ def main():
         with open(os.path.join(args.flash_sweep,
                                "flash_forward_sweep.json"), "w") as f:
             json.dump(rows, f, indent=1)
+        window_rows = sweep_flash_window(runs=args.runs, warmup=args.warmup)
+        with open(os.path.join(args.flash_sweep,
+                               "flash_window_sweep.json"), "w") as f:
+            json.dump(window_rows, f, indent=1)
         print(f"{'Case':<16s} {'Blocks':<12s} {'Heads':>5s} "
               f"{'Wall ms':>9s} {'Device ms':>10s}")
         for r in rows:
@@ -444,6 +514,12 @@ def main():
                   f"{r.get('wall_ms', '-'):>9} "
                   f"{r.get('device_ms') or '-':>10}"
                   + (" <- the shape's" if r["chosen"] else "")
+                  + ("  " + r["error"][-120:] if "error" in r else ""))
+        for r in window_rows:
+            print(f"{r['case']:<16s} {'{} x {}'.format(*r['blocks']):<12s} "
+                  f"{r['side'][:5]:>5} {r.get('wall_ms', '-'):>9} "
+                  f"{r.get('device_ms') or '-':>10}"
+                  + (" <- the window's" if r["chosen"] else "")
                   + ("  " + r["error"][-120:] if "error" in r else ""))
         if rows and rows[0]["interpret"]:
             print("timed in the Pallas INTERPRETER (no TPU here): not a "

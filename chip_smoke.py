@@ -53,7 +53,8 @@ REAL = {
                 "attn_whole": (32, 12, 384, 64),  # BERT's bucket: one block
                 "decode": (8, 12, 2048, 64),      # (B, H, S_max, D)
                 "opt": (512, 512, 3, 3),          # ResNet-50's largest conv
-                "gemm": (512, 1024, 1024)},       # (M, N, K)
+                "gemm": (512, 1024, 1024),        # (M, N, K)
+                "scan": (1, 1000, 1024, 16)},     # (B, S, channels, states)
     "encoder": {"units": 768, "heads": 12, "hidden": 3072, "seq": 512,
                 "batch": 8, "dtype": "bfloat16"},
 }
@@ -392,6 +393,64 @@ def _whole_block_cases(attn, seed=5):
     return out
 
 
+def _chunk_states(args, chunk):
+    """The float32 state every ``chunk`` positions of the step-by-step
+    recurrence, (batch, chunks, states, channels): what the scan's forward
+    kernel saves for its backward."""
+    import jax
+    import jax.numpy as jnp
+
+    x, step, a, bm, cm, _ = args
+
+    def one(h, inp):
+        x_t, s_t, b_t = inp
+        return (jnp.exp(s_t[..., None] * a) * h
+                + (s_t * x_t)[..., None] * b_t[:, None, :]), h
+
+    h0 = jnp.zeros((x.shape[0], x.shape[2], a.shape[1]), jnp.float32)
+    _, before = jax.lax.scan(one, h0, tuple(
+        t.swapaxes(0, 1) for t in (x, step, bm)))
+    return before[::chunk].transpose(1, 0, 3, 2)
+
+
+def _scan_cases(scan, seed=6):
+    """The selective scan, forward and backward, in f32 and bf16, held to
+    the step-by-step ``lax.scan`` in float32 (and its gradient) of the same
+    rounded inputs: ``((label, family, arrays, kwargs, dtype), want)``.
+    The length is no multiple of the kernel's chunk; the backward is
+    handed the states the forward kernel saved at chunk boundaries, as
+    its ``custom_vjp`` hands them."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import selective_scan as ss
+
+    r = np.random.default_rng(seed)
+    b, s, dch, n = scan
+    f32 = jnp.float32
+
+    def normal(*shape):
+        return jnp.asarray(r.standard_normal(shape, dtype=np.float32))
+
+    out = []
+    for dt in (f32, jnp.bfloat16):
+        x, bm, cm, cot = (t.astype(dt) for t in (
+            normal(b, s, dch), normal(b, s, n), normal(b, s, n),
+            normal(b, s, dch)))
+        step = jax.nn.softplus(normal(b, s, dch) - 2.0)
+        a = -jnp.exp(normal(dch, n) * 0.5)
+        args = (x, step, a, bm, cm, normal(dch))
+        up = tuple(t.astype(f32) for t in args)
+        want, vjp = jax.vjp(ss.selective_scan_reference, *up)
+        states = _chunk_states(up, ss.CHUNK)
+        name = jnp.dtype(dt).name
+        out.append(((f"selective_scan/{name}", "selective_scan", args, {},
+                     dt), want))
+        out.append(((f"selective_scan_bwd/{name}", "selective_scan_bwd",
+                     args + (states, cot), {}, dt), vjp(cot.astype(f32))))
+    return out
+
+
 def _close_to_dense(got, want, dt, family):
     """``_close`` at what the attention families register for the chip
     against the float32 dense softmax: the largest error over the largest
@@ -428,13 +487,14 @@ def _close(got, want, dt):
     return err, bool(np.allclose(got, want, rtol=tol, atol=tol)), tol
 
 
-def phase_kernels(*, attn, attn_whole, decode, opt, gemm, interpret):
+def phase_kernels(*, attn, attn_whole, decode, opt, gemm, scan, interpret):
     """Every registered family through ``kernels.dispatch`` FORCED onto
     its kernel (``interpret=False``: compiled by Mosaic; True only for the
     CPU test) and compared with the family's XLA baseline (the attention
     backward, and the forward at BERT's bucket ``attn_whole``, with the
     float32 dense gradient / softmax of the same inputs, which is what
-    their XLA sides compute in the inputs' dtype). Both sides are
+    their XLA sides compute in the inputs' dtype; the selective scan and
+    its backward with the step-by-step recurrence in float32). Both sides are
     traced at the highest matmul precision: the registered tolerances are
     statements about the algorithm, and at the TPU's default (bf16 passes
     for an f32 matmul) kernel and baseline each sit ~1e-2 from the truth
@@ -448,7 +508,8 @@ def phase_kernels(*, attn, attn_whole, decode, opt, gemm, interpret):
     clock = _Clock()
     cases = _kernel_cases(attn, decode, opt, gemm)
     dense = {}      # label -> the float32 dense result a case is held to
-    for case, want in _backward_cases(attn) + _whole_block_cases(attn_whole):
+    for case, want in (_backward_cases(attn) + _whole_block_cases(attn_whole)
+                       + _scan_cases(scan)):
         cases.append(case)
         dense[case[0]] = want
     missing = set(kernels.families()) - {c[1] for c in cases}

@@ -1,13 +1,16 @@
 """Blocks of decoder language models (beyond the reference, whose newest
 text model is a post-LN encoder): RMS norm, the gated SiLU feed-forward,
-multi-head latent attention and the sparse expert layer. The ops under
-them are in ``ops/text_ops.py``; ``gluon.model_zoo.text`` builds models of
-them.
+multi-head latent attention, the sparse expert layer, and the mixers of a
+hybrid decoder: a Mamba-1 state-space layer, differential attention
+(windowed, full, or reading another layer's keys and values) and the
+gated memory unit. The ops under them are in ``ops/text_ops.py``;
+``gluon.model_zoo.text`` builds models of them.
 
 The ``jax.named_scope`` names here (``mla.project``, ``mla.attention``,
 ``moe.shared``; ``moe.route`` and ``moe.experts`` inside
-``parallel.moe.routed_experts``) are what a join of the device trace with
-the HLO will group by.
+``parallel.moe.routed_experts``; ``ssm.project``, ``ssm.conv``,
+``ssm.scan``, ``gmu``, ``attn.window``, ``attn.full``, ``attn.cross``) are
+what a join of the device trace with the HLO will group by.
 """
 from __future__ import annotations
 
@@ -18,7 +21,8 @@ from ...cached_op import update_state
 from ..block import HybridBlock
 from .basic_layers import Dense
 
-__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "SparseMoE"]
+__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "SparseMoE", "LayerNormF32",
+           "MambaMixer", "DiffAttention", "GatedMemoryUnit"]
 
 
 def _scope(name):
@@ -148,7 +152,21 @@ class MLAttention(HybridBlock):
                 f"rope={self._rope_kwargs})")
 
 
-class SparseMoE(HybridBlock):
+class _KeepsFloat32(HybridBlock):
+    """A block some of whose own parameters (``_FLOAT32``) stay float32
+    under ``cast``."""
+
+    _FLOAT32 = ()
+
+    def cast(self, dtype):
+        self._clear_cached_op()
+        for child in self._children.values():
+            child.cast(dtype)
+        for name, p in self._reg_params.items():
+            p.cast(_np.float32 if name in self._FLOAT32 else dtype)
+
+
+class SparseMoE(_KeepsFloat32):
     """Sparse expert layer, DeepSeek-V3 style: a sigmoid router over
     ``num_experts`` with a selection bias (``e_score_correction_bias``, a
     buffer outside the gradient), ``top_k`` experts a token, their weights
@@ -208,16 +226,9 @@ class SparseMoE(HybridBlock):
             self.shared = GatedMLP(units, num_shared * hidden_size) \
                 if num_shared else None
 
+    # the selection bias and the counters stay float32 (the router scores
+    # are float32; a bfloat16 counter stops counting at 256)
     _FLOAT32 = ("router_bias", "load_pairs", "load_peak", "load_calls")
-
-    def cast(self, dtype):
-        """The selection bias and the counters stay float32 (the router
-        scores are float32; a bfloat16 counter stops counting at 256)."""
-        self._clear_cached_op()
-        for child in self._children.values():
-            child.cast(dtype)
-        for name, p in self._reg_params.items():
-            p.cast(_np.float32 if name in self._FLOAT32 else dtype)
 
     def hybrid_forward(self, F, x, router_weight=None, router_bias=None,
                        gate_weight=None, up_weight=None, down_weight=None,
@@ -248,3 +259,181 @@ class SparseMoE(HybridBlock):
         return (f"SparseMoE(experts={self._num_experts}, "
                 f"held={self._held}, {self._route_kwargs}, "
                 f"shared={self.shared!r})")
+
+
+class LayerNormF32(HybridBlock):
+    """Layer norm over the last axis with gain and bias, the statistics in
+    float32 whatever the input's type (``nn.LayerNorm`` takes them in the
+    input's)."""
+
+    def __init__(self, in_channels, epsilon=1e-5, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init="ones")
+            self.beta = self.params.get("beta", shape=(in_channels,),
+                                        init="zeros")
+
+    def hybrid_forward(self, F, x, gamma=None, beta=None):
+        return F.invoke("_contrib_layer_norm", x, gamma, beta,
+                        eps=self._epsilon)
+
+    def __repr__(self):
+        return f"LayerNormF32({self.gamma.shape[0]}, eps={self._epsilon})"
+
+
+class MambaMixer(_KeepsFloat32):
+    """The Mamba-1 mixer (arXiv:2312.00752) over (B, S, units), returning
+    ``(out, scanned)``:
+
+    ``[x; z] = W_in u`` (2 x ``expand * units``); ``x = silu(conv(x))``, a
+    causal depthwise convolution of ``d_conv`` taps with bias; ``[d; B; C]
+    = W_x x`` (``dt_rank`` | ``d_state`` | ``d_state``); the selective scan
+    with step ``softplus(W_dt d + b_dt)``, decay ``-exp(A_log)`` and skip
+    ``D`` (op ``_contrib_selective_scan``: kernel family
+    ``selective_scan``; step, decay and state float32); ``out = W_out
+    (scanned * silu(z))``. ``scanned`` (B, S, ``expand * units``) is the
+    scan's output BEFORE the gate: what a decoder-hybrid-decoder hands on
+    as its memory. ``A_log`` and ``D`` stay float32 under ``cast``."""
+
+    _FLOAT32 = ("a_log", "d_skip")
+
+    def __init__(self, units, d_state=16, d_conv=4, expand=2, dt_rank=None,
+                 interpret=False, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        inner = expand * units
+        self._inner, self._state = inner, d_state
+        self._rank = dt_rank or -(-units // 16)
+        self._interpret = interpret   # the kernel in the interpreter (CPU)
+        with self.name_scope():
+            self.in_proj = Dense(2 * inner, use_bias=False, flatten=False,
+                                 in_units=units)
+            self.conv_weight = self.params.get("conv_weight",
+                                               shape=(inner, d_conv))
+            self.conv_bias = self.params.get("conv_bias", shape=(inner,),
+                                             init="zeros")
+            self.x_proj = Dense(self._rank + 2 * d_state, use_bias=False,
+                                flatten=False, in_units=inner)
+            self.dt_weight = self.params.get("dt_weight",
+                                             shape=(inner, self._rank))
+            self.dt_bias = self.params.get("dt_bias", shape=(inner,),
+                                           init="zeros")
+            self.a_log = self.params.get("a_log", shape=(inner, d_state),
+                                         init="zeros")
+            self.d_skip = self.params.get("d_skip", shape=(inner,),
+                                          init="ones")
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=inner)
+
+    def hybrid_forward(self, F, u, conv_weight=None, conv_bias=None,
+                       dt_weight=None, dt_bias=None, a_log=None,
+                       d_skip=None):
+        inner, state, rank = self._inner, self._state, self._rank
+
+        def part(t, begin, end):
+            return F.slice_axis(t, axis=-1, begin=begin, end=end)
+
+        with _scope("ssm.project"):
+            xz = self.in_proj(u)
+            x, z = part(xz, 0, inner), part(xz, inner, None)
+        with _scope("ssm.conv"):
+            x = F.invoke("_contrib_causal_conv1d", x, conv_weight, conv_bias,
+                         activation="silu")
+        with _scope("ssm.project"):
+            dbc = self.x_proj(x)
+            # the step's bias goes in with the softplus, in float32
+            dt = F.invoke("FullyConnected", part(dbc, 0, rank), dt_weight,
+                          num_hidden=inner, no_bias=True, flatten=False)
+        with _scope("ssm.scan"):
+            scanned = F.invoke(
+                "_contrib_selective_scan", x, dt, a_log,
+                part(dbc, rank, rank + state), part(dbc, rank + state, None),
+                d_skip, dt_bias, interpret=self._interpret)
+        with _scope("ssm.project"):
+            return self.out_proj(
+                F.invoke("_contrib_gated_silu", z, scanned)), scanned
+
+    def __repr__(self):
+        return (f"MambaMixer(inner={self._inner}, state={self._state}, "
+                f"dt_rank={self._rank})")
+
+
+class DiffAttention(_KeepsFloat32):
+    """Causal differential attention with grouped keys (op
+    ``_contrib_diff_attention``) over (B, S, units), returning ``(out, k,
+    v)``: ``q = W_q u + b_q``, ``[k; v] = W_kv u + b_kv`` (``num_kv_heads``
+    heads each), ``out = W_o attention + b_o``. ``window`` is the number
+    of keys a position sees (None: all before it). ``cross=True`` builds
+    no key/value projection: the call takes another layer's projected ``k``
+    and ``v`` and hands them on. ``lam_init`` is the layer's (0.8 - 0.6
+    exp(-0.3 depth) in the paper); the four ``lam_*`` vectors and the
+    sub-norm's gain stay float32 under ``cast``."""
+
+    _FLOAT32 = ("lam_q1", "lam_k1", "lam_q2", "lam_k2", "subln_gamma")
+
+    def __init__(self, units, num_heads, num_kv_heads, lam_init,
+                 window=None, cross=False, epsilon=1e-5, interpret=False,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        if num_heads % 2 or num_kv_heads % 2 or \
+                num_heads % num_kv_heads or units % num_heads:
+            raise ValueError(
+                f"differential attention pairs its heads: {num_heads} "
+                f"query and {num_kv_heads} key heads over {units} units")
+        d = units // num_heads
+        self._span = "attn.cross" if cross else \
+            "attn.full" if window is None else "attn.window"
+        self._kwargs = {
+            "num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads),
+            "lam_init": float(lam_init), "eps": float(epsilon),
+            "interpret": bool(interpret),
+            **({} if window is None else {"window": int(window)})}
+        self._kv_units = num_kv_heads * d
+        with self.name_scope():
+            self.q_proj = Dense(units, use_bias=True, flatten=False,
+                                in_units=units)
+            self.kv_proj = None if cross else Dense(
+                2 * self._kv_units, use_bias=True, flatten=False,
+                in_units=units)
+            self.o_proj = Dense(units, use_bias=True, flatten=False,
+                                in_units=units)
+            for name in self._FLOAT32[:4]:
+                setattr(self, name, self.params.get(name, shape=(d,),
+                                                    init="zeros"))
+            self.subln_gamma = self.params.get(
+                "subln_gamma", shape=(2 * d,), init="ones")
+
+    def hybrid_forward(self, F, u, k=None, v=None, lam_q1=None, lam_k1=None,
+                       lam_q2=None, lam_k2=None, subln_gamma=None):
+        with _scope(self._span):
+            if self.kv_proj is not None:
+                kv = self.kv_proj(u)
+                k = F.slice_axis(kv, axis=-1, begin=0, end=self._kv_units)
+                v = F.slice_axis(kv, axis=-1, begin=self._kv_units, end=None)
+            out = F.invoke("_contrib_diff_attention", self.q_proj(u), k, v,
+                           lam_q1, lam_k1, lam_q2, lam_k2, subln_gamma,
+                           **self._kwargs)
+            return self.o_proj(out), k, v
+
+    def __repr__(self):
+        return f"DiffAttention({self._span}, {self._kwargs})"
+
+
+class GatedMemoryUnit(HybridBlock):
+    """``W_2 (memory * silu(W_1 u))`` (arXiv:2507.06607): ``u`` (B, S,
+    units) gates, element by element, a memory (B, S, ``memory_units``)
+    that an earlier layer made."""
+
+    def __init__(self, units, memory_units, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.in_proj = Dense(memory_units, use_bias=False, flatten=False,
+                                 in_units=units)
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=memory_units)
+
+    def hybrid_forward(self, F, u, memory):
+        with _scope("gmu"):
+            return self.out_proj(F.invoke("_contrib_gated_silu",
+                                          self.in_proj(u), memory))

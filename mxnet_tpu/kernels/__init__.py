@@ -41,6 +41,12 @@ decode_attention   single-query flash against a padded KV cache (the
                    continuous-batching decode prerequisite)
 twobit_compress /  2-bit gradient quantization with error feedback and
 twobit_decompress  its rescale (kvstore gradient compression)
+selective_scan     the recurrence of a Mamba-1 state-space layer, walked
+                   in chunks with the float32 state resident in VMEM; its
+                   XLA side is the same chunking as nested ``lax.scan``s
+selective_scan_    its backward, decided inside the forward's
+bwd                ``custom_vjp``: resumes from the states saved at chunk
+                   boundaries and recomputes inside a chunk
 =================  ====================================================
 
 Fallbacks LATCH: Pallas-unavailable is probed once per process and
@@ -304,3 +310,4 @@ from . import flash  # noqa: E402,F401  (flash_attention, flash_attention_bwd)
 from . import int8_gemm  # noqa: E402,F401  (int8_gemm)
 from . import decode_attention  # noqa: E402,F401  (decode_attention)
 from . import twobit  # noqa: E402,F401  (twobit_compress/_decompress)
+from . import selective_scan  # noqa: E402,F401  (selective_scan, _bwd)

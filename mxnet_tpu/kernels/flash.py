@@ -40,23 +40,42 @@ __all__ = ["flash_attention_reference", "flash_forward",
            "default_blocks", "backward_blocks"]
 
 
-def flash_attention_reference(q, k, v, scale, causal):
+def _dense_keep(qlen, klen, window):
+    """The causal mask, and inside it the band of ``window`` keys a query
+    sees (its own position and the ``window - 1`` before it)."""
+    keep = jnp.tril(jnp.ones((qlen, klen), bool))
+    if window is not None:
+        keep &= ~jnp.tril(jnp.ones((qlen, klen), bool), -int(window))
+    return keep
+
+
+def _per_query_head(q, t):
+    """Keys or values ``t`` with fewer heads than ``q``, one copy a query
+    head (the dense oracle's way; the kernels read a shared head through
+    their index maps instead)."""
+    group = q.shape[1] // t.shape[1]
+    return t if group == 1 else jnp.repeat(t, group, axis=1)
+
+
+def flash_attention_reference(q, k, v, scale, causal, window=None):
     """Dense attention oracle (and the XLA dispatch baseline)."""
+    k, v = _per_query_head(q, k), _per_query_head(q, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
-        qlen, klen = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((qlen, klen), bool))
-        s = jnp.where(mask, s, -jnp.inf)
+        s = jnp.where(_dense_keep(s.shape[-2], s.shape[-1], window), s,
+                      -jnp.inf)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def row_log_sum_exp(q, k, scale, causal):
+def row_log_sum_exp(q, k, scale, causal, window=None):
     """The dense oracle of the forward's second result: float32
     log-sum-exp of each query row's scaled, masked scores."""
+    k = _per_query_head(q, k)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+        s = jnp.where(_dense_keep(s.shape[-2], s.shape[-1], window), s,
+                      -jnp.inf)
     return jax.nn.logsumexp(s, axis=-1)
 
 
@@ -76,20 +95,24 @@ def _dot(a, b, dims):
         else jax.lax.Precision.DEFAULT)
 
 
-def _causal_keep(qi, ki, block_q, block_k, q_axis):
+def _causal_keep(qi, ki, block_q, block_k, q_axis, window=None):
     """The mask of one tile, queries along ``q_axis``: a query sees the
-    keys at or before its own position."""
+    keys at or before its own position, and with a ``window`` only the
+    last ``window`` of them."""
     shape = (block_q, block_k) if q_axis == 0 else (block_k, block_q)
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
                                                     1 - q_axis)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
 
 
-def _on_causal_tiles(tile, causal, qi, ki, block_q, block_k):
+def _on_causal_tiles(tile, causal, qi, ki, block_q, block_k, window=None):
     """Run ``tile(masked)`` for this (q block, k block) pair: not at all
-    where the whole pair lies above the diagonal, with the mask only where
-    the diagonal crosses it."""
+    where the whole pair lies above the diagonal (or, with a ``window``,
+    wholly before the band), with the mask only where the diagonal (or
+    the band's far edge) crosses it."""
     from jax.experimental import pallas as pl
 
     if not causal:
@@ -97,25 +120,42 @@ def _on_causal_tiles(tile, causal, qi, ki, block_q, block_k):
         return
     below = qi * block_q >= (ki + 1) * block_k - 1
     reached = (qi + 1) * block_q > ki * block_k
+    if window is not None:
+        # every pair of the tile inside the band / some pair inside it
+        below = jnp.logical_and(
+            below, (qi + 1) * block_q - 1 - ki * block_k < window)
+        reached = jnp.logical_and(
+            reached, qi * block_q - ((ki + 1) * block_k - 1) < window)
     pl.when(below)(lambda: tile(False))
     pl.when(jnp.logical_and(reached, jnp.logical_not(below)))(
         lambda: tile(True))
 
 
-def _causal_maps(causal, block_q, block_k):
+def _causal_maps(causal, block_q, block_k, window=None, n_qb=None):
     """``(q_of, k_of)``, the block a grid step ``(q block i, k block j)``
     names: when causal, the first q block that reaches k block j and the
     last k block that q block i reaches, so that a step above the
     diagonal keeps the block index of the nearest pair that is not, and an
-    unchanged index moves nothing."""
+    unchanged index moves nothing. A ``window`` clamps the other side the
+    same way: to the last of the ``n_qb`` q blocks that still sees k block
+    j, and the first k block inside q block i's band."""
     if not causal:
         return (lambda i, j: i), (lambda i, j: j)
-    return (lambda i, j: jnp.maximum(i, (j * block_k) // block_q),
-            lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k))
+    if window is None:
+        return (lambda i, j: jnp.maximum(i, (j * block_k) // block_q),
+                lambda i, j: jnp.minimum(
+                    j, ((i + 1) * block_q - 1) // block_k))
+    return (lambda i, j: jnp.clip(
+                i, (j * block_k) // block_q,
+                jnp.minimum(((j + 1) * block_k + window - 2) // block_q,
+                            n_qb - 1)),
+            lambda i, j: jnp.clip(
+                j, jnp.maximum(i * block_q - (window - 1), 0) // block_k,
+                ((i + 1) * block_q - 1) // block_k))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry, scale, causal,
-                  block_q, block_k, n_kb):
+                  block_q, block_k, n_kb, window=None):
     """One (heads, q-block, k-block) program over ``q_ref.shape[0]`` heads
     of the ``batch*head`` axis. Both matmuls take their operands in the
     inputs' dtype (``_dot``); scores, running max, normaliser, ``exp`` and
@@ -127,7 +167,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry, scale, causal,
     to k block in VMEM scratch and only (block, d) tiles ever live there,
     whatever the sequence length. When causal, a tile above the diagonal
     is not computed (nor fetched: the caller clamps its index maps) and
-    only a tile the diagonal crosses is masked. The last k block writes
+    only a tile the diagonal crosses is masked; with a ``window`` the
+    same holds at the band's far edge. The last k block writes
     the output and the rows' log-sum-exp ``m + log l``, one lane-major row
     of ``block_q`` values a head, which is all the backward needs to
     recompute a probability."""
@@ -152,7 +193,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry, scale, causal,
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile(masked):
-        keep = _causal_keep(qi, ki, block_q, block_k, 0) if masked else None
+        keep = _causal_keep(qi, ki, block_q, block_k, 0, window) \
+            if masked else None
         for g in heads:
             v = v_ref[g]
             s = _dot(q_ref[g], k_ref[g], _NT) * scale
@@ -166,13 +208,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carry, scale, causal,
                 continue
             m = m_ref[g]
             m_new = jnp.maximum(m, m_new)
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
+            shift = m_new
+            if window is not None:
+                # a row may see no key of the first tiles its block runs
+                # (the band starts inside them): its maximum is still
+                # -inf, and -inf - -inf is no number
+                shift = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            alpha = jnp.exp(m - shift)
+            p = jnp.exp(s - shift)
             m_ref[g] = m_new
             l_ref[g] = l_ref[g] * alpha + p.sum(axis=-1, keepdims=True)
             acc_ref[g] = acc_ref[g] * alpha + _dot(p.astype(v.dtype), v, _NN)
 
-    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k)
+    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k, window)
 
     if carry:
         @pl.when(ki == n_kb - 1)
@@ -201,42 +249,62 @@ def _rows(stat, block):
 
 
 def flash_forward(q, k, v, scale, causal, block_q, block_k,
-                  interpret=False):
+                  interpret=False, window=None):
     return flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
-                             interpret)[0]
+                             interpret, window=window)[0]
+
+
+def _group(q, k):
+    """Query heads a key (and value) head: 1, or the grouped keys'."""
+    return q.shape[1] // k.shape[1]
+
+
+def _kv_row(group):
+    """The row of the ``batch * key head`` axis that row ``i`` of the
+    ``batch * query head`` axis reads: with ``h = hk * group`` heads,
+    ``b * h + head`` -> ``b * hk + head // group`` is ``i // group``."""
+    return (lambda i: i) if group == 1 else (lambda i: i // group)
 
 
 def flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
-                      interpret=False, heads=None):
+                      interpret=False, heads=None, window=None):
     """``(out, lse)``: the attention output ``(b, h, sq, dv)`` and the
     float32 log-sum-exp of each query row's scaled, masked scores
     ``(b, h, sq)``, both results of one ``pallas_call`` (the output
     first: a trace names a call after its first result). ``heads`` of the
     ``b * h`` axis go to one program: :func:`heads_a_program`'s, unless
-    the caller forces a number (the sweep)."""
+    the caller forces a number (the sweep). Keys and values may have
+    fewer heads than the queries (grouped keys): a program then takes one
+    query head and reads its group's key head through the index map, and
+    nothing is repeated in HBM. ``window`` (causal only) is the number of
+    keys a query sees, its own position included."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    bh = b * h
-    heads = heads or heads_a_program(bh, sq, sk, block_q, block_k)
+    bh, group = b * h, _group(q, k)
+    heads = heads or (heads_a_program(bh, sq, sk, block_q, block_k)
+                      if group == 1 else 1)
     q3 = q.reshape(bh, sq, d)
-    k3 = k.reshape(bh, sk, d)
-    v3 = v.reshape(bh, sk, dv)
+    k3 = k.reshape(bh // group, sk, d)
+    v3 = v.reshape(bh // group, sk, dv)
     n_kb = sk // block_k
-    _, k_of = _causal_maps(causal, block_q, block_k)
+    _, k_of = _causal_maps(causal, block_q, block_k, window,
+                           sq // block_q)
+    kv = _kv_row(group)
     f32 = jnp.float32
     out, lse = pl.pallas_call(
         _functools.partial(_flash_kernel, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k, n_kb=n_kb),
+                           block_q=block_q, block_k=block_k, n_kb=n_kb,
+                           window=window),
         grid=(bh // heads, sq // block_q, n_kb),
         in_specs=[
             pl.BlockSpec((heads, block_q, d), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((heads, block_k, d),
-                         lambda i, j, kk: (i, k_of(j, kk), 0)),
+                         lambda i, j, kk: (kv(i), k_of(j, kk), 0)),
             pl.BlockSpec((heads, block_k, dv),
-                         lambda i, j, kk: (i, k_of(j, kk), 0)),
+                         lambda i, j, kk: (kv(i), k_of(j, kk), 0)),
         ],
         out_specs=[
             pl.BlockSpec((heads, block_q, dv), lambda i, j, kk: (i, j, 0)),
@@ -282,7 +350,7 @@ def _tile_p_ds(x, y, dx, dy, lse, dvec, scale, keep):
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                       dk_ref, dv_ref, *rest, scale, causal, block_q, block_k,
-                      n_qb, n_kb):
+                      n_qb, n_kb, window=None):
     """One (batch*head, k-block, q-block) program of dK and dV; q blocks
     are the sequential dimension, the two sums live in VMEM scratch. The
     tile is held TRANSPOSED, ``(block_k, block_q)``: the rows' statistics
@@ -315,7 +383,8 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
     def tile(masked):
         q, do, k = q_ref[0], do_ref[0], k_ref[0]
-        keep = _causal_keep(qi, ki, block_q, block_k, 1) if masked else None
+        keep = _causal_keep(qi, ki, block_q, block_k, 1, window) \
+            if masked else None
         p, ds = _tile_p_ds(k, q, v_ref[0], do, lse_ref[0, 0],
                            dvec_ref[0, 0], scale, keep)
         ds = ds.astype(q.dtype)
@@ -324,7 +393,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         if dq_acc is not None:
             dq_acc[rows, :] += _dot(ds, k, _TN)
 
-    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k)
+    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k, window)
 
     @pl.when(qi == n_qb - 1)
     def _finish():
@@ -340,7 +409,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                      dq_ref, dq_acc, *, scale, causal, block_q, block_k,
-                     n_kb):
+                     n_kb, window=None):
     """One (batch*head, q-block, k-block) program of dQ; k blocks are the
     sequential dimension. The tile is ``(block_q, block_k)`` as in the
     forward, so the rows' statistics turn into columns here."""
@@ -355,13 +424,14 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
     def tile(masked):
         k = k_ref[0]
-        keep = _causal_keep(qi, ki, block_q, block_k, 0) if masked else None
+        keep = _causal_keep(qi, ki, block_q, block_k, 0, window) \
+            if masked else None
         _, ds = _tile_p_ds(q_ref[0], k, do_ref[0], v_ref[0],
                            _row_to_column(lse_ref),
                            _row_to_column(dvec_ref), scale, keep)
         dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k)
+    _on_causal_tiles(tile, causal, qi, ki, block_q, block_k, window)
 
     @pl.when(ki == n_kb - 1)
     def _finish():
@@ -369,7 +439,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
 
 def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
-                          block_k, interpret=False, fused=None):
+                          block_k, interpret=False, fused=None, window=None):
     """``(dq, dk, dv)`` from the forward's operands, output and row
     log-sum-exp: ``D = rowsum(d_out * out)`` in plain JAX, then ONE Pallas
     call for all three where a head's dQ fits VMEM beside the tiles
@@ -381,7 +451,10 @@ def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
     the attention output's shape. When causal, a block pair above the
     diagonal is neither computed nor fetched: its grid step keeps the
     block index of the nearest pair that is, and an unchanged index moves
-    nothing.
+    nothing; a ``window`` bounds the pairs on the other side in the same
+    way. With grouped keys every query head reads its group's key head
+    through the index map and writes its own float32 dK and dV, which are
+    summed over the group afterwards: nothing is repeated on the way in.
 
     The fused call measured 26 % under the two at 4,096 positions and
     14 % at 384 (``backward_blocks`` has the sweep)."""
@@ -391,8 +464,9 @@ def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     bh, n_qb, n_kb = b * h, sq // block_q, sk // block_k
-    q3, k3 = q.reshape(bh, sq, d), k.reshape(bh, sk, d)
-    v3, do3 = v.reshape(bh, sk, dv), cot.reshape(bh, sq, dv)
+    group = _group(q, k)
+    q3, k3 = q.reshape(bh, sq, d), k.reshape(bh // group, sk, d)
+    v3, do3 = v.reshape(bh // group, sk, dv), cot.reshape(bh, sq, dv)
     f32 = jnp.float32
     dvec = (cot.astype(f32) * out.astype(f32)).sum(-1)
     lse4 = _rows(lse.reshape(bh, sq).astype(f32), block_q)
@@ -400,15 +474,18 @@ def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
     if fused is None:
         fused = sq * d * (4 + 2 * q.dtype.itemsize) <= _FUSED_DQ_BYTES
     static = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, n_kb=n_kb)
+                  block_k=block_k, n_kb=n_kb, window=window)
 
-    q_of, k_of = _causal_maps(causal, block_q, block_k)
+    q_of, k_of = _causal_maps(causal, block_q, block_k, window, n_qb)
+    kv = _kv_row(group)
 
     def specs(q_at, k_at):
         return [
             pl.BlockSpec((1, block_q, d), lambda *g: (g[0], q_at(*g), 0)),
-            pl.BlockSpec((1, block_k, d), lambda *g: (g[0], k_at(*g), 0)),
-            pl.BlockSpec((1, block_k, dv), lambda *g: (g[0], k_at(*g), 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda *g: (kv(g[0]), k_at(*g), 0)),
+            pl.BlockSpec((1, block_k, dv),
+                         lambda *g: (kv(g[0]), k_at(*g), 0)),
             pl.BlockSpec((1, block_q, dv), lambda *g: (g[0], q_at(*g), 0)),
             pl.BlockSpec((1, 1, 1, block_q),
                          lambda *g: (g[0], q_at(*g), 0, 0)),
@@ -427,8 +504,10 @@ def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
             pl.BlockSpec((1, block_k, d), lambda i, j, qq: (i, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda i, j, qq: (i, j, 0)),
         ] + [pl.BlockSpec((1, sq, d), lambda i, j, qq: (i, 0, 0))] * fused,
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, dv), v.dtype)]
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sk, d), k.dtype if group == 1 else f32),
+            jax.ShapeDtypeStruct((bh, sk, dv),
+                                 v.dtype if group == 1 else f32)]
         + [dq_shape] * fused,
         scratch_shapes=[pltpu.VMEM((block_k, d), f32),
                         pltpu.VMEM((block_k, dv), f32)]
@@ -448,23 +527,26 @@ def flash_backward_kernel(q, k, v, out, lse, cot, scale, causal, block_q,
             scratch_shapes=[pltpu.VMEM((block_q, d), f32)],
             interpret=interpret,
         )(*operands)]
+    if group > 1:
+        dk, dv_ = (t.reshape(bh // group, group, sk, -1).sum(1).astype(
+            like.dtype) for t, like in ((dk, k), (dv_, v)))
     return (dq[0].reshape(q.shape), dk.reshape(k.shape),
             dv_.reshape(v.shape))
 
 
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret, window):
     return flash_forward(q, k, v, scale, causal, block_q, block_k,
-                         interpret)
+                         interpret, window)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
     out, lse = flash_forward_lse(q, k, v, scale, causal, block_q, block_k,
-                                 interpret)
+                                 interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, cot):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, cot):
     """The backward is a dispatch of its own (``flash_attention_bwd``), at
     blocks of its own. A forward that ran in the interpreter asks for the
     same; on the chip the table, or the family's default, decides."""
@@ -472,7 +554,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, cot):
 
     del block_q, block_k  # the forward's
     return dispatch("flash_attention_bwd", *res, cot, scale, causal=causal,
-                    interpret=True if interpret else None)
+                    window=window, interpret=True if interpret else None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -561,11 +643,35 @@ def heads_a_program(bh, sq, sk, block_q, block_k):
     return heads
 
 
-def _blocks(q, k, v, block_q=None, block_k=None):
+def _inside_band(blocks, sides, window):
+    """A band of ``window`` keys crosses every tile it touches, and a tile
+    longer than the band is mostly outside it: each block steps down to
+    the largest power of two the band holds, 128 at the least (at window
+    512 and 4,096 positions 512 x 512, two tiles a q block, half of each
+    inside the band; 1024 x 1024 would run two for a quarter). A side the
+    smaller block does not divide keeps the one it has. Measured on a
+    v5e, bf16, 20 query heads over 10 key heads, 4,096 positions, 64 |
+    128 wide, window 512, device ms of the call alone in a jit, forward /
+    fused backward (my chip run, PR 30; ``opperf.py --flash-sweep``): 128
+    x 128 3.27 / 5.35, 256 x 256 1.44 / 1.72, 256 x 512 0.95 / 1.17, 512
+    x 256 1.36 / 1.20, **512 x 512 0.76 / 0.88**, 1024 x 1024 (the shape's
+    own without a window) 0.71 / 1.10. Tiles under the band's width lose
+    to the per-tile overhead; the forward's 1024 x 1024 is 0.05 ms a call
+    ahead and the backward's 0.22 behind, so one rule serves both."""
+    if window is None:
+        return blocks
+    cap = max(128, 1 << max(int(window).bit_length() - 1, 0))
+    return tuple(b if b <= cap or s % cap else cap
+                 for b, s in zip(blocks, sides))
+
+
+def _blocks(q, k, v, block_q=None, block_k=None, window=None):
     """The forward's blocks: the shape's, unless the caller forced a
     tile."""
-    bq, bk = default_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3],
-                            jnp.dtype(q.dtype).itemsize)
+    sides = (q.shape[2], k.shape[2])
+    bq, bk = _inside_band(
+        default_blocks(*sides, q.shape[3], v.shape[3],
+                       jnp.dtype(q.dtype).itemsize), sides, window)
     return int(block_q or bq), int(block_k or bk)
 
 
@@ -593,21 +699,25 @@ def backward_blocks(sq, sk, d, dv):
     return one(sq), one(sk)
 
 
-def _blocks_for(q, k, v):
-    return backward_blocks(q.shape[2], k.shape[2], q.shape[3], v.shape[3])
+def _blocks_for(q, k, v, window=None):
+    sides = (q.shape[2], k.shape[2])
+    return _inside_band(backward_blocks(*sides, q.shape[3], v.shape[3]),
+                        sides, window)
 
 
 # ---- registry wiring -------------------------------------------------
 
 def _kernel(q, k, v, scale, causal=False, block_q=None, block_k=None,
-            interpret=False):
+            interpret=False, window=None):
     return _flash(q, k, v, float(scale), bool(causal),
-                  *_blocks(q, k, v, block_q, block_k), bool(interpret))
+                  *_blocks(q, k, v, block_q, block_k, window),
+                  bool(interpret), None if window is None else int(window))
 
 
-def _xla(q, k, v, scale, causal=False, block_q=None, block_k=None):
+def _xla(q, k, v, scale, causal=False, block_q=None, block_k=None,
+         window=None):
     del block_q, block_k  # dense path has no blocking
-    return flash_attention_reference(q, k, v, scale, causal)
+    return flash_attention_reference(q, k, v, scale, causal, window)
 
 
 def _pow2(n):
@@ -617,23 +727,30 @@ def _pow2(n):
     return p
 
 
-def _bucket(q, k, v, scale, causal=False, block_q=None, block_k=None):
+def _bucket(q, k, v, scale, causal=False, block_q=None, block_k=None,
+            window=None):
     """Sequence lengths and batch*heads round UP to powers of two (one
     table row covers the whole bucket); head dims and dtype are exact —
     they change the kernel's tiling, not just its trip count. A value
     width of its own is named after the query/key width (``d192v128``);
     equal widths keep the key they always had. The blocks are the ones
-    the kernel will run with."""
+    the kernel will run with. Grouped keys and a window are named after
+    them (``..._q512k512_g2_w512``); without either the key is what it
+    always was."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    block_q, block_k = _blocks(q, k, v, block_q, block_k)
+    block_q, block_k = _blocks(q, k, v, block_q, block_k, window)
     width = f"d{d}" if dv == d else f"d{d}v{dv}"
+    group = _group(q, k)
     return (f"bh{_pow2(b * h)}_sq{_pow2(sq)}_sk{_pow2(sk)}_{width}_"
             f"{jnp.dtype(q.dtype).name}_c{int(bool(causal))}_"
-            f"q{block_q}k{block_k}")
+            f"q{block_q}k{block_k}"
+            + (f"_g{group}" if group > 1 else "")
+            + (f"_w{int(window)}" if window is not None else ""))
 
 
-def _supports(q, k, v, scale, causal=False, block_q=None, block_k=None):
+def _supports(q, k, v, scale, causal=False, block_q=None, block_k=None,
+              window=None):
     """The statically checkable Mosaic constraints, of the forward and of
     the backward it will ask for: rank-4 inputs, keys as wide as the
     queries and as many as the values, both head widths a multiple of 8
@@ -641,27 +758,33 @@ def _supports(q, k, v, scale, causal=False, block_q=None, block_k=None):
     backward's blocks the whole sequence or a multiple of the 128 lanes a
     row of statistics is tiled by. No length the default blocks divide
     fails the last; a forced pair under 128 can (64 on 576 positions),
-    and the shape then takes dense XLA forward and backward."""
+    and the shape then takes dense XLA forward and backward. Keys and
+    values have as many heads as each other, a number that divides the
+    queries'; a window is at least one key, of a causal square."""
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         return False
     sq, sk, d, dv = q.shape[2], k.shape[2], q.shape[3], v.shape[3]
-    block_q, block_k = _blocks(q, k, v, block_q, block_k)
+    block_q, block_k = _blocks(q, k, v, block_q, block_k, window)
     return (sq % block_q == 0 and sk % block_k == 0
             and k.shape[3] == d and v.shape[2] == sk
             and d % 8 == 0 and 0 < d <= 512
             and dv % 8 == 0 and 0 < dv <= 512
+            and k.shape[:2] == v.shape[:2] and k.shape[0] == q.shape[0]
+            and q.shape[1] % k.shape[1] == 0
+            and (window is None
+                 or (bool(causal) and sq == sk and int(window) >= 1))
             and all(b == s or b % 128 == 0 for b, s in zip(
-                backward_blocks(sq, sk, d, dv), (sq, sk))))
+                _blocks_for(q, k, v, window), (sq, sk))))
 
 
 def _bwd_kernel(q, k, v, out, lse, cot, scale, causal=False,
-                interpret=False):
+                interpret=False, window=None):
     return flash_backward_kernel(q, k, v, out, lse, cot, float(scale),
-                                 bool(causal), *_blocks_for(q, k, v),
-                                 bool(interpret))
+                                 bool(causal), *_blocks_for(q, k, v, window),
+                                 bool(interpret), window=window)
 
 
-def _bwd_xla(q, k, v, out, lse, cot, scale, causal=False):
+def _bwd_xla(q, k, v, out, lse, cot, scale, causal=False, window=None):
     """The gradient of the dense reference: the XLA side of the pair is
     one function, forward and backward. It holds the (S, S) probabilities
     the kernels exist to avoid (2.1 GB a layer at the language model's
@@ -669,18 +792,20 @@ def _bwd_xla(q, k, v, out, lse, cot, scale, causal=False):
     ``MXNET_TPU_KERNELS=0`` sends a bucket here."""
     del out, lse
     _, vjp = jax.vjp(lambda a, b, c: flash_attention_reference(
-        a, b, c, scale, causal), q, k, v)
+        a, b, c, scale, causal, window), q, k, v)
     return vjp(cot)
 
 
-def _bwd_bucket(q, k, v, out, lse, cot, scale, causal=False):
+def _bwd_bucket(q, k, v, out, lse, cot, scale, causal=False, window=None):
     """The forward's key with the backward's own blocks."""
-    return _bucket(q, k, v, scale, causal, *_blocks_for(q, k, v))
+    return _bucket(q, k, v, scale, causal, *_blocks_for(q, k, v, window),
+                   window=window)
 
 
-def _bwd_supports(q, k, v, out, lse, cot, scale, causal=False):
+def _bwd_supports(q, k, v, out, lse, cot, scale, causal=False, window=None):
     """The forward's condition, at the backward's blocks."""
-    return _supports(q, k, v, scale, causal, *_blocks_for(q, k, v))
+    return _supports(q, k, v, scale, causal, *_blocks_for(q, k, v, window),
+                     window=window)
 
 
 def _register():

@@ -30,7 +30,8 @@ __all__ = ["flash_attention_reference", "decode_attention_reference"]
 
 @register("_contrib_flash_attention")
 def _contrib_flash_attention(q, k, v, scale=None, causal=False,
-                             block_q=None, block_k=None, interpret=False):
+                             block_q=None, block_k=None, interpret=False,
+                             window=None):
     """Fused attention over (B, H, S, D) tensors.
 
     Dispatches to the Pallas flash kernel (registry family
@@ -43,7 +44,9 @@ def _contrib_flash_attention(q, k, v, scale=None, causal=False,
     O(S*block): the kernel's backward is a dispatch of its own (family
     ``flash_attention_bwd``: Pallas calls that recompute the
     probabilities tile by tile from the saved row log-sum-exp), not a
-    dense recompute."""
+    dense recompute. ``k`` and ``v`` may have fewer heads than ``q``
+    (grouped keys: ``H / Hk`` query heads read one key head); ``window``
+    (causal only) is how many keys a query sees, its own included."""
     if q.ndim != 4:
         raise ValueError(
             f"flash_attention expects (B, H, S, D) inputs, got rank "
@@ -55,6 +58,7 @@ def _contrib_flash_attention(q, k, v, scale=None, causal=False,
     return _kernels.dispatch(
         "flash_attention", q, k, v, float(scale), causal=bool(causal),
         block_q=block_q, block_k=block_k,
+        window=None if window is None else int(window),
         interpret=bool(interpret) or None)
 
 

@@ -18,7 +18,8 @@ TOY = {
     "serve": {"rows": (1, 3, 2)},
     "kernels": {"attn": (1, 2, 256, 64), "attn_whole": (1, 2, 384, 64),
                 "decode": (2, 2, 256, 64),
-                "opt": (40, 130), "gemm": (64, 128, 256)},
+                "opt": (40, 130), "gemm": (64, 128, 256),
+                "scan": (1, 100, 128, 8)},
     "encoder": {"units": 64, "heads": 2, "hidden": 128, "seq": 128,
                 "batch": 2, "dtype": "bfloat16"},
 }

@@ -28,8 +28,8 @@ from mxnet_tpu.kernels import table as ktable
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FAMILIES = ("decode_attention", "flash_attention", "flash_attention_bwd",
-            "int8_gemm", "twobit_compress",
-            "twobit_decompress")
+            "int8_gemm", "selective_scan", "selective_scan_bwd",
+            "twobit_compress", "twobit_decompress")
 
 
 @pytest.fixture
@@ -484,6 +484,31 @@ def test_opperf_flash_sweep_marks_the_tile_the_shape_picks():
     assert [r["chosen"] for r in rows] == [False, False, True, False]
     assert all(r["wall_ms"] > 0 and r["device_ms"] is None
                and r["interpret"] for r in rows)
+
+
+def test_opperf_flash_window_sweep_marks_the_tile_the_band_picks():
+    """The windowed, grouped rows of ``--flash-sweep``: forward and fused
+    backward at every tile, the one ``flash._inside_band`` picks marked;
+    the real sweep holds the hybrid decoder's bucket at that tile."""
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import opperf
+
+    from mxnet_tpu.kernels import flash
+    for label, (_b, _h, _hk, s, d, dv), window, tiles in \
+            opperf._FLASH_WINDOW_SWEEP:
+        for blocks in (flash.default_blocks(s, s, d, dv, 2),
+                       flash.backward_blocks(s, s, d, dv)):
+            assert flash._inside_band(blocks, (s, s), window) in tiles, label
+    rows = opperf.sweep_flash_window(
+        runs=1, warmup=1,
+        cases=[("toy", (1, 4, 2, 512, 64, 128), 128,
+                [(128, 128), (256, 256)])])
+    assert [(r["side"], r["blocks"]) for r in rows] == [
+        ("forward", [128, 128]), ("backward", [128, 128]),
+        ("forward", [256, 256]), ("backward", [256, 256])]
+    assert [r["chosen"] for r in rows] == [True, True, False, False]
+    assert all(r["wall_ms"] > 0 and r["device_ms"] is None
+               and r["interpret"] and r["window"] == 128 for r in rows)
 
 
 def test_opperf_kernels_has_a_row_for_the_attention_backward(

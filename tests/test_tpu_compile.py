@@ -183,3 +183,88 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, quiet_cache):
     assert text.count("tpu_custom_call") >= 9
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * 2 ** 30
+
+
+def test_selective_scan_kernels_compile_at_the_cells_bucket(one_chip,
+                                                            quiet_cache):
+    """The scan at the hybrid decoder's shape: one sequence of 4,096
+    positions, 5,120 channels of 16 states, bfloat16 x, B and C and a
+    float32 step: the forward and the backward are one Mosaic call each,
+    64 positions a chunk and 512 channels a program inside the scoped
+    VMEM, and the states saved are the 21 MB of chunk boundaries."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import selective_scan as ss
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    wide = (1, 4096, 5120)
+    args = (_shape(wide, bf, one_chip), _shape(wide, f32, one_chip),
+            _shape((5120, 16), f32, one_chip),
+            _shape((1, 4096, 16), bf, one_chip),
+            _shape((1, 4096, 16), bf, one_chip),
+            _shape((5120,), f32, one_chip))
+    assert ss._supports(*args) and ss._lanes_of(5120) == 512
+    assert ss._bucket(*args) == "b1_s4096_d5120_n16_bfloat16_t64l512"
+    fwd = jax.jit(ss.selective_scan_forward)
+    assert fwd.lower(*args).compile().as_text().count("tpu_custom_call") == 1
+    y, states = jax.eval_shape(ss.selective_scan_forward, *args)
+    assert (y.shape, y.dtype) == (wide, bf)
+    assert (states.shape, states.dtype) == ((1, 64, 16, 5120), f32)
+    bwd = jax.jit(ss.selective_scan_backward_kernel)
+    cot = _shape(wide, bf, one_chip)
+    states = _shape(states.shape, f32, one_chip)
+    assert bwd.lower(*args, states, cot).compile().as_text().count(
+        "tpu_custom_call") == 1
+    grads = jax.eval_shape(ss.selective_scan_backward_kernel, *args, states,
+                           cot)
+    assert [(g.shape, g.dtype) for g in grads] \
+        == [(a.shape, a.dtype) for a in args]
+    # float32 inputs take the exact selector products at the highest
+    # precision; Mosaic has to accept those too
+    args32 = tuple(_shape(a.shape, f32, one_chip) for a in args)
+    assert fwd.lower(*args32).compile().as_text().count(
+        "tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("window,forward_blocks", [(None, (1024, 1024)),
+                                                   (512, (512, 512))],
+                         ids=["full", "window512"])
+def test_grouped_windowed_flash_compiles_at_the_cells_buckets(
+        one_chip, quiet_cache, window, forward_blocks):
+    """One softmax of differential attention at the hybrid decoder's
+    shape: 20 query heads 64 wide read 10 key heads through the index map,
+    values 128 wide, causal, with and without the window of 512: the
+    forward and the fused backward are one Mosaic call each, dK and dV
+    leave the kernel float32 a query head and are summed over the
+    group."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import flash
+
+    bf = jnp.bfloat16
+    q = _shape((1, 20, 4096, 64), bf, one_chip)
+    k = _shape((1, 10, 4096, 64), bf, one_chip)
+    v = _shape((1, 10, 4096, 128), bf, one_chip)
+    cot = _shape((1, 20, 4096, 128), bf, one_chip)
+    assert flash._supports(q, k, v, 0.125, True, window=window)
+    assert flash._blocks(q, k, v, window=window) == forward_blocks
+    assert flash._blocks_for(q, k, v, window) == (512, 512)
+    suffix = "_g2" + (f"_w{window}" if window else "")
+    assert flash._bucket(q, k, v, 0.125, True, window=window) == (
+        "bh32_sq4096_sk4096_d64v128_bfloat16_c1_q%dk%d" % forward_blocks
+        + suffix)
+
+    def fwd_bwd(q_, k_, v_, cot_):
+        out, lse = flash.flash_forward_lse(q_, k_, v_, 0.125, True,
+                                           *forward_blocks, window=window)
+        return (out,) + flash.flash_backward_kernel(
+            q_, k_, v_, out, lse, cot_, 0.125, True, 512, 512, window=window)
+
+    text = jax.jit(fwd_bwd).lower(q, k, v, cot).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "f32[20,4096,64]" in text        # dK a query head, before the sum
+    out, dq, dk, dv = jax.eval_shape(fwd_bwd, q, k, v, cot)
+    assert [(t.shape, t.dtype) for t in (out, dq, dk, dv)] \
+        == [(t.shape, t.dtype) for t in (cot, q, k, v)]
