@@ -91,10 +91,17 @@ class ShardedTrainer:
         Robustness levers:
 
         nan_guard : a non-finite loss or gradient SKIPS the whole update
-            (params, optimizer state and aux are selected back to their
-            pre-step values INSIDE the compiled step — one jnp.where per
-            buffer, no extra transfers), so one bad batch cannot poison
-            the run. Skips are counted (``skipped_steps`` /
+            (optimizer state, aux and every parameter without a master
+            are selected back to their pre-step values INSIDE the
+            compiled step — one jnp.where per buffer, no extra
+            transfers; a low-precision parameter under multi_precision
+            is the cast of its SELECTED fp32 master and has no select of
+            its own, which on a skipped step is the old parameter bit
+            for bit and keeps its update one pass over the state: a
+            select with the old parameter as an operand is one XLA
+            splits from the state's fusion and feeds by recomputing the
+            rule, 44 bytes a parameter under Adam for 28), so one bad
+            batch cannot poison the run. Skips are counted (``skipped_steps`` /
             ``consecutive_skips``, and in the profiler when recording);
             after `max_consecutive_skips` skips in a row step() raises —
             a permanently diverged run must fail loudly, not spin.
@@ -233,6 +240,14 @@ class ShardedTrainer:
                 mesh=self._mesh, churn=False)
         self._wd_mult = [1.0 if (n.endswith("weight") or n.endswith("gamma"))
                          else 0.0 for n in self._param_names]
+        # parameters with an fp32 master (multi_precision, low-precision
+        # weight), and weak references to the arrays their handles held
+        # when the trainer last bound them (_pair_masters)
+        self._mastered = tuple(
+            i for i, h in enumerate(self._train_handles)
+            if getattr(self._opt, "multi_precision", False)
+            and str(h._data.dtype) in ("bfloat16", "float16"))
+        self._paired = ()
         self._opt_raws = self._init_opt_state()
         self._step_fn = None
         # the signature nodes of the last step's outputs, built while the
@@ -355,9 +370,44 @@ class ShardedTrainer:
             tuple(self._global_put(s, self._state_spec_for(name, s.shape))
                   for s in per)
             for name, per in zip(self._param_names, self._opt_raws))
+        self._pair_masters()
 
-    def _is_lowp(self, raw):
-        return str(raw.dtype) in ("bfloat16", "float16")
+    def _pair_masters(self):
+        """Remember the arrays the mastered parameters' handles hold now
+        as the ones their masters belong to. The compiled step derives
+        such a parameter as cast(master) and never reads the parameter
+        for its update, so the pair has to hold whenever a step starts:
+        every place where the trainer itself binds both calls this
+        (placement, a step's commit, load_states, unshard), and
+        _adopt_outside_writes finds what anything else bound. Weak
+        references, as in _remember_signature."""
+        self._paired = tuple(weakref.ref(self._train_handles[i]._data)
+                             for i in self._mastered)
+
+    def _adopt_outside_writes(self):
+        """A mastered parameter whose handle no longer holds the array
+        the trainer bound was written from outside (``set_data``,
+        ``load_parameters``, ``x[:] = ...`` on ``param.data()``): the
+        written value is the weight now, so its master is re-derived
+        from it, as ``_init_opt_state`` derived the first one; the
+        rule's own state (momentum, Adam's moments) stays. Without this
+        the next step would compute from the old master and the write
+        would be lost."""
+        opt = None
+        for i, ref in zip(self._mastered, self._paired):
+            w = self._train_handles[i]._data
+            if ref() is w:
+                continue
+            opt = list(self._opt_raws) if opt is None else opt
+            # through the host, as a loaded checkpoint's master goes: a
+            # rare event, and whole on a process-spanning mesh too
+            w32 = self._global_put(
+                _np.asarray(self._host_copy(w)).astype(_np.float32),
+                self._state_spec_for(self._param_names[i], w.shape))
+            opt[i] = (w32,) + tuple(opt[i][1:])
+        if opt is not None:
+            self._opt_raws = tuple(opt)
+            self._pair_masters()
 
     def _init_opt_state(self):
         """Per-parameter state from the rule's factory. Under
@@ -366,11 +416,10 @@ class ShardedTrainer:
         fp32 (parity: create_state_multi_precision)."""
         import jax.numpy as jnp
 
-        mp = getattr(self._opt, "multi_precision", False)
         out = []
-        for h in self._train_handles:
+        for i, h in enumerate(self._train_handles):
             w = h._data
-            if mp and self._is_lowp(w):
+            if i in self._mastered:
                 w32 = jnp.asarray(w, jnp.float32)
                 out.append((w32,) + self._rule.init(self._opt, w32))
             else:
@@ -403,6 +452,11 @@ class ShardedTrainer:
             # it takes split(rng)[1] itself (an executable persisted by a
             # build whose caller split must not be loaded by this one)
             "rng=split-in-step",
+            # ... and derives a mastered parameter as the cast of its
+            # selected master (an executable whose step selected the
+            # parameter beside the state computes the same values in two
+            # passes: it must not be loaded in this one's place)
+            "update=cast-of-selected-master",
             # kernel-dispatch identity: a retuned table or a flipped
             # MXNET_TPU_KERNELS must not reuse an executable traced
             # under the old routing
@@ -483,8 +537,7 @@ class ShardedTrainer:
         wd_mult = self._wd_mult
         opt = self._opt
         rule = self._rule
-        multi_precision = getattr(opt, "multi_precision", False)
-        is_lowp = self._is_lowp
+        mastered = self._mastered
         n_aux = len(aux_handles)
 
         def run_net(praws, araws, x, y, rng):
@@ -580,6 +633,13 @@ class ShardedTrainer:
             else:
                 finite = jnp.bool_(True)
             tt = t.astype(jnp.float32)
+
+            def keep(new, old):
+                # NaN/Inf step guard: select a buffer back to its pre-step
+                # value when any grad (or the loss) is non-finite — the
+                # update is skipped entirely, on device
+                return jnp.where(finite, new, old) if nan_guard else new
+
             new_p, new_opt = [], []
             for i, (w, g, st) in enumerate(zip(praws, grads, opt_raws)):
                 pwd = wd * wd_mult[i]
@@ -595,32 +655,32 @@ class ShardedTrainer:
                     # backward by the latency-hiding scheduler
                     g = jax.lax.with_sharding_constraint(g, grad_sh[i])
                 rng_i = jax.random.fold_in(rng, i + 1)  # stochastic rules
-                if multi_precision and is_lowp(w):
+                if i in mastered:
                     # fp32 master copy leads the state tuple; the rule
-                    # runs entirely in fp32, params get the cast result
-                    w32, inner = st[0], st[1:]
+                    # runs entirely in fp32
                     w32n, innern = rule.update(
-                        opt, w32, g.astype(jnp.float32), inner, lr, pwd,
+                        opt, st[0], g.astype(jnp.float32), st[1:], lr, pwd,
                         tt, rng_i)
-                    new_p.append(w32n.astype(w.dtype))
-                    new_opt.append((w32n,) + tuple(innern))
+                    stn = tuple(keep(ns, s) for ns, s in
+                                zip((w32n,) + tuple(innern), st))
+                    # the parameter is the cast of the SELECTED master and
+                    # has no select of its own: the old parameter is
+                    # cast(old master) (_adopt_outside_writes keeps the
+                    # pair so), and a select with an operand the fp32
+                    # ones lack is one XLA keeps out of their fusion and
+                    # feeds by recomputing the whole rule — a second pass
+                    # over master, state and gradient, 44 bytes a
+                    # parameter under Adam where this one pass moves 28
+                    new_p.append(stn[0].astype(w.dtype))
+                    new_opt.append(stn)
                 else:
                     # keep update arithmetic in the param dtype
                     wn, stn = rule.update(
                         opt, w, g.astype(w.dtype), st, lr, pwd, tt, rng_i)
-                    new_p.append(wn)
-                    new_opt.append(tuple(stn))
-            if nan_guard:
-                # NaN/Inf step guard: select every buffer back to its
-                # pre-step value when any grad (or the loss) is non-finite
-                # — the update is skipped entirely, on device
-                new_p = [jnp.where(finite, n, w)
-                         for n, w in zip(new_p, praws)]
-                new_opt = [tuple(jnp.where(finite, ns, s)
-                                 for ns, s in zip(per_new, per_old))
-                           for per_new, per_old in zip(new_opt, opt_raws)]
-                new_aux = tuple(jnp.where(finite, na, a)
-                                for na, a in zip(new_aux, araws))
+                    new_p.append(keep(wn, w))
+                    new_opt.append(tuple(keep(ns, s)
+                                         for ns, s in zip(stn, st)))
+            new_aux = tuple(keep(na, a) for na, a in zip(new_aux, araws))
             return tuple(new_p), tuple(new_opt), new_aux, loss, finite
 
         # shardings: batch over dp; params per rules; opt state reuses the
@@ -771,6 +831,7 @@ class ShardedTrainer:
             key = _rand.current_key()
         _tsteps.phase("host", sp.dur_ms)
         with _span("trainer.gather") as sp:
+            self._adopt_outside_writes()
             in_p = tuple(h._data for h in self._train_handles)
             in_opt = self._opt_raws
             in_aux = tuple(h._data for h in self._aux_handles)
@@ -815,6 +876,7 @@ class ShardedTrainer:
                 for h, raw in zip(self._aux_handles, new_aux):
                     h._data = raw
             self._opt_raws = new_opt
+            self._pair_masters()
             # the next step's arguments are these outputs: walk their
             # ~600 leaves for its signature now, not ahead of its enqueue
             self._remember_signature(new_p, new_opt, new_aux)
@@ -1018,6 +1080,7 @@ class ShardedTrainer:
 
         from .. import random as _rand
 
+        self._adopt_outside_writes()
         names_blob = "\n".join(self._param_names + self._aux_names)
         payload = {
             "__t__": NDArray(jnp.asarray(self._t, jnp.int32)),
@@ -1315,7 +1378,11 @@ class ShardedTrainer:
         _rand._state.key = arrays["__rng_key__"]._data
         for i, (name, h) in enumerate(zip(self._param_names,
                                           self._train_handles)):
-            h._rebind(take(f"p{i}", h._data.dtype, self._spec_for(name)))
+            # a mastered parameter is the cast of its saved master, the
+            # saved parameter itself unless something wrote it behind
+            # the saving trainer's back (_adopt_outside_writes)
+            key = f"s{i}_0" if i in self._mastered else f"p{i}"
+            h._rebind(take(key, h._data.dtype, self._spec_for(name)))
         for i, h in enumerate(self._aux_handles):
             h._rebind(take(f"a{i}", h._data.dtype, self._mesh.replicated()))
         self._opt_raws = tuple(
@@ -1324,6 +1391,7 @@ class ShardedTrainer:
                   for j, s in enumerate(per))
             for i, (name, per) in enumerate(zip(self._param_names,
                                                 self._opt_raws)))
+        self._pair_masters()
 
     def unshard(self, ctx=None):
         """Gather parameters back to one device for eager/export use."""
@@ -1332,8 +1400,10 @@ class ShardedTrainer:
         from ..context import current_context
 
         dev = (ctx or current_context()).jax_device()
+        self._adopt_outside_writes()
         for h in self._train_handles + self._aux_handles:
             h._rebind(jax.device_put(self._host_copy(h._data), dev))
+        self._pair_masters()
 
     @property
     def mesh(self):

@@ -320,6 +320,109 @@ def test_nan_guard_off_lets_nans_through():
     assert any(np.isnan(v).any() for v in _params_of(net).values())
 
 
+def _make_bf16_trainer(optimizer, zero, seed=7):
+    """bfloat16 weights with float32 masters around a BatchNorm (float32
+    scale and shift with no master, auxiliary state), on four of the
+    virtual devices."""
+    mx.random.seed(seed)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, activation="relu", in_units=6),
+            gluon.nn.BatchNorm(in_channels=8),
+            gluon.nn.Dense(4, in_units=8))
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    params = {"adam": {"learning_rate": 0.05},
+              "sgd": {"learning_rate": 0.05, "momentum": 0.9}}[optimizer]
+    return net, ShardedTrainer(
+        net, gluon.loss.L2Loss(), optimizer,
+        dict(params, multi_precision=True), mesh=DeviceMesh({"dp": 4}),
+        zero=zero)
+
+
+def _bf16_batch(epoch, step):
+    x, y = _batch(epoch, step)
+    return x.astype("bfloat16"), y.astype("bfloat16")
+
+
+def _device_state(tr):
+    """Every array a step writes, on the host: (parameters, optimizer
+    state a parameter, aux)."""
+    return ([np.asarray(h._data) for h in tr._train_handles],
+            [[np.asarray(s) for s in per] for per in tr._opt_raws],
+            [np.asarray(h._data) for h in tr._aux_handles])
+
+
+def _cast_of(master, like):
+    import jax.numpy as jnp
+
+    return np.asarray(jnp.asarray(master).astype(like.dtype))
+
+
+def _mastered(params, opt):
+    """(parameter index, master) of the bfloat16 parameters."""
+    out = [(i, per[0]) for i, (w, per) in enumerate(zip(params, opt))
+           if str(w.dtype) == "bfloat16"]
+    assert len(out) == 4 and len(params) == 6
+    assert all(str(m.dtype) == "float32" for _, m in out)
+    return out
+
+
+@pytest.mark.parametrize("zero", [False, True], ids=["dp4", "zero4"])
+@pytest.mark.parametrize("optimizer", ["adam", "sgd"])
+def test_nan_guard_with_bf16_masters_skips_and_continues(optimizer, zero):
+    """A mastered parameter is the cast of the SELECTED master and has no
+    select of its own in the compiled step: a skipped step must still leave
+    it, its master, the rule's state and the aux bit-identical, and the
+    next finite step goes on from them."""
+    net, tr = _make_bf16_trainer(optimizer, zero)
+    x, y = _bf16_batch(1, 0)
+    tr.step(x, y)
+    tr.step(x, y)
+    p0, opt0, aux0 = _device_state(tr)
+    assert aux0
+
+    faults.configure("trainer.step:nan@1")
+    assert not np.isfinite(tr.step(x, y).asscalar())
+    assert tr.skipped_steps == 1
+    p1, opt1, aux1 = _device_state(tr)
+    for a, b in zip(p0 + aux0 + sum(opt0, []), p1 + aux1 + sum(opt1, [])):
+        np.testing.assert_array_equal(a, b)
+
+    faults.reset()
+    assert np.isfinite(tr.step(x, y).asscalar())
+    p2, opt2, _ = _device_state(tr)
+    for (i, m1), (_, m2) in zip(_mastered(p1, opt1), _mastered(p2, opt2)):
+        assert not np.array_equal(m1, m2)
+        np.testing.assert_array_equal(p2[i], _cast_of(m2, p2[i]))
+        # no further from where the skip left it than a step could go
+        assert np.abs(m2 - m1).max() < 0.1
+    assert all(not np.array_equal(w1, w2) for w1, w2 in zip(p1, p2))
+
+
+@pytest.mark.parametrize("zero", [False, True], ids=["dp4", "zero4"])
+@pytest.mark.parametrize("optimizer", ["adam", "sgd"])
+def test_bf16_parameter_is_the_guarded_cast_of_its_master(optimizer, zero):
+    """Over finite and skipped steps every bfloat16 parameter is, bit for
+    bit, ``where(finite, cast(new master), old parameter)``, the form the
+    step had before it derived the parameter from the selected master,
+    computed here from the recorded masters; and it is ``cast(master)``."""
+    net, tr = _make_bf16_trainer(optimizer, zero)
+    faults.configure("trainer.step:nan@3,4")
+    prev, _, _ = _device_state(tr)
+    finite_seen = []
+    for step in range(6):
+        loss = tr.step(*_bf16_batch(1, step))
+        finite = bool(np.isfinite(loss.asscalar()))
+        finite_seen.append(finite)
+        now, opt, _ = _device_state(tr)
+        for i, master in _mastered(now, opt):
+            want = _cast_of(master, now[i]) if finite else prev[i]
+            np.testing.assert_array_equal(now[i], want)
+            np.testing.assert_array_equal(now[i], _cast_of(master, now[i]))
+        prev = now
+    assert finite_seen == [True, True, False, False, True, True]
+
+
 # ---------------------------------------------------- kill-and-resume ------
 
 def _train(trainer, manager, epochs, steps, start_epoch=0):

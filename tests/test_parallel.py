@@ -744,6 +744,97 @@ def test_sharded_trainer_multi_precision_master_weights():
     assert err_mp < 0.01
 
 
+def _bf16_trainer(seed=3):
+    """Two bias-free bfloat16 layers under Adam with float32 masters."""
+    x, y = _zoo_data()
+    net = _zoo_net(x)
+    net.cast("bfloat16")
+    mx.random.seed(seed)
+    tr = ShardedTrainer(net, gloss.L2Loss(), "adam",
+                        {"learning_rate": 0.01, "multi_precision": True},
+                        mesh=DeviceMesh({"dp": 4}))
+    return net, tr, x.astype("bfloat16"), y.astype("bfloat16")
+
+
+def _assert_paired(tr):
+    """Every parameter of ``_bf16_trainer`` is bit for bit the cast of its
+    master, which the compiled step relies on."""
+    import jax.numpy as jnp
+
+    for h, per in zip(tr._train_handles, tr._opt_raws):
+        assert str(per[0].dtype) == "float32"
+        np.testing.assert_array_equal(
+            np.asarray(h._data),
+            np.asarray(jnp.asarray(per[0]).astype(h._data.dtype)))
+
+
+@pytest.mark.parametrize("door", ["set_data", "set_data_then_save",
+                                  "resume", "inconsistent_file", "unshard"])
+def test_outside_writes_keep_a_bf16_parameter_the_cast_of_its_master(
+        door, tmp_path):
+    """The compiled step derives a mastered parameter from its master
+    alone, so every door that writes one behind the step's back has to
+    leave the pair consistent: a value written into the parameter becomes
+    its master, a loaded parameter is the cast of the loaded master."""
+    from mxnet_tpu.checkpoint import CheckpointManager
+    from mxnet_tpu.ndarray import utils as nd_utils
+
+    net, tr, x, y = _bf16_trainer()
+    for _ in range(2):
+        tr.step(x, y)
+    _assert_paired(tr)
+    first = next(iter(net.collect_params().values()))
+    threes = mx.nd.ones(first.shape) * 3      # far from any Xavier weight
+    masters = [np.asarray(per[0]) for per in tr._opt_raws]
+    if door == "set_data":
+        first.set_data(threes)
+        tr.step(x, y)
+        _assert_paired(tr)
+        # the step went on from the written value, not from the old master
+        assert np.abs(np.asarray(tr._opt_raws[0][0]) - 3).max() < 0.05
+        assert not np.array_equal(np.asarray(tr._opt_raws[1][0]), masters[1])
+    elif door == "set_data_then_save":
+        first.set_data(threes)
+        tr.save_states(str(tmp_path / "states"))
+        net2, tr2, _, _ = _bf16_trainer(seed=4)
+        tr2.load_states(str(tmp_path / "states"))
+        _assert_paired(tr2)
+        np.testing.assert_array_equal(np.asarray(tr2._opt_raws[0][0]), 3)
+        np.testing.assert_array_equal(np.asarray(tr2._opt_raws[1][0]),
+                                      masters[1])
+    elif door == "resume":
+        tr.save_checkpoint(CheckpointManager(tmp_path, prefix="ck"), 1)
+        net2, tr2, _, _ = _bf16_trainer(seed=4)
+        assert tr2.resume(CheckpointManager(tmp_path, prefix="ck"))
+        _assert_paired(tr2)
+        for _ in range(2):                    # as loaded, and a step on
+            for pa, pb in zip(tr._opt_raws, tr2._opt_raws):
+                for sa, sb in zip(pa, pb):
+                    np.testing.assert_array_equal(np.asarray(sa),
+                                                  np.asarray(sb))
+            for ha, hb in zip(tr._train_handles, tr2._train_handles):
+                np.testing.assert_array_equal(np.asarray(ha._data),
+                                              np.asarray(hb._data))
+            tr.step(x, y)
+            tr2.step(x, y)
+    elif door == "inconsistent_file":
+        # a file whose parameter is not its master's cast (a trainer of
+        # before this rule wrote one after a set_data with no step since)
+        payload = tr._state_payload()
+        payload["p0"] = threes.astype("bfloat16")
+        nd_utils.save(str(tmp_path / "states"), payload)
+        tr.load_states(str(tmp_path / "states"))
+        _assert_paired(tr)
+        np.testing.assert_array_equal(np.asarray(tr._opt_raws[0][0]),
+                                      masters[0])
+    else:
+        tr.unshard()
+        _assert_paired(tr)
+        tr._state_payload()                   # adopts whatever was written
+        for per, m in zip(tr._opt_raws, masters):
+            np.testing.assert_array_equal(np.asarray(per[0]), m)
+
+
 def test_sharded_trainer_optimizer_instance_lr_honored():
     """An Optimizer INSTANCE carries its own lr (and scheduler): the
     compiled step must use it, not the 0.01 default."""

@@ -12,16 +12,21 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:   # no libtpu here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -268,3 +273,88 @@ def test_grouped_windowed_flash_compiles_at_the_cells_buckets(
     out, dq, dk, dv = jax.eval_shape(fwd_bwd, q, k, v, cot)
     assert [(t.shape, t.dtype) for t in (out, dq, dk, dv)] \
         == [(t.shape, t.dtype) for t in (cot, q, k, v)]
+
+
+def _bf16_step_text(monkeypatch, devices, width, optimizer, params, **kw):
+    """The optimized HLO of a ``ShardedTrainer`` step over two bias-free
+    ``width`` x ``width`` bfloat16 layers with float32 masters, compiled
+    for the described ``devices`` (nothing can be put on those, so the
+    trainer's own placement is skipped, as ``rehearse_compile.py`` does)."""
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon import loss as gloss, nn
+    from mxnet_tpu.parallel import DeviceMesh, ShardedTrainer
+
+    monkeypatch.setattr(ShardedTrainer, "_place_params", lambda self: None)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(width, in_units=width, use_bias=False),
+            nn.Dense(width, in_units=width, use_bias=False))
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    trainer = ShardedTrainer(
+        net, gloss.L2Loss(), optimizer, dict(params, multi_precision=True),
+        mesh=DeviceMesh({"dp": len(devices)}, devices=devices), **kw)
+    batch = jax.ShapeDtypeStruct((8, width), jnp.bfloat16)
+    return trainer.aot_lower(batch, batch).compile().as_text()
+
+
+def _entry_fusions(text):
+    """``(name, result dtypes, operand names)`` of the entry computation's
+    fusions."""
+    import re
+
+    entry = text[text.index("ENTRY "):]
+    out = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%(\S+) = (.+?) fusion\((.*?)\), kind=", entry,
+            re.MULTILINE):
+        out.append((m.group(1), re.findall(r"(\w+)\[[\d,]*\]", m.group(2)),
+                    re.findall(r"%([\w.\-]+)", m.group(3))))
+    return out
+
+
+@pytest.mark.parametrize("optimizer,params,slots", [
+    ("adam", {"learning_rate": 1e-3}, 3),
+    ("sgd", {"learning_rate": 1e-3, "momentum": 0.9}, 2),
+], ids=["adam", "sgd_momentum"])
+def test_bf16_parameter_update_is_one_fusion(v5e, quiet_cache, monkeypatch,
+                                             optimizer, params, slots):
+    """The guarded update of a bfloat16 parameter with a float32 master is
+    ONE pass over master, state and gradient: the fusion that writes the
+    float32 state writes the bfloat16 weight too, and no fusion whose
+    results are all low-precision reads optimizer state. (Where the
+    parameter's select had the old weight as an operand of its own, XLA
+    kept it out of the state's fusion and recomputed the whole rule in a
+    ``convert_select_fusion`` over the same arrays: 44 bytes a parameter
+    under Adam where 28 are needed.) At 4,096 x 4,096 no state array is
+    prefetched, so the entry's parameter names are the operands."""
+    fusions = _entry_fusions(_bf16_step_text(
+        monkeypatch, v5e.devices[:1], 4096, optimizer, params))
+    reads_state = [f for f in fusions
+                   if any(o.startswith("opt_raws_") for o in f[2])]
+    assert not [f for f in reads_state
+                if set(f[1]) <= {"bf16", "f16"}], reads_state
+    for i in range(2):
+        mine = [f for f in reads_state
+                if any(o.startswith(f"opt_raws_{i}__") for o in f[2])]
+        assert len(mine) == 1, mine
+        assert sorted(mine[0][1]) == ["bf16"] + ["f32"] * slots, mine
+        assert {o.split(".")[0] for o in mine[0][2]
+                if o.startswith("opt_raws_")} \
+            == {f"opt_raws_{i}__{j}_" for j in range(slots)}, mine
+
+
+def test_zero_gathers_the_bf16_parameter_not_its_master(v5e, quiet_cache,
+                                                        monkeypatch):
+    """Under ``zero`` the state is dp-sharded and the parameter is the cast
+    of the selected master: the cast happens on the shard, so what crosses
+    the chips is the bfloat16 weight, not the float32 master."""
+    import re
+
+    text = _bf16_step_text(monkeypatch, v5e.devices[:4], 1024, "adam",
+                           {"learning_rate": 1e-3}, zero=True)
+    gathered = re.findall(r"= (\w+)\[1024,1024\]\S* all-gather(?:-start)?\(",
+                          text)
+    assert gathered and set(gathered) == {"bf16"}, gathered
