@@ -317,11 +317,11 @@ _FLASH_SWEEP = [
 ]
 
 
-def _device_ms(fn, args, runs, match=""):
-    """Device time a call of ``fn`` spends in the operations whose name
-    holds ``match``, from a profiler trace of ``runs`` calls, read as the
-    benchmark reads its own (``chipbench/harness/trace_reduce.py``); None
-    where the trace has no TPU plane (the interpreter, the CPU)."""
+def _device_ops(fn, args, runs):
+    """``{operation: ms a call}`` of ``fn``'s device operations by self
+    time, from a profiler trace of ``runs`` calls, read as the benchmark
+    reads its own (``chipbench/harness/trace_reduce.py``); empty where the
+    trace has no TPU plane (the interpreter, the CPU)."""
     import tempfile
 
     import jax
@@ -335,10 +335,19 @@ def _device_ms(fn, args, runs, match=""):
             jax.block_until_ready(fn(*args))
         jax.profiler.stop_trace()
         trace = trace_reduce.load(trace_reduce.find_xplane(log_dir))
-    ns = [self_ns for events in trace["devices"].values()
-          for name, self_ns in trace_reduce.self_times(events)
+    ops = {}
+    for events in trace["devices"].values():
+        for name, self_ns in trace_reduce.self_times(events):
+            ops[name] = ops.get(name, 0.0) + self_ns / 1e6 / runs
+    return ops
+
+
+def _device_ms(fn, args, runs, match=""):
+    """Device time a call of ``fn`` spends in the operations whose name
+    holds ``match``; None where the trace has no TPU plane."""
+    ms = [t for name, t in _device_ops(fn, args, runs).items()
           if match in name]
-    return round(sum(ns) / 1e6 / runs, 4) if ns else None
+    return round(sum(ms), 4) if ms else None
 
 
 def sweep_flash_forward(runs=10, warmup=3, cases=None, dtype="bfloat16"):
@@ -452,6 +461,246 @@ def sweep_flash_window(runs=10, warmup=3, cases=None, dtype="bfloat16"):
     return rows
 
 
+# The gradient of ``Embedding`` on the chip, one command (the docstring of
+# ``ops/tensor.py`` ``embedding_grad_columns`` quotes its output): the
+# table's cotangent alone in a jit, rows of Zipf(1) ids from a seed, at the
+# three text cells' tables, at what separates the causes of the hybrid
+# decoder's 10 ms (unique ids, the tied table, half the table, float32), at
+# the published vocabularies, and over widths and row counts around them.
+# ``forms`` names the op's own (``scatter`` whole, ``columns_N`` in blocks of
+# N columns) and the candidates that lost.
+_ZIPF, _UNIQUE, _ARANGE = "zipf", "unique", "arange"
+_EVERY_FORM = ("scatter", "columns_512", "columns_1024", "product",
+               "sorted_dedup", "row_blocks_2", "id_chunks_8")
+_EMBEDDING_GRAD_SWEEP = [
+    # label, rows, (vocab, width), dtype, ids, tied, forms
+    ("phi4_flash", 4096, (25008, 2560), "bfloat16", _ZIPF, False,
+     _EVERY_FORM + ("columns_256", "row_blocks_4")),
+    ("phi4_flash_unique_ids", 4096, (25008, 2560), "bfloat16", _UNIQUE,
+     False, ("scatter", "columns_512", "product")),
+    ("phi4_flash_tied", 4096, (25008, 2560), "bfloat16", _ZIPF, True,
+     ("scatter", "columns_512", "columns_1024", "product", "id_chunks_8")),
+    ("phi4_flash_half_table", 4096, (12504, 2560), "bfloat16", _ZIPF, False,
+     ("scatter", "columns_512", "product")),
+    ("phi4_flash_float32", 4096, (25008, 2560), "float32", _ZIPF, False,
+     ("scatter", "columns_512", "product")),
+    ("kanana2", 8192, (16032, 2048), "bfloat16", _ZIPF, False, _EVERY_FORM),
+    ("bert_words", 12288, (30522, 768), "bfloat16", _ZIPF, False,
+     _EVERY_FORM[:1] + ("columns_256",) + _EVERY_FORM[1:]),
+    ("bert_positions", 384, (512, 768), "bfloat16", _ARANGE, False,
+     ("scatter", "product")),
+    ("bert_types", 12288, (2, 768), "bfloat16", _ZIPF, False,
+     ("scatter", "columns_512", "product")),
+    ("phi4_flash_published", 4096, (200064, 2560), "bfloat16", _ZIPF, False,
+     ("scatter", "columns_512", "product", "sorted_dedup",
+      "row_blocks_16")),
+    ("phi4_flash_published_tied", 4096, (200064, 2560), "bfloat16", _ZIPF,
+     True, ("scatter", "sorted_dedup")),
+    ("phi4_flash_published_32k_rows", 32768, (200064, 2560), "bfloat16",
+     _ZIPF, False, ("scatter", "columns_512")),
+    ("kanana2_published", 8192, (128256, 2048), "bfloat16", _ZIPF, False,
+     ("scatter", "columns_512", "sorted_dedup")),
+] + [
+    (f"width_{w}", 4096, (25008, w), "bfloat16", _ZIPF, False,
+     ("scatter", "columns_512") if w > 512 else ("scatter",))
+    for w in (256, 512, 768, 1024, 1280, 1536, 2048, 2304, 3072, 4096, 5120)
+] + [
+    (f"rows_{n}", n, (25008, 2560), "bfloat16", _ZIPF, False,
+     ("scatter", "columns_512", "product"))
+    for n in (1024, 2048, 3200, 16384)
+] + [
+    (f"row_by_row_width_{w}", 4096, (200064, w), "bfloat16", _ZIPF, False,
+     ("scatter",)) for w in (768, 2048)
+]
+
+
+def _embedding_grad_forms():
+    """``{name: f(ids, cot, vocab)}``: the table's cotangent as
+    ``Embedding``'s backward computes it, whole (``scatter``) or in blocks
+    of columns (``columns_512`` is the op's block), and by the candidates
+    that lost (my chip runs, PR 32)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import tensor
+
+    def zeros(vocab, cot):
+        return jnp.zeros((vocab, cot.shape[1]), cot.dtype)
+
+    def summed(same, cot):
+        return jax.lax.dot_general(
+            same.astype(cot.dtype), cot, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(cot.dtype)
+
+    def sorted_dedup(ids, cot, vocab):
+        # ids sorted; an (N, N) product sums a run of equal ids into its
+        # first row in float32; the other rows go out of range (dropped)
+        order = jnp.argsort(ids)
+        ids, cot = ids[order], cot[order]
+        first = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+        same = (ids[:, None] == ids[None, :]) & first[:, None]
+        return jnp.where(first, ids, vocab), summed(same, cot)
+
+    def unique_scatter(rows_of):
+        def form(ids, cot, vocab):
+            rows, sums = rows_of(ids, cot, vocab)
+            return zeros(vocab, cot).at[rows].add(
+                sums, unique_indices=True, mode="drop")
+        return form
+
+    def row_blocks(k):
+        # the table in k blocks of rows, each scattered on its own
+        def form(ids, cot, vocab):
+            size = -(-vocab // k // 8) * 8
+            parts = []
+            for lo in range(0, vocab, size):
+                n = min(size, vocab - lo)
+                local = jnp.where((ids >= lo) & (ids < lo + n), ids - lo, n)
+                parts.append(zeros(n, cot).at[local].add(cot, mode="drop"))
+            return jnp.concatenate(parts)
+        return form
+
+    def id_chunks(k):
+        # duplicates summed first, then k scatters of rows / k ids each
+        # into one table: each is small against the table, so XLA updates
+        # row by row, in place
+        def form(ids, cot, vocab):
+            rows, sums = sorted_dedup(ids, cot, vocab)
+            out, n = zeros(vocab, cot), ids.shape[0] // k
+            for lo in range(0, ids.shape[0], n):
+                out = out.at[rows[lo:lo + n]].add(sums[lo:lo + n],
+                                                  mode="drop")
+            return out
+        return form
+
+    def product(ids, cot, vocab):
+        # one_hot(ids)^T @ cot, float32 accumulator; XLA fuses the
+        # comparison into the product's operand (no one-hot in memory)
+        one_hot = ids[:, None] == jnp.arange(vocab, dtype=ids.dtype)[None, :]
+        return jax.lax.dot_general(
+            one_hot.astype(cot.dtype), cot, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(cot.dtype)
+
+    def column_blocks(columns):
+        # the op's own form: the table in blocks of columns, each
+        # scattered on its own (``None``: one block, XLA's scatter whole)
+        return lambda ids, cot, vocab: tensor._embedding_grad(
+            ids, cot, vocab, columns or cot.shape[1])
+
+    forms = {"scatter": column_blocks(None), "product": product,
+             "sorted_dedup": unique_scatter(sorted_dedup)}
+    forms.update({f"columns_{c}": column_blocks(c)
+                  for c in (256, 512, 1024)})
+    forms.update({f"row_blocks_{k}": row_blocks(k) for k in (2, 4, 16)})
+    forms["id_chunks_8"] = id_chunks(8)
+    return forms
+
+
+def _sweep_ids(kind, rows, vocab, seed):
+    """``rows`` ids over ``vocab``: Zipf(1) as the text cells draw them
+    (id ``i`` with probability proportional to ``1 / (i + 1)``), a random
+    choice without repeats, or 0, 1, 2, ... (BERT's positions)."""
+    rng = np.random.default_rng(seed)
+    if kind == _UNIQUE:
+        return rng.permutation(vocab)[:rows].astype(np.int32)
+    if kind == _ARANGE:
+        return np.arange(rows, dtype=np.int32)
+    p = 1.0 / np.arange(1, vocab + 1)
+    return np.minimum(np.searchsorted(np.cumsum(p) / p.sum(),
+                                      rng.random(rows), side="right"),
+                      vocab - 1).astype(np.int32)
+
+
+def sweep_embedding_grad(runs=10, warmup=3, cases=None, seed=32,
+                         text_dir=None):
+    """Time the cotangent of ``Embedding``'s table alone in a jit in every
+    form of ``cases`` (``_EMBEDDING_GRAD_SWEEP``): ``device_ms`` a call
+    over all its device operations with the largest of them beside it,
+    ``wall_ms`` by the host's clock. A tied case adds the cotangent to a
+    table it is given (the head's dW), donated, as the step does (a new
+    table a call: its ``wall_ms`` also holds that table's first use, ~12
+    ms on the chip; read its ``device_ms``). From the
+    compiled text: ``emitter`` says whether XLA sorted the ids itself and
+    walks the table (``sorted``) or updates it row by row, ``S(1)`` whether a
+    scatter's result got the compiler's fast-memory placement. ``max_err``
+    and ``id0_err`` are against a float64 sum of the same rows (id 0 has
+    the most duplicates). ``chosen`` marks the form the op takes
+    (``tensor.embedding_grad_columns``). The compiled text of every row
+    goes to ``text_dir``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import kernels as klayer
+    from mxnet_tpu.ops import tensor
+
+    forms = _embedding_grad_forms()
+    rows_out = []
+    for label, rows, (vocab, width), dtype, kind, tied, names in \
+            cases or _EMBEDDING_GRAD_SWEEP:
+        host_ids = _sweep_ids(kind, rows, vocab, seed)
+        ids = jnp.asarray(host_ids)
+        cot = jnp.asarray(np.random.default_rng(seed + 1).standard_normal(
+            (rows, width), dtype=np.float32), dtype)
+        want = np.zeros((vocab, width), np.float64)
+        np.add.at(want, host_ids, np.asarray(cot, np.float64))
+        head = None
+        if tied:
+            head = np.random.default_rng(seed + 2).standard_normal(
+                (vocab, width), dtype=np.float32)
+            want += np.asarray(jnp.asarray(head, dtype), np.float64)
+        columns = tensor.embedding_grad_columns(rows, vocab, width)
+        chosen = "scatter" if columns >= width else f"columns_{columns}"
+        for name in names:
+            form = forms[name]
+            row = {"case": label, "rows": rows, "table": [vocab, width],
+                   "dtype": dtype, "ids": kind, "tied": tied, "form": name,
+                   "chosen": name == chosen, "on_tpu": klayer.on_tpu()}
+            try:
+                if tied:
+                    fn = jax.jit(lambda dw, i, c, _f=form:
+                                 dw + _f(i, c, dw.shape[0]),
+                                 donate_argnums=0)
+                    args = lambda: (jnp.asarray(head, dtype), ids, cot)
+                else:
+                    fn = jax.jit(lambda i, c, _f=form, _v=vocab:
+                                 _f(i, c, _v))
+                    args = lambda: (ids, cot)
+                text = fn.lower(*args()).compile().as_text()
+                if text_dir:
+                    with open(os.path.join(
+                            text_dir, f"{label}.{name}.hlo.txt"), "w") as f:
+                        f.write(text)
+                scatters = [line for line in text.splitlines()
+                            if " scatter(" in line]
+                row["emitter"] = None if not scatters else "sorted" if any(
+                    "indices_are_sorted=true" in line for line in scatters) \
+                    else "row_by_row"
+                row["S(1)"] = [
+                    "S(1)" in line.split(" scatter(")[0] for line in scatters]
+                err = np.abs(np.asarray(fn(*args()), np.float64) - want)
+                row["max_err"] = float(err.max())
+                row["id0_err"] = float(err[0].max())
+                # a donated table is spent by its call: a new one a call,
+                # made before the clock starts
+                wall = 0.0
+                for i in range(warmup + runs):
+                    a = jax.block_until_ready(args())
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(fn(*a))
+                    wall += (time.perf_counter() - t0) * (i >= warmup)
+                row["wall_ms"] = round(wall / runs * 1e3, 4)
+                traced = iter([jax.block_until_ready(args())
+                               for _ in range(runs)])
+                ops = _device_ops(lambda: fn(*next(traced)), (), runs)
+                if ops:
+                    row["device_ms"] = round(sum(ops.values()), 4)
+                    row["largest_ops"] = [
+                        [n, round(t, 4)] for n, t in sorted(
+                            ops.items(), key=lambda kv: -kv[1])[:3]]
+            except Exception as e:  # the compiler's refusal is the row
+                row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+            rows_out.append(row)
+    return rows_out
+
+
 def run_benchmark(ops, size=_DEFAULT_SIZE, runs=10, warmup=2):
     results = []
     for name in ops:
@@ -489,6 +738,13 @@ def main():
                              "backward at those of _FLASH_WINDOW_SWEEP, "
                              "print the tables and keep them as "
                              "DIR/flash_{forward,window}_sweep.json")
+    parser.add_argument("--embedding-grad-sweep", type=str, default="",
+                        metavar="DIR",
+                        help="time the gradient of Embedding's table in "
+                             "every form of _EMBEDDING_GRAD_SWEEP, print "
+                             "the table and keep it as "
+                             "DIR/embedding_grad_sweep.json beside the "
+                             "compiled text of every row")
     parser.add_argument("--chain", type=int, default=16,
                         help="op-chain length for --dispatch")
     parser.add_argument("--bulk", type=int, default=16,
@@ -524,6 +780,29 @@ def main():
         if rows and rows[0]["interpret"]:
             print("timed in the Pallas INTERPRETER (no TPU here): not a "
                   "hardware speed claim")
+        return
+
+    if args.embedding_grad_sweep:
+        os.makedirs(args.embedding_grad_sweep, exist_ok=True)
+        rows = sweep_embedding_grad(runs=args.runs, warmup=args.warmup,
+                                    text_dir=args.embedding_grad_sweep)
+        with open(os.path.join(args.embedding_grad_sweep,
+                               "embedding_grad_sweep.json"), "w") as f:
+            json.dump(rows, f, indent=1)
+        print(f"{'Case':<24s} {'Rows':>6s} {'Table':<14s} {'Form':<17s} "
+              f"{'Device ms':>10s} {'Wall ms':>9s} {'Emitter':<11s} "
+              f"{'Error at id 0':>13s}")
+        for r in rows:
+            print(f"{r['case']:<24s} {r['rows']:>6d} "
+                  f"{'{} x {}'.format(*r['table']):<14s} {r['form']:<17s} "
+                  f"{r.get('device_ms', '-'):>10} {r.get('wall_ms', '-'):>9} "
+                  f"{r.get('emitter') or '-':<11s} "
+                  f"{r.get('id0_err', float('nan')):>13.4g}"
+                  + (" <- the shape's" if r["chosen"] else "")
+                  + ("  " + r["error"][-120:] if "error" in r else ""))
+        if rows and not rows[0]["on_tpu"]:
+            print("timed on the CPU (no TPU here): not a hardware speed "
+                  "claim")
         return
 
     if args.kernels:

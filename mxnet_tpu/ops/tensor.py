@@ -12,6 +12,9 @@ scatters use XLA's native gather/scatter (no hand-written kernels).
 """
 from __future__ import annotations
 
+import functools as _functools
+import math as _math
+
 import jax
 import jax.numpy as jnp
 import numpy as _np
@@ -179,10 +182,95 @@ def _scatter_nd(data, indices, shape=()):
     return out.at[idx].add(data)
 
 
+def embedding_grad_columns(rows, vocab, width):
+    """How many columns of the table ``Embedding``'s backward scatters at a
+    time, from the shape alone: ``rows`` ids into a ``vocab`` x ``width``
+    table. 512 where XLA would sort the ids and walk a table wider than
+    1,024; the whole width elsewhere (one scatter, as ``jnp.take``'s
+    transpose is written).
+
+    Why (a TPU v5e, libtpu 0.0.34; device ms of the table's cotangent
+    alone in a jit, Zipf(1) ids; my chip runs, PR 32; ``benchmark/opperf.py
+    --embedding-grad-sweep``). XLA has two scatter emitters and picks by
+    ``vocab < 8 * rows`` (found by compiling; 3,200 rows into 25,008 walk,
+    2,048 do not). Under it it sorts the ids and WALKS the table: the time
+    follows the table's rows, not the ids (4,096 rows into 25,008 x 2,560:
+    9.79; unique ids 9.83; into 12,504 rows 5.07; float32 9.93; added to a
+    tied head's dW 10.15), and the width decides the rate: 4,096 rows into
+    25,008 x 256 / 512 / 768 / 1,024 / 1,280 / 1,536 / 2,048 / 2,304 /
+    **2,560** / 3,072 / 4,096 / **5,120**: 0.10 / 0.17 / 0.27 / 0.40 / 0.59 /
+    1.02 / 1.38 / 2.53 / **9.79** / 2.05 / 1.52 / **43.7**. In blocks of 512
+    columns the same tables take 0.27 (768) / 0.36 / 0.46 / 0.53 / 0.71 /
+    0.81 / **0.89** / 1.06 / 1.41 / **1.77**: never slower from 1,024 on;
+    blocks of 256 / 1,024 at 2,560: 1.01 / 1.11. The cells: the hybrid
+    decoder (4,096 into 25,008 x 2,560) 9.79 whole, **0.89** in blocks; the
+    language model (8,192 into 16,032 x 2,048) 1.27, **0.92**; BERT's words
+    (12,288 into 30,522 x 768) **0.55**, 0.61. Otherwise XLA updates the
+    table ROW BY ROW, in place, for 0.12-0.25 us a row, and blocks only
+    repeat that: 4,096 into 200,064 x 2,560 **2.60** whole (1.55 of it the
+    zeros), 6.80 in blocks; 8,192 into 128,256 x 2,048 **2.65**, 5.71; 1,024 /
+    2,048 rows into 25,008 x 2,560 **0.46** / **0.72**, 0.53 / 0.82; BERT's
+    type table (12,288 into 2 x 768) 0.25, 0.32. A batch of 32,768 tokens
+    makes XLA walk the published table: 78.7 whole, **10.2** in blocks.
+
+    The values are the scatter's own either way (a block sums the same
+    rows in the same order). The walking emitter sums a run of duplicates
+    in float32 (error at id 0's row 0.21 against a float64 sum, the same
+    as a float32 product's); the row-by-row emitter accumulates in the
+    table's dtype (2.6 at 200,064 rows, PERF.md section 7).
+
+    What lost at the hybrid decoder's shape: ``one_hot(ids)^T @ cot`` 2.73
+    (2.84 and 2.98 at the other two cells, 22.6 at 200,064 rows, bfloat16
+    products on a float32 table); ids sorted and duplicates summed by an
+    (N, N) product before a unique scatter 10.36 (the walk remains); the
+    table in 2 / 4 blocks of ROWS 10.41 / 11.05 (each block is walked at the
+    same rate); that dedup and 8 scatters of 512 ids each, which XLA runs
+    row by row, 1.68."""
+    return 512 if width > 1024 and vocab < 8 * rows else width
+
+
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _embedding_lookup(weight, ids, vocab):
+    return jnp.take(weight, ids, axis=0)
+
+
+def _embedding_lookup_fwd(weight, ids, vocab):
+    return jnp.take(weight, ids, axis=0), ids
+
+
+def _embedding_grad(ids, cot, vocab, columns):
+    """The cotangent of the table under ``jnp.take(table, ids, axis=0)``:
+    the transpose jax writes (one XLA scatter-add into zeros), taken over
+    blocks of ``columns`` columns, each scattered on its own. Every
+    element sums the same rows in the same order whatever ``columns`` is."""
+    row = cot.shape[ids.ndim:]
+    flat = cot.reshape(ids.shape + (-1,))
+
+    def scatter(block):
+        table = jax.ShapeDtypeStruct((vocab, block.shape[-1]), cot.dtype)
+        return jax.linear_transpose(
+            lambda w: jnp.take(w, ids, axis=0), table)(block)[0]
+
+    if columns >= flat.shape[-1]:
+        return scatter(flat).reshape((vocab,) + row)
+    parts = [scatter(flat[..., lo:lo + columns])
+             for lo in range(0, flat.shape[-1], columns)]
+    return jnp.concatenate(parts, axis=1).reshape((vocab,) + row)
+
+
+def _embedding_lookup_bwd(vocab, ids, cot):
+    width = _math.prod(cot.shape[ids.ndim:])
+    columns = embedding_grad_columns(ids.size, vocab, width)
+    return _embedding_grad(ids, cot, vocab, columns), None
+
+
+_embedding_lookup.defvjp(_embedding_lookup_fwd, _embedding_lookup_bwd)
+
+
 @register("Embedding")
 def _embedding(data, weight, input_dim=None, output_dim=None, dtype="float32",
                sparse_grad=False):
-    return jnp.take(weight, data.astype(jnp.int32), axis=0)
+    return _embedding_lookup(weight, data.astype(jnp.int32), weight.shape[0])
 
 
 @register("one_hot")

@@ -358,3 +358,50 @@ def test_zero_gathers_the_bf16_parameter_not_its_master(v5e, quiet_cache,
     gathered = re.findall(r"= (\w+)\[1024,1024\]\S* all-gather(?:-start)?\(",
                           text)
     assert gathered and set(gathered) == {"bf16"}, gathered
+
+
+@pytest.mark.parametrize("rows,vocab,width,columns", [
+    (4096, 25008, 2560, 512),
+    (8192, 16032, 2048, 512),
+    (12288, 30522, 768, 768),
+    (4096, 200064, 2560, 2560),
+], ids=["phi4_flash", "kanana2", "bert_words", "phi4_flash_published"])
+def test_embedding_gradient_compiles_in_the_blocks_its_shape_picks(
+        one_chip, quiet_cache, rows, vocab, width, columns):
+    """The cotangent of ``Embedding``'s bfloat16 table at the three text
+    cells' shapes and at the hybrid decoder's published vocabulary, added
+    to a head's dW as under a tied table. Where the rule says blocks no
+    scatter is left over the whole table (XLA's sort-and-walk emitter took
+    9.8 ms over ``bf16[25008,2560]`` and takes 0.13 ms over each
+    ``bf16[25008,512]``; my chip run, PR 32); where it says whole, XLA's one
+    scatter stands, and at 200,064 rows that is the row-by-row emitter (no
+    sorted ids), which does not walk the table; the program fits the
+    chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import tensor
+
+    assert tensor.embedding_grad_columns(rows, vocab, width) == columns
+
+    def tied(dw, weight, ids, cot):
+        _, pull = jax.vjp(lambda w: tensor._embedding(ids, w), weight)
+        return dw + pull(cot)[0]
+
+    table = _shape((vocab, width), jnp.bfloat16, one_chip)
+    compiled = jax.jit(tied, donate_argnums=0).lower(
+        table, table, _shape((rows,), jnp.int32, one_chip),
+        _shape((rows, width), jnp.bfloat16, one_chip)).compile()
+    text = compiled.as_text()
+    scatters = re.findall(r"= bf16\[(\d+),(\d+)\]\S* scatter\(([^\n]*)",
+                          text)
+    assert [(int(v), int(w)) for v, w, _ in scatters] \
+        == [(vocab, columns)] * (width // columns), scatters
+    walks = ["indices_are_sorted=true" in rest for _, _, rest in scatters]
+    assert walks == [vocab < 8 * rows] * len(scatters), walks
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes \
+        < 15.75 * 2 ** 30
