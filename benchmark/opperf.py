@@ -27,6 +27,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import sys
 import time
 
@@ -701,6 +702,153 @@ def sweep_embedding_grad(runs=10, warmup=3, cases=None, seed=32,
     return rows_out
 
 
+# Dropout where the cells run it: after a Dense, before a residual add and
+# a row mean (BERT's attention output and ``ffn2``: 12,288 rows of 768 out
+# of 768 and of 3,072, p 0.1), and the vision heads' (128, 4096) at p 0.5.
+# (label, rows, in_units, units, p)
+_DROPOUT_SWEEP = [
+    ("bert_attn_out", 12288, 768, 768, 0.1),
+    ("bert_ffn2", 12288, 3072, 768, 0.1),
+    ("vision_head", 128, 4096, 4096, 0.5),
+]
+
+
+def _dropout_forms():
+    """``{name: f(x, key, p)}``: the op as it is (``generator``: the words
+    of XLA's bit generator, drawn once a call) and the two forms that lost
+    (my chip runs, PR 33): the threefry ``bernoulli`` the op compared
+    before, whose hash XLA copies into every fusion that wants the mask,
+    and the same threefry mask drawn once, held as the residual of a
+    ``custom_vjp`` behind an ``optimization_barrier``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn
+
+    def masked(x, mask, p):
+        return jnp.where(mask, x / (1.0 - p), jnp.zeros((), x.dtype))
+
+    def threefry(x, key, p):
+        return masked(x, jax.random.bernoulli(key, 1.0 - p, x.shape), p)
+
+    @jax.custom_vjp
+    def held(x, key, p):
+        return held_fwd(x, key, p)[0]
+
+    def held_fwd(x, key, p):
+        mask = jax.lax.optimization_barrier(
+            jax.random.bernoulli(key, 1.0 - p, x.shape))
+        return masked(x, mask, p), (mask, p)
+
+    def held_bwd(res, cot):
+        mask, p = res
+        return masked(cot, mask, p), None, None
+
+    held.defvjp(held_fwd, held_bwd)
+    return {"threefry": threefry,
+            "generator": lambda x, key, p: nn._dropout(x, key, p=p),
+            "threefry_once": held}
+
+
+def sweep_dropout(runs=10, warmup=3, cases=None, dtype="bfloat16",
+                  text_dir=None):
+    """Time forward + backward of ``res + Dropout(x @ w + b)`` under a sum
+    of squared row means (LayerNorm's first reduction), alone in a jit (the
+    loss and the gradients of x, w and b), in every form of
+    ``_dropout_forms`` at the shapes of ``cases`` (``_DROPOUT_SWEEP``):
+    ``device_ms`` a call over all its device operations with the largest
+    three beside it, ``wall_ms`` by the host's clock. From the compiled
+    text: ``generator_ops`` counts ``rng-bit-generator`` ops, ``hashes``
+    how many times the threefry hash is evaluated over the activation
+    (``dropout_program_counts``) and ``hash_in_product`` whether a fused
+    computation holds both a ``convolution`` and the hash. The compiled text
+    of every row goes to ``text_dir``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import kernels as klayer
+
+    forms = _dropout_forms()
+    rows_out = []
+    for label, rows, in_units, units, p in cases or _DROPOUT_SWEEP:
+        r = np.random.default_rng(0)
+        x = jnp.asarray(r.standard_normal((rows, in_units),
+                                          dtype=np.float32), dtype)
+        w = jnp.asarray(r.standard_normal((in_units, units),
+                                          dtype=np.float32) * 0.02, dtype)
+        b = jnp.zeros((units,), dtype)
+        res = jnp.asarray(r.standard_normal((rows, units),
+                                            dtype=np.float32), dtype)
+        key = jax.random.PRNGKey(0)
+        for name, form in forms.items():
+            def loss(x, w, b, res, key, _f=form, _p=p):
+                y = res + _f(x @ w + b, key, _p)
+                return jnp.sum(jnp.square(
+                    jnp.mean(y.astype(jnp.float32), axis=-1)))
+
+            fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+            args = (x, w, b, res, key)
+            row = {"case": label, "rows": rows, "in_units": in_units,
+                   "units": units, "p": p, "dtype": dtype, "form": name,
+                   "chosen": name == "generator", "on_tpu": klayer.on_tpu()}
+            try:
+                text = fn.lower(*args).compile().as_text()
+                if text_dir:
+                    with open(os.path.join(
+                            text_dir, f"{label}.{name}.hlo.txt"), "w") as f:
+                        f.write(text)
+                row.update(dropout_program_counts(text, (rows, units)))
+                row["wall_ms"] = round(_time_jitted(fn, args, runs, warmup),
+                                       4)
+                ops = _device_ops(fn, args, runs)
+                if ops:
+                    row["device_ms"] = round(sum(ops.values()), 4)
+                    row["largest_ops"] = [
+                        [n, round(t, 4)] for n, t in sorted(
+                            ops.items(), key=lambda kv: -kv[1])[:3]]
+            except Exception as e:  # the compiler's refusal is the row
+                row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+            rows_out.append(row)
+    return rows_out
+
+
+def dropout_program_counts(text, shape):
+    """What a compiled program's text says of the Dropout masks over
+    activations of ``shape``: ``generator_ops`` (``rng-bit-generator``
+    ops), ``hashes`` (evaluations of the threefry hash over the
+    activation: the ``xor`` ops with a ``u32`` result of its size / 21,
+    twenty rounds and the two words' ``xor``) and ``hash_in_product`` (a
+    fused computation holds a ``convolution`` and such an ``xor``)."""
+    import re
+
+    n = int(np.prod(shape))
+    xor = re.compile(r"= u32\[([0-9,]+)\]\S* xor\(")
+
+    def hash_xors(block):
+        return sum(1 for dims in xor.findall(block)
+                   if int(np.prod([int(d) for d in dims.split(",")])) == n)
+
+    # a product's fusion holds the mask's producer as a nested fusion it
+    # ``calls``: follow those
+    bodies = {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \(.*?^}", text, re.MULTILINE | re.DOTALL)}
+    hashed = {}
+
+    def holds_hash(name):
+        if name not in hashed:
+            hashed[name] = False        # a computation does not call itself
+            body = bodies.get(name, "")
+            hashed[name] = bool(hash_xors(body)) or any(
+                holds_hash(callee)
+                for callee in re.findall(r"calls=%([\w.\-]+)", body))
+        return hashed[name]
+
+    return {
+        "generator_ops": len(re.findall(r" rng-bit-generator\(", text)),
+        "hashes": round(hash_xors(text) / 21, 2),
+        "hash_in_product": any(
+            " convolution(" in body and holds_hash(name)
+            for name, body in bodies.items() if not body.startswith("ENTRY"))}
+
+
 def run_benchmark(ops, size=_DEFAULT_SIZE, runs=10, warmup=2):
     results = []
     for name in ops:
@@ -745,6 +893,14 @@ def main():
                              "the table and keep it as "
                              "DIR/embedding_grad_sweep.json beside the "
                              "compiled text of every row")
+    parser.add_argument("--dropout-sweep", type=str, default="",
+                        metavar="DIR",
+                        help="time Dense -> Dropout -> residual -> mean, "
+                             "forward + backward, in every form of "
+                             "_dropout_forms at the shapes of "
+                             "_DROPOUT_SWEEP, print the table and keep it "
+                             "as DIR/dropout_sweep.json beside the compiled "
+                             "text of every row")
     parser.add_argument("--chain", type=int, default=16,
                         help="op-chain length for --dispatch")
     parser.add_argument("--bulk", type=int, default=16,
@@ -799,6 +955,31 @@ def main():
                   f"{r.get('emitter') or '-':<11s} "
                   f"{r.get('id0_err', float('nan')):>13.4g}"
                   + (" <- the shape's" if r["chosen"] else "")
+                  + ("  " + r["error"][-120:] if "error" in r else ""))
+        if rows and not rows[0]["on_tpu"]:
+            print("timed on the CPU (no TPU here): not a hardware speed "
+                  "claim")
+        return
+
+    if args.dropout_sweep:
+        os.makedirs(args.dropout_sweep, exist_ok=True)
+        rows = sweep_dropout(runs=args.runs, warmup=args.warmup,
+                             text_dir=args.dropout_sweep)
+        with open(os.path.join(args.dropout_sweep,
+                               "dropout_sweep.json"), "w") as f:
+            json.dump(rows, f, indent=1)
+        print(f"{'Case':<14s} {'Product':<20s} {'p':>4s} {'Form':<14s} "
+              f"{'Device ms':>10s} {'Wall ms':>9s} {'Generator':>9s} "
+              f"{'Hashes':>7s} {'In product':>10s}")
+        for r in rows:
+            product = f"{r['rows']} x {r['in_units']} x {r['units']}"
+            print(f"{r['case']:<14s} {product:<20s} {r['p']:>4} "
+                  f"{r['form']:<14s} {r.get('device_ms', '-'):>10} "
+                  f"{r.get('wall_ms', '-'):>9} "
+                  f"{r.get('generator_ops', '-'):>9} "
+                  f"{r.get('hashes', '-'):>7} "
+                  f"{str(r.get('hash_in_product', '-')):>10}"
+                  + (" <- the op's" if r["chosen"] else "")
                   + ("  " + r["error"][-120:] if "error" in r else ""))
         if rows and not rows[0]["on_tpu"]:
             print("timed on the CPU (no TPU here): not a hardware speed "

@@ -143,7 +143,11 @@ class Dense(HybridBlock):
 
 class Dropout(HybridBlock):
     """parity: basic_layers.py:262 — active only in train_mode (autograd
-    training flag), scaled at train time."""
+    training flag), scaled at train time. Every call draws one key from the
+    global stream (`random.next_key()`; inside a compiled graph the scope's)
+    and the op draws the mask once from XLA's bit generator seeded by it:
+    reproducible on one backend from `mx.random.seed`, not the same stream
+    on CPU and TPU."""
 
     def __init__(self, rate, axes=(), prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)

@@ -427,14 +427,65 @@ def _dropout(data, key=None, p=0.5, mode="training", axes=(), training=True,
              cudnn_off=False):
     """parity: src/operator/nn/dropout-inl.h. `key` is a uint32 PRNG key array
     threaded by the caller (imperative: global generator; hybridized: per-call
-    key input). Identity when not training or key is None."""
+    key input). Identity when not training or key is None.
+
+    An independent Bernoulli(1 - p) mask (one draw shared along ``axes``),
+    kept values divided by ``keep`` in the input's type, zeros elsewhere;
+    the backward applies the forward's mask to the cotangent. The mask's
+    words are drawn ONCE a call from XLA's bit generator
+    (``jax.lax.rng_bit_generator``, the backend's default algorithm) seeded
+    by ``key``, and an element is kept iff its word is under ``keep *
+    2**32``: the keep probability is exact to 2**-32. Same key, same mask,
+    eagerly, under ``jit``, ``lax.scan`` and any mesh layout; reproducible
+    on one backend from ``mx.random.seed``; NOT the same stream on CPU and
+    TPU (the default algorithm is the backend's; the reference's Dropout
+    differed between cuDNN and the CPU too). Under ``vmap`` over keys the
+    generator is seeded by the first key and draws the whole batch, as
+    jax's own ``rbg`` keys do. Under a ``dp`` mesh every chip draws the
+    whole mask and slices its rows (compiled for a described v5e:2x2, PR
+    33): the same mask in every layout, not a sharded draw.
+
+    Why not ``jax.random.bernoulli``: to XLA a threefry mask is a cheap
+    elementwise function of an ``iota`` and the key (126 integer vector
+    operations an element), so it never stores it and copies the hash into
+    every fusion that wants the mask, the matmuls' among them: 104
+    evaluations over ``u32[32,384,768]`` in ``bert_base``'s step for 25
+    Dropout calls. The TPU compiler does not expand ``rng-bit-generator``,
+    so it cannot copy it: one op a call, the mask packed to a bit an
+    element (``u32[384,768]``) and read by every consumer.
+
+    Device ms from a trace, forward + backward of ``res + Dropout(x @ w +
+    b)`` under a sum of squared row means, alone in a jit, bfloat16
+    (``benchmark/opperf.py --dropout-sweep``; my chip run, PR 33; TPU v5e),
+    as threefry ``bernoulli`` / this form / threefry drawn once and held as
+    the residual of a ``custom_vjp`` behind an ``optimization_barrier``:
+
+    ====================  =====  ========  =========  =============
+    rows x in x units     p      threefry  generator  threefry once
+    ====================  =====  ========  =========  =============
+    12,288 x 768 x 768    0.1    1.0792    0.3278     0.4925
+    12,288 x 3,072 x 768  0.1    1.7335    1.0766     1.1991
+    128 x 4,096 x 4,096   0.5    0.1342    0.1032     0.1142
+    ====================  =====  ========  =========  =============
+
+    The forward product with its Dropout epilogue: 0.2809 / 0.0912 / 0.0912
+    ms at 768 x 768 and 0.4909 / 0.3221 / 0.3073 at 3,072 x 768 (0.294 is
+    the bf16 peak's); the held mask's own fusion (``pred[12288,768]``)
+    0.2089 ms, the generator op 0.0125. In ``bert_base_train_s384`` (same
+    run): 310.4-312.4 / 388.7-391.6 / 364.6-367.0 samples/s, device busy
+    97.14 / 76.59 / 81.67 ms a step: the generator won and is the one form.
+    """
     if not training or key is None or p <= 0:
         return data
     shape = list(data.shape)
     for a in axes or ():
         shape[a] = 1
     keep = 1.0 - p
-    mask = jax.random.bernoulli(key, keep, shape=tuple(shape))
+    # the generator's key is 128 bits wide: the call's 64 twice, as jax's own
+    # "rbg" keys are seeded
+    _, words = jax.lax.rng_bit_generator(
+        jnp.concatenate([key, key]), tuple(shape), jnp.uint32)
+    mask = words < _np.uint32(min(round(keep * 2 ** 32), 2 ** 32 - 1))
     return jnp.where(mask, data / keep, jnp.zeros((), data.dtype))
 
 

@@ -9,6 +9,13 @@ TPU-native: JAX PRNG is functional (threefry keys). This module owns the
 op draws `next_key()`; hybridized graphs receive a key as an extra traced
 input so the compiled executable stays pure. `seed()` resets the stream
 (optionally per-context, matching `mx.random.seed(..., ctx=...)`).
+
+The keys are threefry keys (``uint32[2]``) and the sampling ops draw with
+threefry, the same values on every backend. `Dropout` alone takes its
+mask's words from XLA's bit generator (`jax.lax.rng_bit_generator`, the
+backend's default algorithm) seeded by the key it is handed: reproducible
+on one backend from `mx.random.seed`, NOT the same stream on CPU and TPU
+(the reference's Dropout differed between cuDNN and the CPU too).
 """
 from __future__ import annotations
 

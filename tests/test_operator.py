@@ -257,6 +257,155 @@ def test_dropout_modes():
     assert 0.3 < frac < 0.7
 
 
+def _dropout_raw(x, key, **kw):
+    """The op's own function over jax arrays (what a compiled graph
+    traces), training on."""
+    from mxnet_tpu.ops import registry
+
+    return registry.get("Dropout").fn(x, key, training=True, **kw)
+
+
+@pytest.mark.parametrize("p", [0.1, 0.5, 0.9])
+def test_dropout_keeps_its_share(p):
+    """Bernoulli(1 - p) an element: the kept share of 1e6 elements lies
+    within four binomial standard deviations of ``keep``."""
+    import jax
+    import jax.numpy as jnp
+
+    n, keep = 10 ** 6, 1.0 - p
+    out = _dropout_raw(jnp.ones((1000, 1000), jnp.float32),
+                       jax.random.PRNGKey(7), p=p)
+    kept = int((np.asarray(out) != 0).sum())
+    assert abs(kept - n * keep) < 4 * np.sqrt(n * keep * p), kept
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dropout_kept_values_are_x_over_keep(dtype):
+    """Kept values are ``x / keep`` computed in the input's type, the rest
+    exact zeros, and the result keeps the type."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (64, 96), dtype=np.float32) + 3.0, dtype)
+    out = _dropout_raw(x, jax.random.PRNGKey(1), p=0.1)
+    assert out.dtype == x.dtype
+    want = np.asarray((x / 0.9).astype(jnp.float32))
+    got = np.asarray(out.astype(jnp.float32))
+    kept = got != 0
+    assert 0.8 < kept.mean() < 0.97
+    np.testing.assert_array_equal(got[kept], want[kept])
+
+
+def test_dropout_backward_uses_the_forward_mask():
+    """The cotangent is ``1 / keep`` where the output was kept and zero
+    exactly where it was dropped."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.full((128, 64), 2.0, jnp.float32)
+    key = jax.random.PRNGKey(3)
+    out, pull = jax.vjp(lambda t: _dropout_raw(t, key, p=0.25), x)
+    grad, = pull(jnp.ones_like(out))
+    kept = np.asarray(out) != 0
+    np.testing.assert_array_equal(np.asarray(grad) != 0, kept)
+    np.testing.assert_array_equal(np.asarray(grad)[kept],
+                                  np.float32(1.0) / np.float32(0.75))
+    # and through the imperative front end's tape
+    xs = mx.nd.ones((64, 64)) * 2
+    xs.attach_grad()
+    with ag.record():
+        ys = mx.nd.Dropout(xs, p=0.5)
+    ys.backward()
+    np.testing.assert_array_equal(xs.grad.asnumpy() == 0, ys.asnumpy() == 0)
+    assert set(np.unique(xs.grad.asnumpy())) == {0.0, 2.0}
+
+
+def test_dropout_same_key_same_mask_another_key_another():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((256, 256), jnp.float32)
+    a = np.asarray(_dropout_raw(x, jax.random.PRNGKey(5), p=0.5))
+    b = np.asarray(_dropout_raw(x, jax.random.PRNGKey(5), p=0.5))
+    c = np.asarray(_dropout_raw(x, jax.random.PRNGKey(6), p=0.5))
+    np.testing.assert_array_equal(a, b)
+    assert 0.4 < ((a != 0) != (c != 0)).mean() < 0.6
+    # reproducible from the global seed
+    outs = []
+    for _ in range(2):
+        mx.random.seed(11)
+        with ag.train_mode():
+            outs.append(mx.nd.Dropout(mx.nd.ones((32, 32)), p=0.5).asnumpy())
+    np.testing.assert_array_equal(*outs)
+
+
+def test_dropout_axes_broadcast_one_mask():
+    """``axes=(1,)``: one draw a (row, column), shared along axis 1."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((64, 16, 32), jnp.float32)
+    out = np.asarray(_dropout_raw(x, jax.random.PRNGKey(2), p=0.5,
+                                  axes=(1,)))
+    assert (out == out[:, :1, :]).all()
+    assert 0.4 < (out[:, 0, :] != 0).mean() < 0.6
+
+
+@pytest.mark.parametrize("case", ["not_training", "p_zero", "no_key"])
+def test_dropout_is_the_identity(case):
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import registry
+
+    fn = registry.get("Dropout").fn
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (8, 8), dtype=np.float32))
+    key = jax.random.PRNGKey(0)
+    out = {"not_training": lambda: fn(x, key, p=0.5, training=False),
+           "p_zero": lambda: fn(x, key, p=0.0, training=True),
+           "no_key": lambda: fn(x, None, p=0.5, training=True)}[case]()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+
+
+@pytest.mark.parametrize("how", ["jit", "scan"])
+def test_dropout_mask_is_the_eager_one(how):
+    """A key gives the mask it gives eagerly under ``jit`` and for a
+    microbatch of a ``lax.scan`` (gradient accumulation draws one key a
+    microbatch)."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((4, 48, 64), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(9), 4)
+    eager = np.stack([np.asarray(_dropout_raw(x[i], keys[i], p=0.3))
+                      for i in range(4)])
+    if how == "jit":
+        got = np.stack([np.asarray(jax.jit(
+            lambda t, k: _dropout_raw(t, k, p=0.3))(x[i], keys[i]))
+            for i in range(4)])
+    else:
+        _, got = jax.lax.scan(
+            lambda c, tk: (c, _dropout_raw(tk[0], tk[1], p=0.3)), 0,
+            (x, keys))
+    np.testing.assert_array_equal(np.asarray(got), eager)
+    assert not (eager[0] == eager[1]).all()
+
+
+def test_dropout_vmap_over_keys_runs():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((3, 32, 32), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    out = np.asarray(jax.vmap(
+        lambda t, k: _dropout_raw(t, k, p=0.5))(x, keys))
+    assert out.shape == (3, 32, 32)
+    assert set(np.unique(out)) == {0.0, 2.0}
+    assert 0.4 < (out != 0).mean() < 0.6
+    assert not (out[0] == out[1]).all()
+
+
 def test_random_ops():
     mx.random.seed(42)
     u = mx.nd.random.uniform(0.0, 1.0, shape=(1000,))

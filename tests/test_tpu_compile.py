@@ -275,29 +275,38 @@ def test_grouped_windowed_flash_compiles_at_the_cells_buckets(
         == [(t.shape, t.dtype) for t in (cot, q, k, v)]
 
 
-def _bf16_step_text(monkeypatch, devices, width, optimizer, params, **kw):
-    """The optimized HLO of a ``ShardedTrainer`` step over two bias-free
-    ``width`` x ``width`` bfloat16 layers with float32 masters, compiled
-    for the described ``devices`` (nothing can be put on those, so the
-    trainer's own placement is skipped, as ``rehearse_compile.py`` does)."""
+def _step_text(monkeypatch, devices, net, batch, optimizer, params, **kw):
+    """The optimized HLO of a ``ShardedTrainer`` step over ``net`` in
+    bfloat16 with float32 masters under an L2 loss against a batch of its
+    input's shape, compiled for the described ``devices`` (nothing can be
+    put on those, so the trainer's own placement is skipped, as
+    ``rehearse_compile.py`` does)."""
     import jax
     import jax.numpy as jnp
 
     import mxnet_tpu as mx
-    from mxnet_tpu.gluon import loss as gloss, nn
+    from mxnet_tpu.gluon import loss as gloss
     from mxnet_tpu.parallel import DeviceMesh, ShardedTrainer
 
     monkeypatch.setattr(ShardedTrainer, "_place_params", lambda self: None)
-    net = nn.HybridSequential()
-    net.add(nn.Dense(width, in_units=width, use_bias=False),
-            nn.Dense(width, in_units=width, use_bias=False))
     net.initialize(mx.init.Zero())
     net.cast("bfloat16")
     trainer = ShardedTrainer(
         net, gloss.L2Loss(), optimizer, dict(params, multi_precision=True),
         mesh=DeviceMesh({"dp": len(devices)}, devices=devices), **kw)
-    batch = jax.ShapeDtypeStruct((8, width), jnp.bfloat16)
-    return trainer.aot_lower(batch, batch).compile().as_text()
+    x = jax.ShapeDtypeStruct(batch, jnp.bfloat16)
+    return trainer.aot_lower(x, x).compile().as_text()
+
+
+def _bf16_step_text(monkeypatch, devices, width, optimizer, params, **kw):
+    """``_step_text`` of two bias-free ``width`` x ``width`` layers."""
+    from mxnet_tpu.gluon import nn
+
+    net = nn.HybridSequential()
+    net.add(nn.Dense(width, in_units=width, use_bias=False),
+            nn.Dense(width, in_units=width, use_bias=False))
+    return _step_text(monkeypatch, devices, net, (8, width), optimizer,
+                      params, **kw)
 
 
 def _entry_fusions(text):
@@ -405,3 +414,55 @@ def test_embedding_gradient_compiles_in_the_blocks_its_shape_picks(
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
         + memory.output_size_in_bytes - memory.alias_size_in_bytes \
         < 15.75 * 2 ** 30
+
+
+def _dropout_block_step_text(monkeypatch, devices, batch, width, hidden):
+    """``_step_text`` under Adam of a BERT-shaped block: twice ``Dense``
+    (GELU), ``Dense``, ``Dropout(0.1)``, the residual add, ``LayerNorm``."""
+    from mxnet_tpu.gluon import nn
+
+    class Block(nn.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                for i in range(2):
+                    setattr(self, f"ffn1_{i}", nn.Dense(
+                        hidden, in_units=width, flatten=False))
+                    setattr(self, f"ffn2_{i}", nn.Dense(
+                        width, in_units=hidden, flatten=False))
+                    setattr(self, f"drop_{i}", nn.Dropout(0.1))
+                    setattr(self, f"norm_{i}", nn.LayerNorm(
+                        in_channels=width))
+
+        def hybrid_forward(self, F, x):
+            for i in range(2):
+                h = F.invoke("LeakyReLU", getattr(self, f"ffn1_{i}")(x),
+                             act_type="gelu")
+                h = getattr(self, f"drop_{i}")(getattr(self, f"ffn2_{i}")(h))
+                x = getattr(self, f"norm_{i}")(x + h)
+            return x
+
+    return _step_text(monkeypatch, devices, Block(), batch + (width,),
+                      "adam", {"learning_rate": 1e-3})
+
+
+def test_dropout_mask_is_drawn_once_by_the_bit_generator(v5e, quiet_cache,
+                                                         monkeypatch):
+    """The mechanism's counter: generator ops a step = Dropout calls a step
+    (2 here, 25 in ``bert_base``'s). The mask's words come from XLA's
+    ``rng-bit-generator``, which the TPU compiler cannot copy into its
+    consumers; with ``jax.random.bernoulli`` the threefry hash over the
+    activation's ``u32`` shape (126 integer vector operations an element)
+    was evaluated inside every fusion that wanted the mask, the products'
+    among them: 104 times a step in BERT's, 2,184 ``xor`` ops (PR 33)."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "benchmark"))
+    import opperf
+
+    text = _dropout_block_step_text(monkeypatch, v5e.devices[:1], (32, 384),
+                                    768, 3072)
+    counts = opperf.dropout_program_counts(text, (32, 384, 768))
+    assert counts == {"generator_ops": 2, "hashes": 0,
+                      "hash_in_product": False}, counts
