@@ -64,6 +64,7 @@ import hashlib
 import json
 import os
 import pickle
+import re
 import threading
 import time
 import weakref
@@ -80,7 +81,8 @@ from .telemetry import trace as _trace
 __all__ = ["jit", "stats", "totals", "reset_stats", "set_enabled",
            "enabled", "configure", "cache_dir", "fingerprint", "warmup",
            "manifest", "save_manifest", "clear_manifest", "last_warmup",
-           "disk_report", "gc_cache", "clear_memory", "registered"]
+           "disk_report", "gc_cache", "clear_memory", "registered",
+           "program_texts"]
 
 ENV_DIR = "MXNET_TPU_CACHE_DIR"
 ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
@@ -402,6 +404,7 @@ def clear_memory():
     through the disk/compile path. Test seam for exercising persistence
     in-process."""
     with _lock:
+        _LOWERED.clear()
         for ref in list(_REGISTRY.values()):
             fn = ref()
             if fn is not None:
@@ -756,18 +759,25 @@ def _spec_args(node):
 
 
 def _record_manifest(token_key, site, args):
+    """Record one compiled signature; the new entry, or None where it
+    was recorded before, cannot be replayed, or the manifest is full."""
     spec = _spec_tree(args)
     if spec is None:
-        return
+        return None
     ident = (token_key, json.dumps(spec, sort_keys=True))
     with _lock:
-        if ident in _MANIFEST_SEEN or len(_MANIFEST) >= _MANIFEST_CAP:
-            return
+        # the cap bounds what the per-op sites can record; a big
+        # executable's few signatures always get in (program_texts
+        # replays them, and the per-op sites record first)
+        if ident in _MANIFEST_SEEN or (len(_MANIFEST) >= _MANIFEST_CAP
+                                       and site not in _XCOST_DEFAULT):
+            return None
         _MANIFEST_SEEN.add(ident)
         entry = {"site": site, "token": token_key, "args": spec}
         _MANIFEST.append(entry)
     if _DIR is not None:
         _append_manifest_file(entry)
+    return entry
 
 
 def _append_manifest_file(entry):
@@ -809,6 +819,9 @@ def clear_manifest():
     with _lock:
         _MANIFEST.clear()
         _MANIFEST_SEEN.clear()
+        _LOWERED.clear()
+        _PROGRAMS.clear()
+        _PROGRAM_LISTS.clear()
 
 
 def save_manifest(path):
@@ -900,6 +913,80 @@ def warmup(source=None):
         except OSError:
             pass
     return report
+
+
+# id(manifest entry) -> the ``Lowered`` a miss of a big-executable site
+# made for its cost analysis, until program_texts prints it. A STOPGAP for
+# a caller that reads its trace after it dropped its trainer
+# (chipbench/modes/train.py; ROADMAP A2 has what lets it go again). jit's
+# own caches hold the same lowering and its loaded executable while the
+# function lives, so a kept one costs nothing until its owner dies and
+# pins both after (a module in host memory, the program in the device's;
+# PERF.md section 6 has the sizes), nothing of the owner (no function, no
+# buffer). The last ``_LOWERED_CAP`` a process: a later miss, program_texts,
+# clear_manifest and clear_memory let go.
+_LOWERED = {}
+_LOWERED_CAP = 4
+_PROGRAMS = {}       # id(manifest entry) -> what program_texts printed
+_PROGRAM_LISTS = {}  # site -> the list program_texts last returned
+
+
+def _keep_lowered(entry, lowered):
+    with _lock:
+        _LOWERED[id(entry)] = lowered
+        for key in list(_LOWERED)[:-_LOWERED_CAP]:
+            del _LOWERED[key]
+
+
+def program_texts(site):
+    """The optimized HLO of what ``site`` ran: one ``{"token", "module",
+    "text"}`` for every signature of ``site`` in the manifest (``"trainer"``:
+    the compiled step) whose function is alive or left its lowering
+    behind (``_LOWERED`` above). ``module`` is the
+    ``HloModule``'s name, what a device trace's ``XLA Modules`` events are
+    named after (``jit_step_fn``); every instruction of ``text`` carries
+    ``metadata={op_name="jit(step_fn)/..."}``, where jax marks forward
+    (``jvp(``) and backward (``transpose(jvp(``) and the step its update
+    and its guard (``sharded_trainer.UPDATE_SCOPE`` / ``GUARD_SCOPE``): the
+    key to join a trace's instruction names with.
+
+    The executable itself is never in this module's hands (a donating step
+    runs through jit's own path), so the recorded specs are lowered again
+    through the live function, the way ``_warmup`` replays them, and
+    ``compile()`` hands back what jit made of that lowering (memoized
+    there; a load from jax's persistent cache where it is not). On demand
+    and only here; the ``Compiled`` is dropped uncalled and enters no
+    cache of this module, no counter moves, and a lowering that fails
+    raises. The names in an executable jax took from its persistent cache
+    are those of whichever tree compiled it first (jax's key leaves them
+    out): over a cache an older tree filled the text is that tree's.
+    Memoized: the same list until ``site`` records another signature."""
+    _ensure_configured()
+    with _lock:
+        entries = [e for e in _MANIFEST if e["site"] == site]
+    texts = []
+    for entry in entries:
+        held = _PROGRAMS.get(id(entry))
+        if held is None:
+            lowered = _LOWERED.pop(id(entry), None)
+            if lowered is None:
+                ref = _REGISTRY.get(entry["token"])
+                fn = ref() if ref is not None else None
+                if fn is None:
+                    continue
+                lowered = fn.lower(*_spec_args(entry["args"]))
+            text = lowered.compile().as_text()
+            module = re.match(r"HloModule\s+([^\s,]+)", text)
+            held = _PROGRAMS[id(entry)] = {
+                "token": entry["token"],
+                "module": module.group(1) if module else None, "text": text}
+        texts.append(held)
+    memo = _PROGRAM_LISTS.get(site)
+    if memo is not None and len(memo) == len(texts) \
+            and all(a is b for a, b in zip(memo, texts)):
+        return memo
+    _PROGRAM_LISTS[site] = texts
+    return texts
 
 
 # -------------------------------------------------- telemetry capture ------
@@ -1016,7 +1103,9 @@ class ServiceFunction:
         return self._miss(sig, args)
 
     def lower(self, *args, **kwargs):
-        """Pass-through to the wrapped jit's AOT lowering."""
+        """Pass-through to the wrapped jit's AOT lowering (no bookkeeping:
+        ``_warmup`` and :func:`program_texts` replay recorded specs
+        through it)."""
         return self._jit.lower(*args, **kwargs)
 
     def _miss(self, sig, args):
@@ -1069,15 +1158,18 @@ class ServiceFunction:
         st[3] += 1
         st[4] += ms
         self._seen[sig] = self._jit
-        _record_manifest(self._token_key, self._site, args)
+        entry = _record_manifest(self._token_key, self._site, args)
         if _xcost_wanted(self._site):
             # no Compiled object in hand on this path (the jit's own
             # executable is internal); one extra trace+lower buys the
-            # cost analysis — no XLA backend compile happens here
+            # cost analysis — no XLA backend compile happens here — and
+            # is what program_texts prints from once the owner is gone
             try:
+                lowered = self._jit.lower(*args)
                 _capture_analysis(self._site, self._token_key,
-                                  lowered=self._jit.lower(*args),
-                                  source="trace")
+                                  lowered=lowered, source="trace")
+                if entry is not None:
+                    _keep_lowered(entry, lowered)
             except Exception:
                 pass
         _profiler_compile(self._site, ms, "compile", st)
@@ -1106,7 +1198,7 @@ class ServiceFunction:
                 self._seen[sig] = loaded
                 return "disk"
         t0 = time.perf_counter()
-        compiled = self._jit.lower(*args).compile()
+        compiled = self.lower(*args).compile()
         ms = (time.perf_counter() - t0) * 1e3
         st[3] += 1
         st[4] += ms

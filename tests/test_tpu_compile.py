@@ -276,11 +276,16 @@ def test_grouped_windowed_flash_compiles_at_the_cells_buckets(
 
 
 def _step_text(monkeypatch, devices, net, batch, optimizer, params, **kw):
-    """The optimized HLO of a ``ShardedTrainer`` step over ``net`` in
-    bfloat16 with float32 masters under an L2 loss against a batch of its
-    input's shape, compiled for the described ``devices`` (nothing can be
-    put on those, so the trainer's own placement is skipped, as
-    ``rehearse_compile.py`` does)."""
+    """The optimized HLO of ``_step_lowered``'s step."""
+    return _step_lowered(monkeypatch, devices, net, batch, optimizer,
+                         params, **kw).compile().as_text()
+
+
+def _step_lowered(monkeypatch, devices, net, batch, optimizer, params, **kw):
+    """A ``ShardedTrainer`` step over ``net`` in bfloat16 with float32
+    masters under an L2 loss against a batch of its input's shape, lowered
+    for the described ``devices`` (nothing can be put on those, so the
+    trainer's own placement is skipped, as ``rehearse_compile.py`` does)."""
     import jax
     import jax.numpy as jnp
 
@@ -295,7 +300,7 @@ def _step_text(monkeypatch, devices, net, batch, optimizer, params, **kw):
         net, gloss.L2Loss(), optimizer, dict(params, multi_precision=True),
         mesh=DeviceMesh({"dp": len(devices)}, devices=devices), **kw)
     x = jax.ShapeDtypeStruct(batch, jnp.bfloat16)
-    return trainer.aot_lower(x, x).compile().as_text()
+    return trainer.aot_lower(x, x)
 
 
 def _bf16_step_text(monkeypatch, devices, width, optimizer, params, **kw):
@@ -466,3 +471,91 @@ def test_dropout_mask_is_drawn_once_by_the_bit_generator(v5e, quiet_cache,
     counts = opperf.dropout_program_counts(text, (32, 384, 768))
     assert counts == {"generator_ops": 2, "hashes": 0,
                       "hash_in_product": False}, counts
+
+
+def _named_instructions(text):
+    """``[(name, result dtypes, opcode, op_name)]`` of every instruction of
+    ``text`` that carries metadata."""
+    import re
+
+    out = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%(\S+) = (.+?) ([a-z][a-z\-]*)\(.*?"
+            r'metadata=\{op_name="([^"]*)"', text, re.MULTILINE):
+        out.append((m.group(1), re.findall(r"(\w+)\[[\d,]*\]", m.group(2)),
+                    m.group(3), m.group(4)))
+    return out
+
+
+def test_step_text_names_update_and_backward(v5e, quiet_cache, monkeypatch):
+    """What ``chipbench/harness/step_phases.py`` joins a trace to: in the
+    compiled bf16 step the update fusion of each parameter, results
+    ``(bf16, f32, f32, f32)``, carries ``trainer.update`` in its OWN
+    ``op_name``, each dW product ``transpose(jvp(`` and each forward
+    product ``jvp(`` without it; the guard's name is on no product."""
+    from mxnet_tpu.parallel import sharded_trainer
+
+    text = _bf16_step_text(monkeypatch, v5e.devices[:1], 4096, "adam",
+                           {"learning_rate": 1e-3})
+    entry = _named_instructions(text[text.index("ENTRY "):])
+    fusions = [i for i in entry if i[2] == "fusion"]
+    updates = [f for f in fusions
+               if sorted(f[1]) == ["bf16", "f32", "f32", "f32"]]
+    assert len(updates) == 2, updates
+    assert all(sharded_trainer.UPDATE_SCOPE in f[3] for f in updates)
+    products = [f for f in fusions if f[3].endswith("/dot_general")]
+    # two layers: a dW product each, the guard's reduction fused in (a
+    # ``pred`` beside the gradient), and a forward product each
+    gradients = [f for f in products if "pred" in f[1]]
+    forward = [f for f in products if "pred" not in f[1]]
+    assert len(gradients) == 2 and len(forward) == 2, products
+    assert all("transpose(jvp(" in f[3] for f in gradients), gradients
+    assert all("jvp(" in f[3] and "transpose(" not in f[3]
+               for f in forward), forward
+    assert not [f for f in products if "trainer." in f[3]], products
+    guards = [i for i in _named_instructions(text)
+              if i[2] == "is-finite"]
+    assert guards and all(sharded_trainer.GUARD_SCOPE in g[3]
+                          for g in guards)
+
+
+def test_step_scopes_leave_the_mosaic_calls_their_names(v5e, quiet_cache,
+                                                        monkeypatch):
+    """A Mosaic call's payload holds the kernel's name, the name comes from
+    the scope stack, and jax's cache key does not strip it: a scope AROUND
+    the forward would rename every Pallas call and compile the attention
+    cells cold. The step's two scopes sit after the gradient: the calls of
+    a latent-attention block are still ``jvp_mla.attention_*`` and
+    ``transpose_jvp_mla.attention_*`` with no ``trainer.`` in them, and the
+    lowered module, payloads and all, is the same without the two."""
+    import contextlib
+    import re
+
+    import jax
+
+    from mxnet_tpu import kernels
+    from mxnet_tpu.gluon import nn
+
+    def lowered():
+        net = nn.MLAttention(256, num_heads=2, kv_lora_rank=128,
+                             qk_nope_head_dim=128, qk_rope_head_dim=64,
+                             v_head_dim=128)
+        return _step_lowered(monkeypatch, v5e.devices[:1], net,
+                             (1, 1024, 256), "adam", {"learning_rate": 1e-3})
+
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    named = lowered()
+    text = named.compile().as_text()
+    calls = re.findall(r"%(\S+) = [^\n]*? custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) >= 2, calls
+    assert all(re.match(r"(transpose_)?jvp_mla\.attention_", c)
+               for c in calls), calls
+    assert any(c.startswith("transpose_jvp_") for c in calls), calls
+    assert not [c for c in calls if "trainer" in c], calls
+    assert "trainer.update" in text
+    scope = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+        if name.startswith("trainer.") else scope(name))
+    assert lowered().as_text() == named.as_text()
