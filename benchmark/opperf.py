@@ -25,6 +25,7 @@ analogue of the reference's MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN A/B.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import re
@@ -789,25 +790,61 @@ def sweep_dropout(runs=10, warmup=3, cases=None, dtype="bfloat16",
             row = {"case": label, "rows": rows, "in_units": in_units,
                    "units": units, "p": p, "dtype": dtype, "form": name,
                    "chosen": name == "generator", "on_tpu": klayer.on_tpu()}
-            try:
-                text = fn.lower(*args).compile().as_text()
-                if text_dir:
-                    with open(os.path.join(
-                            text_dir, f"{label}.{name}.hlo.txt"), "w") as f:
-                        f.write(text)
-                row.update(dropout_program_counts(text, (rows, units)))
-                row["wall_ms"] = round(_time_jitted(fn, args, runs, warmup),
-                                       4)
-                ops = _device_ops(fn, args, runs)
-                if ops:
-                    row["device_ms"] = round(sum(ops.values()), 4)
-                    row["largest_ops"] = [
-                        [n, round(t, 4)] for n, t in sorted(
-                            ops.items(), key=lambda kv: -kv[1])[:3]]
-            except Exception as e:  # the compiler's refusal is the row
-                row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+            _time_row(row, fn, args, runs, warmup,
+                      lambda text: dropout_program_counts(text,
+                                                          (rows, units)),
+                      text_dir and os.path.join(text_dir,
+                                                f"{label}.{name}.hlo.txt"))
             rows_out.append(row)
     return rows_out
+
+
+def _time_row(row, fn, args, runs, warmup, counts, text_path=None, largest=3):
+    """Fill ``row`` for the jitted ``fn(*args)``: what ``counts`` reads off
+    its compiled text (kept as ``text_path``), ``wall_ms`` a call by the
+    host's clock, ``device_ms`` over all its device operations from a trace
+    with the ``largest`` of them beside it; the compiler's refusal is the
+    row's ``error``."""
+    try:
+        text = fn.lower(*args).compile().as_text()
+        if text_path:
+            with open(text_path, "w") as f:
+                f.write(text)
+        row.update(counts(text))
+        row["wall_ms"] = round(_time_jitted(fn, args, runs, warmup), 4)
+        ops = _device_ops(fn, args, runs)
+        if ops:
+            row["device_ms"] = round(sum(ops.values()), 4)
+            row["largest_ops"] = [
+                [n, round(t, 4)] for n, t in sorted(
+                    ops.items(), key=lambda kv: -kv[1])[:largest]]
+    except Exception as e:
+        row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+
+
+def _computations(text):
+    """``{name: text}`` of a compiled program's computations; the entry's is
+    the one that starts with ``ENTRY``."""
+    return {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \(.*?^}", text, re.MULTILINE | re.DOTALL)}
+
+
+def _holds(bodies, found):
+    """``name -> bool``: ``found(body)`` of the computation ``name`` or of
+    any it ``calls`` (a product's fusion holds the producer of an operand
+    as a nested fusion)."""
+    memo = {}
+
+    def holds(name):
+        if name not in memo:
+            memo[name] = False          # a computation does not call itself
+            body = bodies.get(name, "")
+            memo[name] = bool(found(body)) or any(
+                holds(callee)
+                for callee in re.findall(r"calls=%([\w.\-]+)", body))
+        return memo[name]
+
+    return holds
 
 
 def dropout_program_counts(text, shape):
@@ -817,8 +854,6 @@ def dropout_program_counts(text, shape):
     activation: the ``xor`` ops with a ``u32`` result of its size / 21,
     twenty rounds and the two words' ``xor``) and ``hash_in_product`` (a
     fused computation holds a ``convolution`` and such an ``xor``)."""
-    import re
-
     n = int(np.prod(shape))
     xor = re.compile(r"= u32\[([0-9,]+)\]\S* xor\(")
 
@@ -826,27 +861,139 @@ def dropout_program_counts(text, shape):
         return sum(1 for dims in xor.findall(block)
                    if int(np.prod([int(d) for d in dims.split(",")])) == n)
 
-    # a product's fusion holds the mask's producer as a nested fusion it
-    # ``calls``: follow those
-    bodies = {m.group(1): m.group(0) for m in re.finditer(
-        r"^(?:ENTRY )?%(\S+) \(.*?^}", text, re.MULTILINE | re.DOTALL)}
-    hashed = {}
-
-    def holds_hash(name):
-        if name not in hashed:
-            hashed[name] = False        # a computation does not call itself
-            body = bodies.get(name, "")
-            hashed[name] = bool(hash_xors(body)) or any(
-                holds_hash(callee)
-                for callee in re.findall(r"calls=%([\w.\-]+)", body))
-        return hashed[name]
-
+    bodies = _computations(text)
+    holds_hash = _holds(bodies, hash_xors)
     return {
         "generator_ops": len(re.findall(r" rng-bit-generator\(", text)),
         "hashes": round(hash_xors(text) / 21, 2),
         "hash_in_product": any(
             " convolution(" in body and holds_hash(name)
             for name, body in bodies.items() if not body.startswith("ENTRY"))}
+
+
+# Exact GELU where BERT runs it: between ``ffn1`` (768 -> 3,072) and ``ffn2``
+# (3,072 -> 768) over a batch of 32 x 384. (label, batch, units, hidden)
+_GELU_SWEEP = [("bert_ffn", (32, 384), 768, 3072)]
+
+
+def _gelu_forms():
+    """``{name: f(x)}``: ``jax.nn.gelu(x, approximate=False)`` as the op
+    called it until PR 35 (XLA copies the ``erfc`` expansion into every
+    fusion that wants ``gelu(h)`` or its slope), the op as it is
+    (``kept_erfc``: ``erfc`` behind an ``optimization_barrier``, the value
+    rebuilt as ``0.5 * h * erfc`` wherever it is wanted, the backward reads
+    ``h`` and ``erfc``) and the form that lost on the chip (``ops/math.py``
+    ``exact_gelu`` holds the readings): the value kept behind the barrier
+    beside ``erfc``."""
+    import jax
+    from mxnet_tpu.ops import math as mops
+
+    @jax.custom_vjp
+    def kept_erfc_and_value(x):
+        return jax.nn.gelu(x, approximate=False)
+
+    def kept_erfc_and_value_fwd(x):
+        e = jax.lax.erfc(-x * np.sqrt(0.5).astype(x.dtype))
+        out, e = jax.lax.optimization_barrier((0.5 * x * e, e))
+        return out, (x, e)
+
+    kept_erfc_and_value.defvjp(kept_erfc_and_value_fwd, mops._exact_gelu_bwd)
+
+    return {"jax": lambda x: jax.nn.gelu(x, approximate=False),
+            "kept_erfc": mops.exact_gelu,
+            "kept_erfc_and_value": kept_erfc_and_value}
+
+
+def sweep_gelu(runs=10, warmup=3, cases=None, dtype="bfloat16",
+               text_dir=None):
+    """Time exact GELU where ``bert_base`` runs it, alone in a jit, at the
+    shapes of ``cases`` (``_GELU_SWEEP``), through the ops a layer calls
+    (``FullyConnected(flatten=False)``, ``LayerNorm``). ``product``:
+    ``ffn2(f(h))`` alone, forward only, with no activation (``none``) and
+    with ``jax.nn.gelu(h, approximate=False)`` on the operand. ``step``:
+    forward + backward of a layer's second half, ``LayerNorm(x +
+    ffn2(f(ffn1(x))))`` under a sum of squares (the loss and the gradients
+    of x, both layers' weights and biases, gamma and beta), in every form
+    of ``_gelu_forms``; it is the LayerNorm behind the residual that makes
+    XLA copy the ``erfc`` expansion into ``ffn2``'s product and its dW
+    (without it the compiler keeps ``gelu(h)`` itself). ``device_ms`` a
+    call over all its device operations with the largest six beside it,
+    ``wall_ms`` by the host's clock, ``exponentials`` and ``holders`` off
+    the compiled text (``gelu_program_counts``). The compiled text of every
+    row goes to ``text_dir``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import kernels as klayer
+    from mxnet_tpu.ops import nn
+
+    forms = _gelu_forms()
+    dense = functools.partial(nn._fully_connected, flatten=False)
+    rows_out = []
+    for label, batch, units, hidden in cases or _GELU_SWEEP:
+        r = np.random.default_rng(0)
+
+        def normal(*shape, scale=1.0):
+            return jnp.asarray(r.standard_normal(shape, dtype=np.float32)
+                               * scale, dtype)
+
+        x, h = normal(*batch, units), normal(*batch, hidden)
+        w1, w2 = normal(hidden, units, scale=0.02), \
+            normal(units, hidden, scale=0.02)
+        b1, b2 = jnp.zeros((hidden,), dtype), jnp.zeros((units,), dtype)
+        gamma, beta = jnp.ones((units,), dtype), jnp.zeros((units,), dtype)
+
+        def step(f, x, w1, b1, w2, b2, gamma, beta):
+            y = nn._layer_norm(x + dense(f(dense(x, w1, b1)), w2, b2),
+                               gamma, beta)
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+        timed = [("product", "none", jax.jit(dense), (h, w2, b2)),
+                 ("product", "jax", jax.jit(
+                     lambda h, w2, b2: dense(forms["jax"](h), w2, b2)),
+                  (h, w2, b2))]
+        timed += [("step", name, jax.jit(jax.value_and_grad(
+            functools.partial(step, form), argnums=tuple(range(7)))),
+            (x, w1, b1, w2, b2, gamma, beta)) for name, form in forms.items()]
+        for what, name, fn, args in timed:
+            row = {"case": label, "what": what, "batch": list(batch),
+                   "units": units, "hidden": hidden, "dtype": dtype,
+                   "form": name, "on_tpu": klayer.on_tpu(),
+                   "chosen": what == "step" and name == "kept_erfc"}
+            _time_row(row, fn, args, runs, warmup,
+                      lambda text: gelu_program_counts(
+                          text, tuple(batch) + (hidden,)),
+                      text_dir and os.path.join(
+                          text_dir, f"{label}.{what}.{name}.hlo.txt"),
+                      largest=6)
+            rows_out.append(row)
+    return rows_out
+
+
+def gelu_program_counts(text, shape):
+    """What a compiled program's text says of exact GELU over activations
+    of ``shape`` (any leading axes: 32 x 384 x 3,072 counts as 12,288 x
+    3,072): ``exponentials``, the ``exponential`` instructions with a
+    float32 result of that size (one in each copy of the ``erfc`` expansion
+    and one in the density of the slope: two a GELU call where each is
+    evaluated once), and ``holders``, the result types of the entry's
+    fusions that hold one, themselves or in a fusion they call."""
+    n = int(np.prod(shape))
+    exp = re.compile(r"= f32\[([0-9,]+)\]\S* exponential\(")
+
+    def exponentials(block):
+        found = ([int(d) for d in dims.split(",")]
+                 for dims in exp.findall(block))
+        return sum(1 for dims in found
+                   if dims[-1] == shape[-1] and int(np.prod(dims)) == n)
+
+    bodies = _computations(text)
+    holds = _holds(bodies, exponentials)
+    entry = next(b for b in bodies.values() if b.startswith("ENTRY"))
+    holders = [", ".join(re.findall(r"\w+\[[\d,]*\]", m.group(1)))
+               for m in re.finditer(
+                   r"^\s*(?:ROOT )?%\S+ = (.+?) fusion\(.*?calls=%([\w.\-]+)",
+                   entry, re.MULTILINE) if holds(m.group(2))]
+    return {"exponentials": exponentials(text), "holders": sorted(holders)}
 
 
 def run_benchmark(ops, size=_DEFAULT_SIZE, runs=10, warmup=2):
@@ -901,6 +1048,14 @@ def main():
                              "_DROPOUT_SWEEP, print the table and keep it "
                              "as DIR/dropout_sweep.json beside the compiled "
                              "text of every row")
+    parser.add_argument("--gelu-sweep", type=str, default="",
+                        metavar="DIR",
+                        help="time ffn2's product with exact GELU on its "
+                             "operand and Dense -> GELU -> Dense, forward "
+                             "+ backward, in every form of _gelu_forms at "
+                             "the shapes of _GELU_SWEEP, print the table "
+                             "and keep it as DIR/gelu_sweep.json beside "
+                             "the compiled text of every row")
     parser.add_argument("--chain", type=int, default=16,
                         help="op-chain length for --dispatch")
     parser.add_argument("--bulk", type=int, default=16,
@@ -979,6 +1134,28 @@ def main():
                   f"{r.get('generator_ops', '-'):>9} "
                   f"{r.get('hashes', '-'):>7} "
                   f"{str(r.get('hash_in_product', '-')):>10}"
+                  + (" <- the op's" if r["chosen"] else "")
+                  + ("  " + r["error"][-120:] if "error" in r else ""))
+        if rows and not rows[0]["on_tpu"]:
+            print("timed on the CPU (no TPU here): not a hardware speed "
+                  "claim")
+        return
+
+    if args.gelu_sweep:
+        os.makedirs(args.gelu_sweep, exist_ok=True)
+        rows = sweep_gelu(runs=args.runs, warmup=args.warmup,
+                          text_dir=args.gelu_sweep)
+        with open(os.path.join(args.gelu_sweep, "gelu_sweep.json"),
+                  "w") as f:
+            json.dump(rows, f, indent=1)
+        print(f"{'Case':<10s} {'What':<8s} {'Form':<20s} {'Device ms':>10s} "
+              f"{'Wall ms':>9s} {'Exponentials':>12s}  Largest ops")
+        for r in rows:
+            largest = "  ".join(f"{n} {t}" for n, t in
+                                r.get("largest_ops", []))
+            print(f"{r['case']:<10s} {r['what']:<8s} {r['form']:<20s} "
+                  f"{r.get('device_ms', '-'):>10} {r.get('wall_ms', '-'):>9} "
+                  f"{r.get('exponentials', '-'):>12}  {largest}"
                   + (" <- the op's" if r["chosen"] else "")
                   + ("  " + r["error"][-120:] if "error" in r else ""))
         if rows and not rows[0]["on_tpu"]:

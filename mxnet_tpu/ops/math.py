@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 
 from .registry import register
 
@@ -69,13 +70,104 @@ for _name, _fn in _UNARY.items():
 register("copy", aliases=("identity", "_copy"))(lambda x: jnp.asarray(x))
 register("zeros_like")(jnp.zeros_like)
 register("ones_like")(jnp.ones_like)
-register("LeakyReLU")(
+
+
+@jax.custom_vjp
+def _exact_gelu(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+def _exact_gelu_fwd(x):
+    # jax.nn.gelu's own expression, term for term, in x's type
+    e = jax.lax.optimization_barrier(
+        jax.lax.erfc(-x * _np.sqrt(0.5).astype(x.dtype)))
+    return 0.5 * x * e, (x, e)
+
+
+def _exact_gelu_bwd(res, g):
+    x, e = res
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+    h = x.astype(wide)
+    slope = 0.5 * e.astype(wide) \
+        + h * jnp.exp(-0.5 * h * h) * (1.0 / _np.sqrt(2.0 * _np.pi))
+    return ((g.astype(wide) * slope).astype(x.dtype),)
+
+
+_exact_gelu.defvjp(_exact_gelu_fwd, _exact_gelu_bwd)
+
+
+def exact_gelu(x):
+    """Exact GELU, the erf form: ``jax.nn.gelu(x, approximate=False)`` =
+    ``0.5 * x * erfc(-x * sqrt(0.5))``, bit for bit, in every dtype; the
+    one function behind ``LeakyReLU(act_type="gelu")``, ``gluon.nn.GELU``
+    and ``npx.gelu``.
+
+    Under reverse-mode differentiation the forward hands the backward
+    ``h`` and ``e = erfc(-h * sqrt(0.5))`` in ``h``'s type, ``e`` behind
+    ``jax.lax.optimization_barrier``, and the backward is ``g * (0.5 * e +
+    h * exp(-h * h / 2) / sqrt(2 pi))`` taken in float32 (float64 for a
+    float64 ``h``) from the stored values and rounded once, where jax's own
+    rule takes the same terms in ``h``'s type. Outside differentiation
+    nothing is kept and the program is jax's. A ``jax.custom_vjp``:
+    forward mode (``jax.jvp``) over this op is gone; nothing in the package
+    uses it.
+
+    Why: XLA expands ``erfc`` over float32 into three branch polynomials,
+    an ``exponential``, two ``divide``s and five ``select``s (~140 vector
+    operations an element), prices that chain as a cheap producer and
+    COPIES it into every fusion that wants ``gelu(h)`` or its slope. In
+    ``bert_base``'s step (``bf16[32,384,3072]``, 12 layers; compiled for
+    the described v5e) that is ``ffn2``'s product, ``ffn2.weight``'s dW
+    product and twice the dX fusion: 48 ``exponential`` instructions over
+    ``f32[32,384,3072]``, four a layer. A ``custom_vjp`` alone changes
+    nothing (XLA fuses the forward chain back into every consumer); with
+    the barrier ``e`` is written once by ``ffn1``'s product and read by the
+    rest: 24, two a layer (``erfc``'s, and the density's in the dX fusion;
+    ``tests/test_tpu_compile.py`` holds a two-layer block to it).
+
+    Device ms from a trace (``benchmark/opperf.py --gelu-sweep``; my chip
+    run, PR 35; TPU v5e), bfloat16, batch 32 x 384, 768 / 3,072. ``ffn2``'s
+    product alone, forward only: 0.3044 without an activation, **0.8202**
+    with ``jax.nn.gelu`` on its operand. Forward + backward of
+    ``LayerNorm(x + ffn2(f(ffn1(x))))`` alone in a jit (without the residual
+    and LayerNorm behind it the compiler keeps ``gelu(h)`` itself and
+    copies nothing), as ``jax.nn.gelu`` / this form / ``gelu(h)`` kept
+    behind the barrier beside ``e``:
+
+    ==========================  ========  =========  ==================
+    ms                          jax       kept erfc  kept erfc + value
+    ==========================  ========  =========  ==================
+    all device operations       3.4834    2.4491     2.4779
+    ``exponential``s            4         2          2
+    ``ffn1``'s product          0.3374    0.6871     0.7062
+    ``ffn2``'s product          0.8376    0.3185     0.3191
+    ``ffn2.weight``'s dW        0.8049    0.3359     0.3359
+    dX with ``ffn1``'s bias     0.7787    0.3840     0.3870
+    ==========================  ========  =========  ==================
+
+    In ``bert_base_train_s384`` (same call, one seed, parent / this form /
+    value kept too): 387.99 / 446.02 / 438.97 samples/s, device busy 76.59
+    / 66.01 / 67.10 ms a step, ``peak_hbm_gib`` 5.37 / 6.15 / 6.89: keeping
+    ``gelu(h)`` too saves ``ffn2``'s product 0.05 ms a layer (0.319 against
+    0.369) and loses more than that to the copies and slices 0.85 GB more
+    of residuals bring; ``e`` alone won and is the one form (six pairs
+    sharing seeds: medians 392.41 / 448.87 samples/s). A layer's four
+    fusions in that cell, parent / this form: ``ffn2``'s product 0.824 /
+    0.371 ms, ``ffn2.weight``'s dW 0.695 / 0.326, the dX fusion 0.755 /
+    0.319, ``ffn1``'s product 0.334 / 0.688: what is left is ``erfc``
+    itself in ``ffn1``'s epilogue and the density's ``exp`` in the dX
+    fusion."""
+    with jax.named_scope("act.gelu"):
+        return _exact_gelu(x)
+
+
+leaky_relu_elementwise = register("LeakyReLU")(
     lambda x, act_type="leaky", slope=0.25: {
         "leaky": lambda: jnp.where(x >= 0, x, slope * x),
         "elu": lambda: jnp.where(x >= 0, x, slope * jnp.expm1(x)),
         "selu": lambda: 1.0507009873554805 * jnp.where(
             x >= 0, x, 1.6732632423543772 * jnp.expm1(x)),
-        "gelu": lambda: jax.nn.gelu(x, approximate=False),
+        "gelu": lambda: exact_gelu(x),
     }[act_type]()
 )
 register("hard_sigmoid")(lambda x, alpha=0.2, beta=0.5: jnp.clip(alpha * x + beta, 0, 1))

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as _np
 
+from .math import exact_gelu
 from .registry import register
 
 
@@ -412,7 +413,7 @@ def _leaky_relu(data, gamma=None, key=None, act_type="leaky", slope=0.25,
         alpha, lam = 1.6732632423543772, 1.0507009873554805
         return lam * jnp.where(data > 0, data, alpha * jnp.expm1(data))
     if act_type == "gelu":
-        return jax.nn.gelu(data, approximate=False)
+        return exact_gelu(data)
     if act_type == "rrelu":
         mid = (lower_bound + upper_bound) / 2.0
         return jnp.where(data > 0, data, mid * data)

@@ -451,6 +451,17 @@ def _dropout_block_step_text(monkeypatch, devices, batch, width, hidden):
                       "adam", {"learning_rate": 1e-3})
 
 
+def _opperf():
+    """``benchmark/opperf.py``, whose counters read a compiled text."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "benchmark"))
+    import opperf
+
+    return opperf
+
+
 def test_dropout_mask_is_drawn_once_by_the_bit_generator(v5e, quiet_cache,
                                                          monkeypatch):
     """The mechanism's counter: generator ops a step = Dropout calls a step
@@ -460,17 +471,32 @@ def test_dropout_mask_is_drawn_once_by_the_bit_generator(v5e, quiet_cache,
     activation's ``u32`` shape (126 integer vector operations an element)
     was evaluated inside every fusion that wanted the mask, the products'
     among them: 104 times a step in BERT's, 2,184 ``xor`` ops (PR 33)."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "benchmark"))
-    import opperf
-
     text = _dropout_block_step_text(monkeypatch, v5e.devices[:1], (32, 384),
                                     768, 3072)
-    counts = opperf.dropout_program_counts(text, (32, 384, 768))
+    counts = _opperf().dropout_program_counts(text, (32, 384, 768))
     assert counts == {"generator_ops": 2, "hashes": 0,
                       "hash_in_product": False}, counts
+
+
+def test_exact_gelu_is_evaluated_once_a_layer(v5e, quiet_cache, monkeypatch):
+    """The mechanism's counter: ``exponential`` instructions over the
+    activation's float32 shape in the compiled Adam step = two a GELU call
+    (the forward's ``erfc`` in ``ffn1``'s product epilogue, the density's
+    ``exp`` in the dX fusion): 4 in this two-layer block, 24 in
+    ``bert_base``'s step. With ``jax.nn.gelu(h, approximate=False)`` alone
+    (until PR 35; a ``custom_vjp`` without the barrier too) XLA copied the
+    ``erfc`` expansion, ~140 vector operations an element, into ``ffn2``'s
+    product, ``ffn2.weight``'s dW product and the dX fusion as well: 8
+    here, 48 in BERT's."""
+    text = _dropout_block_step_text(monkeypatch, v5e.devices[:1], (32, 384),
+                                    768, 3072)
+    counts = _opperf().gelu_program_counts(text, (32, 384, 3072))
+    assert counts["exponentials"] == 4, counts
+    # ffn1's product with h and erfc as results, and the dX fusion with
+    # ffn1's bias gradient: no dW and no ffn2 product among them
+    assert counts["holders"] == [
+        "bf16[3072], bf16[32,384,3072]"] * 2 + [
+        "bf16[32,384,3072], bf16[32,384,3072]"] * 2, counts
 
 
 def _named_instructions(text):
