@@ -17,6 +17,7 @@ from __future__ import annotations
 from ...block import HybridBlock
 from ...nn import (Dense, Embedding, GatedMLP, HybridSequential, MLAttention,
                    RMSNorm, SparseMoE)
+from ...nn.text_layers import mirror_expert_load
 
 __all__ = ["DeepseekV3Block", "DeepseekV3ForCausalLM", "deepseek_v3"]
 
@@ -92,26 +93,7 @@ class DeepseekV3ForCausalLM(HybridBlock):
     def expert_load(self):
         """Every expert layer's load counters (``SparseMoE.expert_load``)
         by layer index, mirrored as ``mxtpu_moe_*`` gauges."""
-        from ....telemetry import registry
-
-        pairs_g = registry.gauge(
-            "mxtpu_moe_expert_pairs",
-            "(token, expert) pairs routed to a held expert since the "
-            "counters were zeroed", ("layer", "expert"))
-        peak_g = registry.gauge(
-            "mxtpu_moe_peak_pairs",
-            "pairs of the busiest held expert, summed over training calls",
-            ("layer",))
-        calls_g = registry.gauge(
-            "mxtpu_moe_calls", "training calls counted", ("layer",))
-        out = {}
-        for i, moe in self.moe_layers():
-            load = out[i] = moe.expert_load()
-            for e, n in enumerate(load["pairs"]):
-                pairs_g.set(n, str(i), str(load["first_expert"] + e))
-            peak_g.set(load["peak"], str(i))
-            calls_g.set(load["calls"], str(i))
-        return out
+        return mirror_expert_load(self.moe_layers())
 
 
 def deepseek_v3(experts_held=None, interpret=False, **config):

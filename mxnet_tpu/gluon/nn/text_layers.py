@@ -1,28 +1,31 @@
 """Blocks of decoder language models (beyond the reference, whose newest
 text model is a post-LN encoder): RMS norm, the gated SiLU feed-forward,
-multi-head latent attention, the sparse expert layer, and the mixers of a
-hybrid decoder: a Mamba-1 state-space layer, differential attention
-(windowed, full, or reading another layer's keys and values) and the
-gated memory unit. The ops under them are in ``ops/text_ops.py``;
-``gluon.model_zoo.text`` builds models of them.
+multi-head latent attention, grouped-query attention with rotary
+positions and q/k norms, the sparse expert layer, LFM2's gated short
+convolution, and the mixers of a hybrid decoder: a Mamba-1 state-space
+layer, differential attention (windowed, full, or reading another layer's
+keys and values) and the gated memory unit. The ops under them are in
+``ops/text_ops.py``; ``gluon.model_zoo.text`` builds models of them.
 
 The ``jax.named_scope`` names here (``mla.project``, ``mla.attention``,
-``moe.shared``; ``moe.route`` and ``moe.experts`` inside
+``moe.shared``, ``moe.balance``; ``moe.route`` and ``moe.experts`` inside
 ``parallel.moe.routed_experts``; ``ssm.project``, ``ssm.conv``,
-``ssm.scan``, ``gmu``, ``attn.window``, ``attn.full``, ``attn.cross``) are
-what a join of the device trace with the HLO will group by.
+``ssm.scan``, ``gmu``, ``attn.window``, ``attn.full``, ``attn.cross``,
+``attn.project``, ``sconv.project``, ``sconv.gate``) are what a join of
+the device trace with the HLO will group by.
 """
 from __future__ import annotations
 
 import numpy as _np
 
-from ... import autograd
+from ... import autograd, initializer
 from ...cached_op import update_state
 from ..block import HybridBlock
 from .basic_layers import Dense
 
-__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "SparseMoE", "LayerNormF32",
-           "MambaMixer", "DiffAttention", "GatedMemoryUnit"]
+__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "GQAttention", "SparseMoE",
+           "ShortConv", "LayerNormF32", "MambaMixer", "DiffAttention",
+           "GatedMemoryUnit"]
 
 
 def _scope(name):
@@ -152,6 +155,101 @@ class MLAttention(HybridBlock):
                 f"rope={self._rope_kwargs})")
 
 
+class GQAttention(HybridBlock):
+    """Causal grouped-query attention with rotary positions and q/k norms
+    (the ``full_attention`` operator of ``lfm2_moe``) over (B, S, units),
+    no biases: ``[q; k; v] = W_qkv x`` gives ``num_heads`` query heads and
+    ``num_kv_heads`` key and value heads of ``units / num_heads``; every
+    head's q and k go through an RMS norm of that width (``q_norm``,
+    ``k_norm``: one gain each, shared by the heads), then rotate-half
+    rotary positions 0..S-1 (``rope_theta``); key head ``j`` serves query
+    heads ``j g .. j g + g - 1`` (``g = num_heads / num_kv_heads``);
+    softmax of ``q k^T / sqrt(width)`` through
+    ``F.contrib.flash_attention``, whose kernels take grouped keys as they
+    are; ``out = W_o attention``."""
+
+    def __init__(self, units, num_heads, num_kv_heads, rope_theta=10000.0,
+                 epsilon=1e-6, interpret=False, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        if units % num_heads or num_heads % num_kv_heads:
+            raise ValueError(
+                f"{num_heads} query heads over {num_kv_heads} key heads "
+                f"do not divide {units} units")
+        self._heads, self._kv_heads = int(num_heads), int(num_kv_heads)
+        self._d = units // num_heads
+        self._theta = float(rope_theta)
+        self._interpret = interpret   # the kernel in the interpreter (CPU)
+        with self.name_scope():
+            self.qkv_proj = Dense((num_heads + 2 * num_kv_heads) * self._d,
+                                  use_bias=False, flatten=False,
+                                  in_units=units)
+            self.q_norm = RMSNorm(self._d, epsilon=epsilon)
+            self.k_norm = RMSNorm(self._d, epsilon=epsilon)
+            self.o_proj = Dense(units, use_bias=False, flatten=False,
+                                in_units=units)
+
+    def hybrid_forward(self, F, x):
+        d, q_end = self._d, self._heads * self._d
+        k_end = q_end + self._kv_heads * d
+
+        def heads(t, norm=None):  # (B, S, H * d) -> (B, H, S, d)
+            t = F.reshape(t, shape=(0, 0, -1, d))
+            t = F.transpose(t if norm is None else norm(t),
+                            axes=(0, 2, 1, 3))
+            return t if norm is None else F.invoke(
+                "_contrib_rotary_embedding", t, theta=self._theta)
+
+        with _scope("attn.project"):
+            qkv = self.qkv_proj(x)
+            q = heads(F.slice_axis(qkv, axis=-1, begin=0, end=q_end),
+                      self.q_norm)
+            k = heads(F.slice_axis(qkv, axis=-1, begin=q_end, end=k_end),
+                      self.k_norm)
+            v = heads(F.slice_axis(qkv, axis=-1, begin=k_end, end=None))
+        with _scope("attn.full"):
+            out = F.contrib.flash_attention(
+                q, k, v, scale=float(d ** -0.5), causal=True,
+                interpret=self._interpret)
+        with _scope("attn.project"):
+            return self.o_proj(F.reshape(
+                F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1)))
+
+    def __repr__(self):
+        return (f"GQAttention(heads={self._heads}|{self._kv_heads} x "
+                f"{self._d}, theta={self._theta})")
+
+
+class ShortConv(HybridBlock):
+    """LFM2's double-gated short convolution over (B, S, units), no
+    biases: ``[b; c; x] = W_in u`` (three streams of ``units``, in that
+    order); ``z = b * x``; a causal depthwise convolution of ``taps``
+    taps over positions (``v_t = sum_k w[:, k] z_(t - taps + 1 + k)``,
+    zero before the first); ``out = W_out (c * v)``. What lies between the
+    projections is one op (``_contrib_gated_short_conv``)."""
+
+    def __init__(self, units, taps=3, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.in_proj = Dense(3 * units, use_bias=False, flatten=False,
+                                 in_units=units)
+            self.conv_weight = self.params.get("conv_weight",
+                                               shape=(units, taps))
+            self.out_proj = Dense(units, use_bias=False, flatten=False,
+                                  in_units=units)
+
+    def hybrid_forward(self, F, u, conv_weight=None):
+        with _scope("sconv.project"):
+            bcx = self.in_proj(u)
+        with _scope("sconv.gate"):
+            gated = F.invoke("_contrib_gated_short_conv", bcx, conv_weight)
+        with _scope("sconv.project"):
+            return self.out_proj(gated)
+
+    def __repr__(self):
+        return (f"ShortConv({self.conv_weight.shape[0]}, "
+                f"taps={self.conv_weight.shape[1]})")
+
+
 class _KeepsFloat32(HybridBlock):
     """A block some of whose own parameters (``_FLOAT32``) stay float32
     under ``cast``."""
@@ -185,11 +283,31 @@ class SparseMoE(_KeepsFloat32):
     expert) pairs each held expert got, ``load_peak`` the busiest held
     expert's pairs, summed over calls, and ``load_calls``. ``expert_load``
     reads them.
+
+    ``bias_update_rate`` above 0 makes the selection bias balance the load
+    itself (the auxiliary-loss-free rule of arXiv:2408.15664, which
+    DeepSeek-V3 trains the same buffer by): after a training call, with
+    ``c`` (num_experts,) the pairs each expert of the WHOLE router got
+    from the call's tokens, ``bias += rate * sign(mean(c) - c)``, in
+    float32, outside the gradient, like the counters. The layer then has
+    three more buffers: ``route_pairs`` (num_experts,), ``c`` summed over
+    calls (what ``load_pairs`` is for the experts held, for all);
+    ``route_recent`` (``RECENT_CALLS``, num_experts), ``c`` of each of the
+    last calls, call ``n`` in row ``n mod RECENT_CALLS`` (whether the load
+    is even at the end of a run as at its start); and ``bias_rate`` (1,),
+    the rate: a traced scalar, so a schedule sets it and compiles
+    nothing. On one device ``c`` is local: nothing stands in
+    for other ranks' counts. The default 0 builds the layer without the
+    rule or either buffer, the program it was. ``norm_eps`` is what the
+    renormalising sum is kept from zero by.
     """
+
+    RECENT_CALLS = 128
 
     def __init__(self, units, hidden_size, num_experts, top_k,
                  num_shared=0, routed_scaling_factor=1.0, norm_topk=True,
-                 experts_held=None, prefix=None, params=None):
+                 experts_held=None, bias_update_rate=0.0, norm_eps=1e-20,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count > 0 and first + count <= num_experts):
@@ -201,7 +319,8 @@ class SparseMoE(_KeepsFloat32):
         self._route_kwargs = {
             "top_k": int(top_k), "first_expert": int(first),
             "scale": float(routed_scaling_factor),
-            "norm_topk": bool(norm_topk)}
+            "norm_topk": bool(norm_topk), "norm_eps": float(norm_eps),
+            "route_counts": bias_update_rate > 0}
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(num_experts, units))
@@ -223,20 +342,42 @@ class SparseMoE(_KeepsFloat32):
             self.load_calls = self.params.get(
                 "load_calls", shape=(1,), init="zeros", grad_req="null",
                 differentiable=False)
+            if bias_update_rate > 0:
+                self.route_pairs = self.params.get(
+                    "route_pairs", shape=(num_experts,), init="zeros",
+                    grad_req="null", differentiable=False)
+                self.route_recent = self.params.get(
+                    "route_recent", shape=(self.RECENT_CALLS, num_experts),
+                    init="zeros", grad_req="null", differentiable=False)
+                self.bias_rate = self.params.get(
+                    "bias_rate", shape=(1,), grad_req="null",
+                    init=initializer.Constant(float(bias_update_rate)),
+                    differentiable=False)
             self.shared = GatedMLP(units, num_shared * hidden_size) \
                 if num_shared else None
 
     # the selection bias and the counters stay float32 (the router scores
     # are float32; a bfloat16 counter stops counting at 256)
-    _FLOAT32 = ("router_bias", "load_pairs", "load_peak", "load_calls")
+    _FLOAT32 = ("router_bias", "load_pairs", "load_peak", "load_calls",
+                "route_pairs", "route_recent", "bias_rate")
 
     def hybrid_forward(self, F, x, router_weight=None, router_bias=None,
                        gate_weight=None, up_weight=None, down_weight=None,
-                       load_pairs=None, load_peak=None, load_calls=None):
-        y, load = F.invoke(
+                       load_pairs=None, load_peak=None, load_calls=None,
+                       route_pairs=None, route_recent=None, bias_rate=None):
+        y, load, *counts = F.invoke(
             "_contrib_sparse_moe", x, router_weight, router_bias,
             gate_weight, up_weight, down_weight, **self._route_kwargs)
         if autograd.is_training():
+            if counts:
+                with _scope("moe.balance"):
+                    c = counts[0]
+                    update_state(router_bias, router_bias
+                                 + bias_rate * F.sign(c.mean() - c))
+                    update_state(route_pairs, route_pairs + c)
+                    # row ``load_calls`` mod R, before the call is counted
+                    update_state(route_recent, F.invoke(
+                        "_contrib_ring_write", route_recent, c, load_calls))
             update_state(load_pairs, load_pairs + load)
             update_state(load_peak, load_peak + load.max())
             update_state(load_calls, load_calls + 1)
@@ -248,12 +389,32 @@ class SparseMoE(_KeepsFloat32):
     def expert_load(self):
         """``{"first_expert", "pairs": [per held expert], "peak",
         "calls"}`` since the counters were last zeroed (one read of the
-        device)."""
-        pairs, peak, calls = (
-            p.data().asnumpy().astype(float)
-            for p in (self.load_pairs, self.load_peak, self.load_calls))
-        return {"first_expert": self._held[0], "pairs": pairs.tolist(),
-                "peak": float(peak[0]), "calls": float(calls[0])}
+        device), and ``"route_pairs": [per expert of the router]`` where
+        the layer balances itself."""
+        names = ["load_pairs", "load_peak", "load_calls"] + [
+            n for n in ("route_pairs", "route_recent")
+            if n in self._reg_params]
+        pairs, peak, calls, *routed = (
+            self._reg_params[n].data().asnumpy().astype(float)
+            for n in names)
+        out = {"first_expert": self._held[0], "pairs": pairs.tolist(),
+               "peak": float(peak[0]), "calls": float(calls[0])}
+        if routed:
+            total, ring = routed
+            n = int(calls[0])
+            # oldest call first
+            rows = [i % len(ring) for i in range(max(0, n - len(ring)), n)]
+            out.update(route_pairs=total.tolist(),
+                       route_recent=ring[rows].tolist())
+        return out
+
+    def zero_load(self):
+        """Zero the counters (the bias stays)."""
+        for name in ("load_pairs", "load_peak", "load_calls", "route_pairs",
+                     "route_recent"):
+            p = self._reg_params.get(name)
+            if p is not None:
+                p.set_data(p.data() * 0)
 
     def __repr__(self):
         return (f"SparseMoE(experts={self._num_experts}, "
@@ -437,3 +598,35 @@ class GatedMemoryUnit(HybridBlock):
         with _scope("gmu"):
             return self.out_proj(F.invoke("_contrib_gated_silu",
                                           self.in_proj(u), memory))
+
+
+def mirror_expert_load(moe_layers):
+    """``{layer: SparseMoE.expert_load()}`` of ``[(layer, SparseMoE)]``,
+    each mirrored as ``mxtpu_moe_*`` gauges of ``telemetry.registry``."""
+    from ...telemetry import registry
+
+    pairs_g = registry.gauge(
+        "mxtpu_moe_expert_pairs",
+        "(token, expert) pairs routed to a held expert since the "
+        "counters were zeroed", ("layer", "expert"))
+    peak_g = registry.gauge(
+        "mxtpu_moe_peak_pairs",
+        "pairs of the busiest held expert, summed over training calls",
+        ("layer",))
+    calls_g = registry.gauge(
+        "mxtpu_moe_calls", "training calls counted", ("layer",))
+    route_g = registry.gauge(
+        "mxtpu_moe_route_pairs",
+        "(token, expert) pairs routed to each expert of the whole router "
+        "(a layer that balances itself) since the counters were zeroed",
+        ("layer", "expert"))
+    out = {}
+    for i, moe in moe_layers:
+        load = out[i] = moe.expert_load()
+        for e, n in enumerate(load["pairs"]):
+            pairs_g.set(n, str(i), str(load["first_expert"] + e))
+        for e, n in enumerate(load.get("route_pairs", ())):
+            route_g.set(n, str(i), str(e))
+        peak_g.set(load["peak"], str(i))
+        calls_g.set(load["calls"], str(i))
+    return out

@@ -64,21 +64,36 @@ def _contrib_gated_silu(gate, up):
     return jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
 
 
-@register("_contrib_sparse_moe", num_outputs=2)
+@register("_contrib_sparse_moe",
+          num_outputs=lambda _n, kw: 3 if kw.get("route_counts") else 2)
 def _contrib_sparse_moe(x, router_weight, router_bias, gate_weight,
                         up_weight, down_weight, top_k=1, first_expert=0,
-                        scale=1.0, norm_topk=True):
+                        scale=1.0, norm_topk=True, norm_eps=1e-20,
+                        route_counts=False):
     """The held experts' part of a sparse expert layer over ``x``
     (..., h): ``parallel.moe.routed_experts`` on the flattened tokens.
-    Outputs ``(y like x, load (n_held,))``."""
+    Outputs ``(y like x, load (n_held,))`` and, with ``route_counts``,
+    the pairs every expert of the router got, (n_experts,)."""
     from ..parallel.moe import routed_experts
 
-    y, load = routed_experts(
+    y, *counts = routed_experts(
         x.reshape(-1, x.shape[-1]), router_weight, router_bias,
         gate_weight, up_weight, down_weight, top_k=int(top_k),
         first_expert=int(first_expert), scale=float(scale),
-        norm_topk=bool(norm_topk))
-    return y.reshape(x.shape), load
+        norm_topk=bool(norm_topk), norm_eps=float(norm_eps),
+        route_counts=bool(route_counts))
+    return (y.reshape(x.shape), *counts)
+
+
+@register("_contrib_ring_write")
+def _contrib_ring_write(ring, row, index):
+    """``ring`` (R, ...) with ``row`` written at ``index[0] mod R``: a
+    history of the last R values of a statistic, kept as auxiliary state
+    (``SparseMoE``'s ``route_recent``)."""
+    import jax.numpy as jnp
+
+    at = index.reshape(-1)[0].astype(jnp.int32) % ring.shape[0]
+    return ring.at[at].set(row.astype(ring.dtype))
 
 
 @register("_contrib_lm_cross_entropy")
@@ -131,6 +146,63 @@ def _contrib_causal_conv1d(x, weight, bias, activation=None):
             raise ValueError(f"causal_conv1d has no activation {activation!r}")
         y = jax.nn.silu(y)
     return y.astype(x.dtype)
+
+
+@register("_contrib_gated_short_conv")
+def _contrib_gated_short_conv(bcx, weight):
+    """The inside of LFM2's double-gated short convolution: ``bcx`` (B, S,
+    3 C) holds three streams ``[b; c; x]`` side by side, ``weight`` (C, K)
+    the taps of a depthwise causal convolution without bias. ``z = b * x``;
+    ``v_t = sum_k weight[:, k] * z_(t - K + 1 + k)``, positions before the
+    first counted as zero; the result is ``c * v`` (B, S, C). Products and
+    sums in float32, the result in ``bcx``'s type.
+
+    One pass over the streams each way: the backward keeps ``bcx`` as it
+    came, recomputes the few products an element it needs and writes the
+    three cotangents side by side. What jax would derive keeps ``z``,
+    ``v`` and a float32 copy of ``bcx`` a layer and pads and concatenates
+    its way back; and XLA undoes a recomputation it can see through, so
+    both passes take ``bcx`` behind ``optimization_barrier``: without the
+    forward's the projection before it writes float32 (XLA drops the
+    rounding to ``bcx``'s type in between), without the backward's the
+    forward's ``z`` and ``v`` are kept in float32 for it (read off the
+    step compiled for a described v5e)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    s, taps = bcx.shape[1], weight.shape[1]
+
+    def streams(bcx, weight):
+        b, c, x = jnp.split(bcx.astype(f32), 3, axis=-1)
+        z = jnp.pad(b * x, ((0, 0), (taps - 1, 0), (0, 0)))
+        w32 = weight.astype(f32)
+        v = sum(z[:, k:k + s, :] * w32[:, k] for k in range(taps))
+        return b, c, x, z, v, w32
+
+    @jax.custom_vjp
+    def gated(bcx, weight):
+        _, c, _, _, v, _ = streams(jax.lax.optimization_barrier(bcx), weight)
+        return (c * v).astype(bcx.dtype)
+
+    def gated_fwd(bcx, weight):
+        return gated(bcx, weight), (bcx, weight)
+
+    def gated_bwd(kept, g):
+        kept = jax.lax.optimization_barrier(kept)
+        b, c, x, z, v, w32 = streams(*kept)
+        g = g.astype(f32)
+        # v_t reads z_(t - K + 1 + k): z_u is read by v_(u + K - 1 - k)
+        dv = jnp.pad(g * c, ((0, 0), (0, taps - 1), (0, 0)))
+        dz = sum(dv[:, taps - 1 - k:taps - 1 - k + s, :] * w32[:, k]
+                 for k in range(taps))
+        dw = jnp.stack([(dv[:, :s, :] * z[:, k:k + s, :]).sum(axis=(0, 1))
+                        for k in range(taps)], axis=-1)
+        d_bcx = jnp.concatenate([dz * x, g * v, dz * b], axis=-1)
+        return d_bcx.astype(kept[0].dtype), dw.astype(kept[1].dtype)
+
+    gated.defvjp(gated_fwd, gated_bwd)
+    return gated(bcx, weight)
 
 
 @register("_contrib_selective_scan")

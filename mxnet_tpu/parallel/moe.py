@@ -96,15 +96,19 @@ def moe_apply(expert_fn, mesh, axis="ep"):
 
 # ---------------------------------------------- sparse top-k expert layer --
 
-def route_topk(x, router_w, bias, top_k, scale, norm_topk=True):
+def route_topk(x, router_w, bias, top_k, scale, norm_topk=True,
+               norm_eps=1e-20, route_counts=False):
     """Sigmoid router with a selection bias (the ``noaux_tc`` recipe of
     DeepSeek-V3, one group): ``s = sigmoid(x W^T)`` in float32 over every
     expert; the ``top_k`` experts are chosen by ``s + bias``; their weights
     are ``s`` alone (the bias steers the choice and nothing else),
-    renormalised to sum to one where ``norm_topk``, times ``scale``.
+    renormalised to sum to one where ``norm_topk`` (over ``sum +
+    norm_eps``: ``lfm2_moe`` publishes 1e-6), times ``scale``.
 
     x (T, h), router_w (E, h), bias (E,) -> (ids (T, k) int32,
-    weights (T, k) float32)."""
+    weights (T, k) float32), and with ``route_counts`` a third: (E,) float32, the
+    (token, expert) pairs each expert of the WHOLE router got, which is
+    what a rule that moves the bias by the load reads."""
     import jax
     import jax.numpy as jnp
 
@@ -113,8 +117,13 @@ def route_topk(x, router_w, bias, top_k, scale, norm_topk=True):
     _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     if norm_topk:
-        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
-    return ids.astype(jnp.int32), w * scale
+        w = w / (w.sum(axis=-1, keepdims=True) + norm_eps)
+    ids = ids.astype(jnp.int32)
+    if not route_counts:
+        return ids, w * scale
+    # a compare and a sum (E is tens): no scatter
+    pairs = (ids.reshape(-1, 1) == jnp.arange(s.shape[-1], dtype=jnp.int32))
+    return ids, w * scale, pairs.sum(axis=0, dtype=jnp.float32)
 
 
 def _pair_gathers(top_k):
@@ -157,7 +166,8 @@ def _pair_gathers(top_k):
 
 
 def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
-                   first_expert=0, scale=1.0, norm_topk=True):
+                   first_expert=0, scale=1.0, norm_topk=True,
+                   norm_eps=1e-20, route_counts=False):
     """The part of a sparse expert layer that the experts held here give.
 
     ``x`` is (T, h). The router is as wide as the model (``router_w``
@@ -178,7 +188,9 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
     the grouped product and masked.
 
     Returns ``(y (T, h) in x's type, load (n,) float32)``: ``load[e]`` is
-    the number of pairs routed to held expert ``e`` in this call.
+    the number of pairs routed to held expert ``e`` in this call; with
+    ``route_counts`` a third, (E,) float32: the pairs every expert of the
+    router got (``route_topk``), held here or not.
     """
     import jax
     import jax.numpy as jnp
@@ -186,7 +198,8 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
     t, h = x.shape
     n = w_gate.shape[0]
     with jax.named_scope("moe.route"):
-        ids, w = route_topk(x, router_w, bias, top_k, scale, norm_topk)
+        ids, w, *counts = route_topk(x, router_w, bias, top_k, scale,
+                                     norm_topk, norm_eps, route_counts)
         local = ids - first_expert
         held = (local >= 0) & (local < n)
         # pairs on absent experts sort behind every held group
@@ -194,7 +207,10 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
-        sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+        # the held groups' sizes: the whole router's counts hold them
+        sizes = (counts[0][first_expert:first_expert + n] if counts
+                 else jnp.bincount(group, length=n + 1)[:n]
+                 ).astype(jnp.int32)
         live = (jnp.arange(t * top_k) < sizes.sum())[:, None]
     to_experts, to_tokens = _pair_gathers(top_k)
 
@@ -216,4 +232,4 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
         ys = jax.checkpoint(experts)(x, w_gate, w_up, w_down)
         wk = jnp.where(held, w, 0.0)[:, :, None]
         y = (ys.reshape(t, top_k, h).astype(jnp.float32) * wk).sum(axis=1)
-    return y.astype(x.dtype), sizes.astype(jnp.float32)
+    return (y.astype(x.dtype), sizes.astype(jnp.float32), *counts)

@@ -585,3 +585,104 @@ def test_step_scopes_leave_the_mosaic_calls_their_names(v5e, quiet_cache,
         jax, "named_scope", lambda name: contextlib.nullcontext()
         if name.startswith("trainer.") else scope(name))
     assert lowered().as_text() == named.as_text()
+
+
+# the lowered step of ``_deepseek_block`` on the tree BEFORE ``SparseMoE``
+# learned ``bias_update_rate`` (PR 35's; jax 0.9.0): sha256 of the module's
+# text, which holds no source location. A PR that changes the deepseek_v3
+# step on purpose replaces it and says so.
+_DEEPSEEK_BLOCK_STEP = \
+    "6933eae19b934814e651f5e000cc07e66aa9277e8922ae7437ac276809500494"
+
+
+def _deepseek_block(**moe):
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.gluon.model_zoo.text.deepseek_v3 import DeepseekV3Block
+
+    cfg = {"hidden_size": 256, "rms_norm_eps": 1e-6,
+           "num_attention_heads": 2, "kv_lora_rank": 64,
+           "qk_nope_head_dim": 64, "qk_rope_head_dim": 64,
+           "v_head_dim": 128, "rope_theta": 10000.0,
+           "rope_interleave": True, "moe_intermediate_size": 128,
+           "n_routed_experts": 8, "num_experts_per_tok": 2,
+           "n_shared_experts": 1, "routed_scaling_factor": 2.5,
+           "norm_topk_prob": True}
+    net = DeepseekV3Block(cfg, True, (2, 4))
+    if moe:
+        with net.name_scope():
+            net.ffn = nn.SparseMoE(256, 128, 8, 2, num_shared=1,
+                                   routed_scaling_factor=2.5,
+                                   experts_held=(2, 4), **moe)
+    return net
+
+
+def test_a_still_bias_leaves_the_deepseek_step_as_it_was(v5e, quiet_cache,
+                                                         monkeypatch):
+    """``bias_update_rate``'s default builds the expert layer without the
+    rule, its counters or the router's third output: the lowered step of a
+    ``deepseek_v3`` block is byte for byte the parent's. With a rate the
+    same block counts the whole router under ``moe.balance``."""
+    import hashlib
+
+    def lowered(**moe):
+        return _step_lowered(monkeypatch, v5e.devices[:1],
+                             _deepseek_block(**moe), (2, 256, 256), "adam",
+                             {"learning_rate": 1e-3})
+
+    still = lowered().as_text()
+    assert hashlib.sha256(still.encode()).hexdigest() == _DEEPSEEK_BLOCK_STEP
+    assert "moe.balance" not in lowered().as_text(debug_info=True)
+    moving = lowered(bias_update_rate=1e-3)
+    assert moving.as_text() != still
+    assert "moe.balance" in moving.as_text(debug_info=True)
+
+
+def test_lfm2_kernels_compile_at_the_cells_shapes(one_chip, quiet_cache):
+    """The new cell's kernels at its widths, one sequence of 8,192: the
+    flash family with four query heads a key head (32 over 8 heads of 64)
+    at the blocks the shape picks, forward and backward, and the gated
+    short convolution's two passes over three (8192, 2048) streams."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import flash
+    from mxnet_tpu.ops import registry
+
+    bf = jnp.bfloat16
+    q = _shape((1, 32, 8192, 64), bf, one_chip)
+    kv = _shape((1, 8, 8192, 64), bf, one_chip)
+    assert flash.default_blocks(8192, 8192, 64, 64) == (1024, 1024)
+    assert flash.backward_blocks(8192, 8192, 64, 64) == (512, 512)
+    assert flash._bucket(q, kv, kv, 0.125, True) \
+        == "bh32_sq8192_sk8192_d64_bfloat16_c1_q1024k1024_g4"
+
+    assert flash._supports(q, kv, kv, 0.125, True)
+    assert flash._blocks_for(q, kv, kv) == (512, 512)
+
+    def fwd_bwd(q_, k_, v_, cot_):
+        out, lse = flash.flash_forward_lse(q_, k_, v_, 0.125, True, 1024,
+                                           1024)
+        return (out,) + flash.flash_backward_kernel(
+            q_, k_, v_, out, lse, cot_, 0.125, True, 512, 512)
+
+    text = jax.jit(fwd_bwd).lower(q, kv, kv, q).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    out, dq, dk, dv = jax.eval_shape(fwd_bwd, q, kv, kv, q)
+    assert [(t.shape, t.dtype) for t in (out, dq, dk, dv)] \
+        == [(t.shape, t.dtype) for t in (q, q, kv, kv)]
+
+    gated = registry.get("_contrib_gated_short_conv").fn
+    bcx = _shape((1, 8192, 6144), bf, one_chip)
+    taps = _shape((2048, 3), bf, one_chip)
+    cot = _shape((1, 8192, 2048), bf, one_chip)
+
+    def conv(bcx, taps, cot):
+        out, vjp = jax.vjp(gated, bcx, taps)
+        return (out,) + vjp(cot)
+
+    compiled = jax.jit(conv).lower(bcx, taps, cot).compile()
+    # beside the results: the gated product z and the convolution's
+    # cotangent in float32 (64 MiB each, what a shifted read wants
+    # materialised) and the three cotangents before they are put side by
+    # side; no float32 copy of bcx (192 MiB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 288 * 2 ** 20
