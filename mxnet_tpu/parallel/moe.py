@@ -27,7 +27,10 @@ the block over it.
 from __future__ import annotations
 
 __all__ = ["moe_apply", "stack_expert_params", "route_topk",
-           "routed_experts"]
+           "routed_experts", "buffer_rungs"]
+
+import functools
+import math
 
 from .pipeline import _check_stacked_leading_dim
 from .pipeline import stack_stage_params as stack_expert_params
@@ -165,6 +168,162 @@ def _pair_gathers(top_k):
     return to_experts, to_tokens
 
 
+# a rung below every pair is a multiple of this many rows (the grouped
+# product ran slower a call on rows that are multiples of 128 alone,
+# PR 38); every rung is another copy of the layer's kernels in the step's
+# executable, which a warm start loads: kanana's warm setup read +1.1 s
+# with 3 rungs and +3.6 s with 4 against a 10 % bound (PR 38)
+ROW_TILE = 1024
+MAX_RUNGS = 3
+
+
+def buffer_rungs(tokens, top_k, held, experts):
+    """The row counts the expert-ordered buffer of :func:`routed_experts`
+    may take, smallest first, from the shapes alone: ``tokens * top_k``
+    pairs, ``held`` of a router ``experts`` wide.
+
+    An even router sends ``e = tokens * top_k * held / experts`` pairs to
+    the experts held. The first rung is ``e + e / 16`` (a router that
+    balances itself hovers around ``e``, a hair above as often as under),
+    the last is every pair, and the ones between grow by one factor, so a
+    live count anywhere between them fills at least ``1 / factor`` of its
+    rung; each is rounded up to ``ROW_TILE``. With every expert held every
+    pair is live: one rung.
+
+    At the expert cells' shapes: ``lfm2`` (8,192 tokens, top-4, 8 of 32)
+    9,216 17,408 32,768; ``kanana`` (8,192, top-6, 16 of 128) 7,168
+    18,432 49,152.
+    """
+    rows = tokens * top_k
+    even = -(-rows * held // experts)
+    first = even + even // 16
+    if first >= rows:
+        return (rows,)
+    factor = (rows / first) ** (1 / (MAX_RUNGS - 1))
+    rungs = {min(rows, -(-math.ceil(first * factor ** i) // ROW_TILE)
+                 * ROW_TILE) for i in range(MAX_RUNGS - 1)}
+    return tuple(sorted(rungs | {rows}))
+
+
+def _slots(rows, top_k, inv, live):
+    """Where each (token, choice) pair sits in a buffer of the first
+    ``rows`` pairs in expert order, (T, top_k), and whether it is live
+    there: a dead pair, or one ranked at or past ``rows``, reads the last
+    row through the clamped index and is selected away."""
+    import jax.numpy as jnp
+
+    return (jnp.minimum(inv, rows - 1).reshape(-1, top_k),
+            (inv < live).reshape(-1, top_k))
+
+
+def _rows_forward(rows, top_k, x, wk, w_gate, w_up, w_down, order, inv,
+                  sizes):
+    """The held experts' part of the layer, (T, h) float32, with the
+    expert-ordered buffer ``rows`` long (``order[:rows]``): every array
+    between the gather into expert order and the combine has ``rows`` rows.
+    The grouped products leave the rows past the live count undefined and
+    nothing reads them. The combine sums each token's ``top_k`` rows with
+    their weights in float32, reading them where they lie: no (T * top_k,
+    h) copy in token order."""
+    import jax
+    import jax.numpy as jnp
+
+    xs = x[order[:rows] // top_k]
+    gate = jax.lax.ragged_dot(xs, w_gate, sizes)
+    up = jax.lax.ragged_dot(xs, w_up, sizes)
+    act = jax.nn.silu(gate.astype(jnp.float32)).astype(xs.dtype) * up
+    ys = jax.lax.ragged_dot(act, w_down, sizes)
+    at, kept = _slots(rows, top_k, inv, sizes.sum())
+    return sum(jnp.where(kept[:, j, None],
+                         wk[:, j, None] * ys[at[:, j]].astype(jnp.float32),
+                         0.0) for j in range(top_k))
+
+
+def _rows_backward(rows, top_k, g, x, wk, w_gate, w_up, w_down, order, inv,
+                   sizes):
+    """The cotangents of :func:`_rows_forward`'s ``(x, wk, w_gate, w_up,
+    w_down)`` for ``g`` (T, h), from the two products recomputed up to the
+    activation. The down product's input cotangent ``u = g W_down^T`` is
+    taken once, row by row in expert order, and gives both the
+    activation's (``wk u``) and the router weights' (``<act, u>``, which is
+    ``<act W_down, g>``): the down product itself is not recomputed. Every
+    gather reads by index, none scatters (a row-wise scatter-add
+    serialises on the TPU); x's cotangent sums each token's live rows."""
+    import jax
+    import jax.numpy as jnp
+
+    f32, dt = jnp.float32, x.dtype
+
+    def product_t(a, w):
+        return jax.lax.ragged_dot(a, jnp.swapaxes(w, 1, 2), sizes)
+
+    def weight_t(a, b):
+        return jax.lax.ragged_dot_general(
+            a, b, sizes, jax.lax.RaggedDotDimensionNumbers(
+                (((0,), (0,)), ((), ())), (0,), ()))
+
+    pair = order[:rows]
+    tok = pair // top_k
+    xs = x[tok]
+    gate = jax.lax.ragged_dot(xs, w_gate, sizes).astype(f32)
+    up = jax.lax.ragged_dot(xs, w_up, sizes)
+    sig = jax.nn.sigmoid(gate)
+    silu = (gate * sig).astype(dt)
+    act = silu * up
+    # the cotangent of a result routed_experts rounds to x's type: gathered
+    # in that type, exactly
+    gr = g.astype(dt)[tok]
+    w_r = wk.reshape(-1)[pair][:, None]
+    u = product_t(gr, w_down).astype(f32)
+    dact = w_r * u
+    d_up = (dact * silu.astype(f32)).astype(dt)
+    d_gate = (dact * up.astype(f32) * sig * (1 + gate * (1 - sig))
+              ).astype(dt)
+    dxs = product_t(d_gate, w_gate) + product_t(d_up, w_up)
+    at, kept = _slots(rows, top_k, inv, sizes.sum())
+    dx = sum(jnp.where(kept[:, j, None], dxs[at[:, j]].astype(f32), 0.0)
+             for j in range(top_k))
+    dw = (act.astype(f32) * u).sum(-1)
+    return (dx.astype(dt), jnp.where(kept, dw[at], 0.0),
+            weight_t(xs, d_gate), weight_t(xs, d_up),
+            weight_t(act, (w_r * gr.astype(f32)).astype(dt)))
+
+
+@functools.lru_cache(maxsize=None)
+def _ladder(top_k, rungs):
+    """``layer(x, wk, w_gate, w_up, w_down, order, inv, sizes)``:
+    :func:`_rows_forward` at the smallest of ``rungs`` that holds
+    ``sizes.sum()`` rows, one branch of a ``jax.lax.switch`` a rung. Like
+    ``jax.checkpoint`` it keeps its inputs alone; the backward pass runs
+    the chosen rung's :func:`_rows_backward` in a second switch.
+    Differentiating the switch itself would make every branch hand out the
+    residuals of all the others, zeros at every rung's size. Each rung is
+    jitted: traced and lowered once a program, not once a layer."""
+    import jax
+    import jax.numpy as jnp
+
+    def rung_of(fn, rows):
+        # inlined into the caller's program, never an executable of its own
+        return jax.jit(functools.partial(fn, rows, top_k))  # noqa: raw-jit
+
+    forwards = [rung_of(_rows_forward, rows) for rows in rungs]
+    backwards = [rung_of(_rows_backward, rows) for rows in rungs]
+
+    def rung(sizes):
+        return (sizes.sum() > jnp.asarray(rungs[:-1], jnp.int32)).sum()
+
+    def forward(*args):
+        return jax.lax.switch(rung(args[-1]), forwards, *args)
+
+    def backward(args, g):
+        return (*jax.lax.switch(rung(args[-1]), backwards, g, *args),
+                None, None, None)
+
+    layer = jax.custom_vjp(forward)
+    layer.defvjp(lambda *args: (forward(*args), args), backward)
+    return layer
+
+
 def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
                    first_expert=0, scale=1.0, norm_topk=True,
                    norm_eps=1e-20, route_counts=False):
@@ -180,12 +339,17 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
     (with every expert held this is the whole layer). On one device
     nothing is exchanged.
 
-    The pairs are sorted by expert and multiplied group by group
-    (``jax.lax.ragged_dot``: on the TPU a grouped-matmul kernel whose work
-    follows the rows of each group). There is no capacity: the row buffer
-    has room for every pair (T * top_k rows), so no token is ever dropped,
-    however uneven the routing; rows past the held pairs are skipped by
-    the grouped product and masked.
+    The pairs are sorted by expert, held groups first, and multiplied
+    group by group (``jax.lax.ragged_dot``: on the TPU a grouped-matmul
+    kernel whose work follows the rows of each group). There is no
+    capacity and no token is ever dropped, however uneven the routing.
+    With every expert held every pair is live and the buffer has T *
+    top_k rows. Otherwise the buffer is the smallest rung of
+    :func:`buffer_rungs` that holds the live pairs, chosen in the step
+    (``jax.lax.switch``), the last rung being every pair; the gathers, the
+    activation and the combine follow its rows, and the grouped products
+    skip the rows past the live pairs. The combine reads each token's
+    rows where they lie (:func:`_rows_forward`).
 
     Returns ``(y (T, h) in x's type, load (n,) float32)``: ``load[e]`` is
     the number of pairs routed to held expert ``e`` in this call; with
@@ -197,6 +361,7 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
 
     t, h = x.shape
     n = w_gate.shape[0]
+    rungs = buffer_rungs(t, top_k, n, router_w.shape[0])
     with jax.named_scope("moe.route"):
         ids, w, *counts = route_topk(x, router_w, bias, top_k, scale,
                                      norm_topk, norm_eps, route_counts)
@@ -211,6 +376,13 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
         sizes = (counts[0][first_expert:first_expert + n] if counts
                  else jnp.bincount(group, length=n + 1)[:n]
                  ).astype(jnp.int32)
+    if len(rungs) > 1:
+        with jax.named_scope("moe.experts"):
+            y = _ladder(top_k, rungs)(
+                x, jnp.where(held, w, 0.0), w_gate, w_up, w_down, order,
+                inv, sizes)
+        return (y.astype(x.dtype), sizes.astype(jnp.float32), *counts)
+    with jax.named_scope("moe.route"):
         live = (jnp.arange(t * top_k) < sizes.sum())[:, None]
     to_experts, to_tokens = _pair_gathers(top_k)
 

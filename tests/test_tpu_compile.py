@@ -190,6 +190,66 @@ def test_routed_experts_compile_to_grouped_kernels(one_chip, quiet_cache):
     assert mem.temp_size_in_bytes < 2 * 2 ** 30
 
 
+def test_expert_buffer_rungs_compile_to_their_rows(one_chip, quiet_cache):
+    """An expert layer shaped like the short-convolution decoder's, small
+    (2,048 tokens, top-4, 8 held of a 32-way router, 256 x 224): in each
+    branch of the forward's and of the backward's switch every grouped
+    product with a (rows, width) result has that rung's rows, and no float32
+    tensor of every (token, expert) pair is left outside a fusion."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel.moe import MAX_RUNGS, buffer_rungs, routed_experts
+
+    t, h, f, e, n, k = 2048, 256, 224, 32, 8, 4
+    rungs = buffer_rungs(t, k, n, e)
+    assert len(rungs) == MAX_RUNGS and rungs[-1] == t * k
+    bf = jnp.bfloat16
+    args = (_shape((t, h), bf, one_chip), _shape((e, h), bf, one_chip),
+            _shape((e,), jnp.float32, one_chip),
+            _shape((n, h, f), bf, one_chip), _shape((n, h, f), bf, one_chip),
+            _shape((n, f, h), bf, one_chip))
+
+    def loss(*a):
+        y, load = routed_experts(*a, top_k=k, scale=1.0)
+        return y.astype(jnp.float32).sum(), load
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5),
+                                      has_aux=True)).lower(*args) \
+        .compile().as_text()
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None and line.strip() not in ("", "}"):
+            bodies[name].append(line)
+    switches = [re.search(r"branch_computations=\{([^}]*)\}", line)
+                .group(1).replace("%", "").split(", ")
+                for lines in bodies.values() for line in lines
+                if " conditional(" in line]
+    assert len(switches) == 2
+    for branches in switches:
+        assert len(branches) == len(rungs)
+        for rows, branch in zip(rungs, branches):
+            products = [re.search(r"= bf16\[(\d+),\d+\]", line)
+                        for line in bodies[branch] if "ragged-dot-none" in
+                        line.split("=")[0]]
+            seen = {int(m.group(1)) for m in products if m}
+            assert seen == {rows}, (branch, seen)
+    fused = {m.group(1) for m in re.finditer(r"calls=%([\w.\-]+)", text)}
+    every_pair = (f"f32[{t * k},{h}]", f"f32[{t},{k},{h}]")
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            result = line.split(" = ", 1)[-1].split("{", 1)[0]
+            assert not result.startswith(every_pair), line[:200]
+
+
 def test_selective_scan_kernels_compile_at_the_cells_bucket(one_chip,
                                                             quiet_cache):
     """The scan at the hybrid decoder's shape: one sequence of 4,096
