@@ -996,6 +996,120 @@ def gelu_program_counts(text, shape):
     return {"exponentials": exponentials(text), "holders": sorted(holders)}
 
 
+# The expert layer's first-rung grouped products at the three expert cells'
+# widths (first rung's rows, held experts, hidden, expert width), one row a
+# (form, tile): each of the six product shapes of a rung at every tile that
+# divides its shape and is estimated to fit VMEM, beside jax.lax.ragged_dot.
+_GMM_SWEEP = [
+    ("mellum2_12b", 17408, 16, 2304, 896),
+    ("lfm2_8b_a1b", 9216, 8, 2048, 1792),
+    ("kanana2_30b_a3b", 7168, 16, 2048, 768),
+]
+
+
+def _gmm_tiles(mode, rows, k, n, cap=15 * 2 ** 20):
+    """The tiles the sweep tries for one product: row tiles of 512 and
+    1,024, K and N tiles of at least 384 (or the whole width) that divide
+    the shape, inside ``cap`` bytes of VMEM by ``vmem_bytes``."""
+    from mxnet_tpu.kernels import grouped_matmul as gm
+
+    def sides(width):
+        got = [d for d in gm._divisors(width, width) if d >= 384]
+        return got or [width]
+
+    return [(tm, tk, tn) for tm in (512, 1024) if rows % tm == 0
+            for tk in sides(k) for tn in sides(n)
+            if gm.vmem_bytes(mode, tm, tk, tn) <= cap]
+
+
+def sweep_grouped_matmul(runs=20, warmup=3, cases=None, seed=41):
+    """Time the ``grouped_matmul`` kernel at every tile ``_gmm_tiles``
+    gives, for the six products of a rung (``nn`` / ``nt`` / ``tn`` at h x f
+    and f x h) at each case's first-rung rows, and ``jax.lax.ragged_dot``
+    beside it: ``wall_ms`` is a call's mean over ``runs`` calls queued
+    back to back (the host's clock; the calls are ~0.3-3 ms, far above
+    what a dispatch costs), ``err`` the largest difference from
+    ``ragged_dot`` on the live rows over its largest magnitude. The live
+    rows are 15/16 of the rung, split unevenly over the groups. A tile the
+    compiler refuses is a row with its ``error``; the fastest tile of each
+    product is marked ``best``."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import kernels as klayer
+    from mxnet_tpu.kernels import grouped_matmul as gm
+
+    interp = not klayer.on_tpu()
+    r = np.random.default_rng(seed)
+    rows_out = []
+    for label, rows, groups, h, f in cases or _GMM_SWEEP:
+        share = r.dirichlet(np.full(groups, 8.0))
+        sizes = np.floor(share * rows * 15 / 16).astype(np.int32)
+        sizes = jnp.asarray(sizes)
+        live = int(sizes.sum())
+        bf = jnp.bfloat16
+        for k, n in ((h, f), (f, h)):
+            a = jnp.asarray(r.standard_normal((rows, k), np.float32), bf)
+            operands = {
+                "nn": jnp.asarray(r.standard_normal((groups, k, n),
+                                                    np.float32), bf),
+                "nt": jnp.asarray(r.standard_normal((groups, n, k),
+                                                    np.float32), bf),
+                "tn": jnp.asarray(r.standard_normal((rows, n), np.float32),
+                                  bf)}
+            for mode, b in operands.items():
+                head = {"case": label, "mode": mode, "rows": rows,
+                        "live": live, "k": k, "n": n, "groups": groups,
+                        "interpret": interp}
+                want = jax.jit(functools.partial(
+                    gm.grouped_matmul_xla, mode=mode))(a, b, sizes)
+                keep = slice(None) if mode == "tn" else slice(0, live)
+                want = np.asarray(want, np.float32)[keep]
+                scale = float(np.abs(want).max()) or 1.0
+                found = []
+                for tile in _gmm_tiles(mode, rows, k, n):
+                    if mode == "tn":
+                        fn = jax.jit(functools.partial(
+                            gm._tgmm, tile=tile, interpret=interp))
+                    else:
+                        fn = jax.jit(functools.partial(
+                            gm._gmm, tile=tile, transpose=mode == "nt",
+                            interpret=interp))
+                    row = {**head, "tile": list(tile),
+                           "vmem_mib": round(gm.vmem_bytes(mode, *tile)
+                                             / 2 ** 20, 2)}
+                    try:
+                        got = np.asarray(fn(a, b, sizes), np.float32)[keep]
+                        row["err"] = float(np.abs(got - want).max() / scale)
+                        row["wall_ms"] = round(_queued_ms(
+                            fn, (a, b, sizes), runs, warmup), 4)
+                    except Exception as e:  # the compiler's refusal
+                        row["error"] = f"{type(e).__name__}: {str(e)[-300:]}"
+                    found.append(row)
+                timed = [x for x in found if "wall_ms" in x]
+                if timed:
+                    min(timed, key=lambda x: x["wall_ms"])["best"] = True
+                xla = jax.jit(functools.partial(gm.grouped_matmul_xla,
+                                                mode=mode))
+                found.append({**head, "tile": "ragged_dot",
+                              "wall_ms": round(_queued_ms(
+                                  xla, (a, b, sizes), runs, warmup), 4)})
+                rows_out += found
+    return rows_out
+
+
+def _queued_ms(fn, args, runs, warmup):
+    """Mean ms a call over ``runs`` calls queued back to back."""
+    import jax
+
+    for _ in range(warmup):
+        jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(runs):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / runs * 1e3
+
+
 def run_benchmark(ops, size=_DEFAULT_SIZE, runs=10, warmup=2):
     results = []
     for name in ops:
@@ -1056,6 +1170,12 @@ def main():
                              "the shapes of _GELU_SWEEP, print the table "
                              "and keep it as DIR/gelu_sweep.json beside "
                              "the compiled text of every row")
+    parser.add_argument("--gmm-sweep", type=str, default="",
+                        help="time the expert layer's grouped products "
+                             "(kernels.grouped_matmul) at every tile that "
+                             "fits, at the expert cells' first-rung shapes, "
+                             "beside ragged_dot, and keep the rows as "
+                             "DIR/gmm_sweep.json")
     parser.add_argument("--chain", type=int, default=16,
                         help="op-chain length for --dispatch")
     parser.add_argument("--bulk", type=int, default=16,
@@ -1087,6 +1207,28 @@ def main():
                   f"{r['side'][:5]:>5} {r.get('wall_ms', '-'):>9} "
                   f"{r.get('device_ms') or '-':>10}"
                   + (" <- the window's" if r["chosen"] else "")
+                  + ("  " + r["error"][-120:] if "error" in r else ""))
+        if rows and rows[0]["interpret"]:
+            print("timed in the Pallas INTERPRETER (no TPU here): not a "
+                  "hardware speed claim")
+        return
+
+    if args.gmm_sweep:
+        os.makedirs(args.gmm_sweep, exist_ok=True)
+        rows = sweep_grouped_matmul(runs=max(args.runs, 20),
+                                    warmup=args.warmup)
+        with open(os.path.join(args.gmm_sweep, "gmm_sweep.json"), "w") as f:
+            json.dump(rows, f, indent=1)
+        print(f"{'Case':<16s} {'Mode':<4s} {'K x N':<11s} {'Tile':<16s} "
+              f"{'VMEM':>6s} {'Wall ms':>9s} {'Err':>9s}")
+        for r in rows:
+            tile = r["tile"] if isinstance(r["tile"], str) \
+                else "{} {} {}".format(*r["tile"])
+            err = f"{r['err']:.3g}" if "err" in r else "-"
+            print(f"{r['case']:<16s} {r['mode']:<4s} "
+                  f"{'{} x {}'.format(r['k'], r['n']):<11s} {tile:<16s} "
+                  f"{r.get('vmem_mib', '-'):>6} {r.get('wall_ms', '-'):>9} "
+                  f"{err:>9}" + (" <- best" if r.get("best") else "")
                   + ("  " + r["error"][-120:] if "error" in r else ""))
         if rows and rows[0]["interpret"]:
             print("timed in the Pallas INTERPRETER (no TPU here): not a "

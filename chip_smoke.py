@@ -326,6 +326,15 @@ def _kernel_cases(attn, decode, opt, gemm, seed=2):
     codes = jnp.asarray(r.integers(-4, 5, opt), jnp.int8)
     cases.append(("twobit_decompress", "twobit_decompress", (codes,),
                   {"thr": 0.5}, None))
+    # an expert layer's three grouped products in bf16, its type: 512 rows
+    # in four groups, one of them empty, every row live
+    bf = jnp.bfloat16
+    sizes = jnp.asarray([200, 0, 180, 132], jnp.int32)
+    rows = f32(512, 256).astype(bf)
+    for mode, b in (("nn", f32(4, 256, 384)), ("nt", f32(4, 384, 256)),
+                    ("tn", f32(512, 384))):
+        cases.append((f"grouped_matmul/{mode}", "grouped_matmul",
+                      (rows, b.astype(bf), sizes), {"mode": mode}, bf))
     return cases
 
 

@@ -47,6 +47,10 @@ selective_scan     the recurrence of a Mamba-1 state-space layer, walked
 selective_scan_    its backward, decided inside the forward's
 bwd                ``custom_vjp``: resumes from the states saved at chunk
                    boundaries and recomputes inside a chunk
+grouped_matmul     an expert layer's grouped products over its
+                   expert-ordered rows (megablox ``gmm`` / ``tgmm``), in
+                   the first rung of its buffer; its XLA side is
+                   ``jax.lax.ragged_dot``
 =================  ====================================================
 
 Fallbacks LATCH: Pallas-unavailable is probed once per process and
@@ -311,3 +315,4 @@ from . import int8_gemm  # noqa: E402,F401  (int8_gemm)
 from . import decode_attention  # noqa: E402,F401  (decode_attention)
 from . import twobit  # noqa: E402,F401  (twobit_compress/_decompress)
 from . import selective_scan  # noqa: E402,F401  (selective_scan, _bwd)
+from . import grouped_matmul  # noqa: E402,F401  (grouped_matmul)

@@ -230,31 +230,40 @@ def _slots(rows, top_k, inv, live):
             (inv < live).reshape(-1, top_k))
 
 
-def _rows_forward(rows, top_k, x, wk, w_gate, w_up, w_down, order, inv,
-                  sizes):
+def _kernel_products(a, b, sizes, mode):
+    """The grouped products of the first rung: the ``grouped_matmul``
+    family, whose kernel side is traced once a distinct shape."""
+    from .. import kernels
+
+    return kernels.dispatch("grouped_matmul", a, b, sizes, mode=mode)
+
+
+def _rows_forward(rows, top_k, grouped, x, wk, w_gate, w_up, w_down, order,
+                  inv, sizes):
     """The held experts' part of the layer, (T, h) float32, with the
     expert-ordered buffer ``rows`` long (``order[:rows]``): every array
     between the gather into expert order and the combine has ``rows`` rows.
-    The grouped products leave the rows past the live count undefined and
-    nothing reads them. The combine sums each token's ``top_k`` rows with
-    their weights in float32, reading them where they lie: no (T * top_k,
-    h) copy in token order."""
+    ``grouped(a, b, sizes, mode)`` is the grouped product
+    (``kernels.grouped_matmul``'s three modes). It leaves the rows past the
+    live count undefined and nothing reads them. The combine sums each
+    token's ``top_k`` rows with their weights in float32, reading them
+    where they lie: no (T * top_k, h) copy in token order."""
     import jax
     import jax.numpy as jnp
 
     xs = x[order[:rows] // top_k]
-    gate = jax.lax.ragged_dot(xs, w_gate, sizes)
-    up = jax.lax.ragged_dot(xs, w_up, sizes)
+    gate = grouped(xs, w_gate, sizes, "nn")
+    up = grouped(xs, w_up, sizes, "nn")
     act = jax.nn.silu(gate.astype(jnp.float32)).astype(xs.dtype) * up
-    ys = jax.lax.ragged_dot(act, w_down, sizes)
+    ys = grouped(act, w_down, sizes, "nn")
     at, kept = _slots(rows, top_k, inv, sizes.sum())
     return sum(jnp.where(kept[:, j, None],
                          wk[:, j, None] * ys[at[:, j]].astype(jnp.float32),
                          0.0) for j in range(top_k))
 
 
-def _rows_backward(rows, top_k, g, x, wk, w_gate, w_up, w_down, order, inv,
-                   sizes):
+def _rows_backward(rows, top_k, grouped, g, x, wk, w_gate, w_up, w_down,
+                   order, inv, sizes):
     """The cotangents of :func:`_rows_forward`'s ``(x, wk, w_gate, w_up,
     w_down)`` for ``g`` (T, h), from the two products recomputed up to the
     activation. The down product's input cotangent ``u = g W_down^T`` is
@@ -269,18 +278,16 @@ def _rows_backward(rows, top_k, g, x, wk, w_gate, w_up, w_down, order, inv,
     f32, dt = jnp.float32, x.dtype
 
     def product_t(a, w):
-        return jax.lax.ragged_dot(a, jnp.swapaxes(w, 1, 2), sizes)
+        return grouped(a, w, sizes, "nt")
 
     def weight_t(a, b):
-        return jax.lax.ragged_dot_general(
-            a, b, sizes, jax.lax.RaggedDotDimensionNumbers(
-                (((0,), (0,)), ((), ())), (0,), ()))
+        return grouped(a, b, sizes, "tn")
 
     pair = order[:rows]
     tok = pair // top_k
     xs = x[tok]
-    gate = jax.lax.ragged_dot(xs, w_gate, sizes).astype(f32)
-    up = jax.lax.ragged_dot(xs, w_up, sizes)
+    gate = grouped(xs, w_gate, sizes, "nn").astype(f32)
+    up = grouped(xs, w_up, sizes, "nn")
     sig = jax.nn.sigmoid(gate)
     silu = (gate * sig).astype(dt)
     act = silu * up
@@ -312,13 +319,24 @@ def _ladder(top_k, rungs):
     the chosen rung's :func:`_rows_backward` in a second switch.
     Differentiating the switch itself would make every branch hand out the
     residuals of all the others, zeros at every rung's size. Each rung is
-    jitted: traced and lowered once a program, not once a layer."""
+    jitted: traced and lowered once a program, not once a layer.
+
+    The first rung's grouped products are the ``grouped_matmul`` family
+    (on the TPU a Pallas kernel, traced and lowered once a distinct shape);
+    the rungs above it take the overflow of an uneven router and keep
+    ``jax.lax.ragged_dot``, which adds no kernel a start has to lower: a
+    rung of Pallas kernels adds its own tracing and lowering to every warm
+    start (PERF.md section 6)."""
     import jax
     import jax.numpy as jnp
 
+    from ..kernels.grouped_matmul import grouped_matmul_xla
+
     def rung_of(fn, rows):
+        grouped = _kernel_products if rows == rungs[0] else grouped_matmul_xla
+        body = functools.partial(fn, rows, top_k, grouped)
         # inlined into the caller's program, never an executable of its own
-        return jax.jit(functools.partial(fn, rows, top_k))  # noqa: raw-jit
+        return jax.jit(body)  # noqa: raw-jit
 
     forwards = [rung_of(_rows_forward, rows) for rows in rungs]
     backwards = [rung_of(_rows_backward, rows) for rows in rungs]
@@ -355,8 +373,9 @@ def routed_experts(x, router_w, bias, w_gate, w_up, w_down, *, top_k,
     nothing is exchanged.
 
     The pairs are sorted by expert, held groups first, and multiplied
-    group by group (``jax.lax.ragged_dot``: on the TPU a grouped-matmul
-    kernel whose work follows the rows of each group). There is no
+    group by group (a grouped-matmul kernel whose work follows the rows
+    of each group: ``kernels.grouped_matmul`` in the buffer's first rung,
+    ``jax.lax.ragged_dot`` elsewhere). There is no
     capacity and no token is ever dropped, however uneven the routing.
     With every expert held every pair is live and the buffer has T *
     top_k rows. Otherwise the buffer is the smallest rung of

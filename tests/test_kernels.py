@@ -28,8 +28,8 @@ from mxnet_tpu.kernels import table as ktable
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FAMILIES = ("decode_attention", "flash_attention", "flash_attention_bwd",
-            "int8_gemm", "selective_scan", "selective_scan_bwd",
-            "twobit_compress", "twobit_decompress")
+            "grouped_matmul", "int8_gemm", "selective_scan",
+            "selective_scan_bwd", "twobit_compress", "twobit_decompress")
 
 
 @pytest.fixture

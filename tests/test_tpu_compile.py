@@ -4,6 +4,7 @@ is a measurement): what Mosaic or the chip's memory would refuse on the
 chip fails here, at no chip time. Everything built from the topology is
 built inside the fixtures, so every xdist worker collects the same tests
 and only the one that runs this file loads the TPU's library."""
+import functools
 import os
 
 import pytest
@@ -821,3 +822,41 @@ def test_mellum_flash_compiles_at_the_cells_buckets(one_chip, quiet_cache,
     out, dq, dk, dv = jax.eval_shape(fwd_bwd, q, kv, kv, q)
     assert [(t.shape, t.dtype) for t in (out, dq, dk, dv)] \
         == [(t.shape, t.dtype) for t in (q, q, kv, kv)]
+
+
+@pytest.mark.parametrize("rows,held,h,f", [
+    (17408, 16, 2304, 896), (9216, 8, 2048, 1792), (7168, 16, 2048, 768)],
+    ids=["mellum", "lfm2", "kanana"])
+def test_grouped_matmul_compiles_at_the_first_rungs(one_chip, quiet_cache,
+                                                    rows, held, h, f):
+    """The six grouped products of an expert cell's first rung (``nn``,
+    ``nt`` and ``tn`` with K x N = h x f and f x h), each one Mosaic call
+    at the tiles ``grouped_matmul.tiles`` gives the shape, inside the
+    scoped VMEM a v5e gives a call, results in the operands' type."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.kernels import grouped_matmul as gm
+
+    bf = jnp.bfloat16
+    sizes = _shape((held,), jnp.int32, one_chip)
+    for k, n in ((h, f), (f, h)):
+        a = _shape((rows, k), bf, one_chip)
+        for mode, b, out in (
+                ("nn", (held, k, n), (rows, n)),
+                ("nt", (held, n, k), (rows, n)),
+                ("tn", (rows, n), (held, k, n))):
+            b = _shape(b, bf, one_chip)
+            assert gm._supports(a, b, sizes, mode)
+            tile = gm.tiles(mode, rows, k, n)
+            if mode == "tn":
+                fn = functools.partial(gm._tgmm, tile=tile, interpret=False)
+            else:
+                fn = functools.partial(gm._gmm, tile=tile,
+                                       transpose=mode == "nt",
+                                       interpret=False)
+            text = jax.jit(fn).lower(a, b, sizes).compile().as_text()
+            assert text.count('custom_call_target="tpu_custom_call"') == 1, \
+                (mode, k, n, tile)
+            got = jax.eval_shape(fn, a, b, sizes)
+            assert (got.shape, got.dtype) == (out, bf)
